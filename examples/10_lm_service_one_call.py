@@ -16,11 +16,6 @@ import threading
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax
 import jax.numpy as jnp
 import optax

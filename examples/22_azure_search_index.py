@@ -21,11 +21,6 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 
 from mmlspark_tpu import Table
 from mmlspark_tpu.cognitive import AzureSearchWriter

@@ -293,14 +293,9 @@ def _jax_if_initialized():
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    try:
-        from jax._src import xla_bridge
-        backends = getattr(xla_bridge, "_backends", None)
-        if backends is not None and not backends:
-            return None
-    except Exception:
-        pass
-    return jax
+    from jax._src import xla_bridge
+
+    return jax if xla_bridge.backends_are_initialized() else None
 
 
 def sample_device_memory(devices=None) -> Dict[str, int]:

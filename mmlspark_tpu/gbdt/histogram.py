@@ -109,9 +109,8 @@ def _split_summary(hist, feature_mask, lambda_l1, lambda_l2,
                    min_data_in_leaf, min_sum_hessian):
     """One fused program per node: argmax split + its left/right stats as
     a single [8] vector — the grower pulls 32 bytes per node instead of
-    the whole [F, B, 3] histogram plus separate scalar syncs (on a
-    remote/tunneled device, per-node round trips dominate the grow loop
-    otherwise)."""
+    the whole [F, B, 3] histogram plus separate scalar syncs (per-node
+    host round trips dominate the grow loop otherwise)."""
     scores = _split_scores(hist, lambda_l1, lambda_l2, min_data_in_leaf,
                            min_sum_hessian)
     idx, gain = _best_of(scores, feature_mask)

@@ -88,7 +88,10 @@ class FlaxBundle(ModelBundle):
             # token models (nn.Embed inputs) declare input_dtype=int32 on
             # the module; image/feature models default to float32
             in_dtype = getattr(self.module, "input_dtype", jnp.float32)
-            variables = self.module.init(
+            # ONE compiled program, not an eager op (and a compile) per
+            # initializer: a ResNet-50's worth of those is seconds on the
+            # CPU backend and far worse on a chip
+            variables = jax.jit(self.module.init)(
                 {"params": jax.random.PRNGKey(seed)},
                 jnp.zeros((1, *self.input_shape), in_dtype),
             )

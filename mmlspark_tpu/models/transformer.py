@@ -90,9 +90,13 @@ def _gqa_expand(kv, num_heads: int):
 
 
 def _single_tpu() -> bool:
-    """Default-attention dispatch predicate (separable so tests can force
-    the Pallas branch on the CPU backend via interpret mode)."""
-    return jax.default_backend() == "tpu" and jax.device_count() == 1
+    """Default-attention / paged-decode dispatch predicate: the
+    computation being traced targets exactly ONE TPU device
+    (ops.pallas_kernels.on_single_tpu).  Separable so tests can force
+    the Pallas branch on the CPU backend via interpret mode."""
+    from ..ops.pallas_kernels import on_single_tpu
+
+    return on_single_tpu()
 
 
 def default_attn(causal: bool):

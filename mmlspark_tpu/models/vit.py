@@ -21,7 +21,7 @@ is the penultimate feature vector.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax.numpy as jnp
@@ -48,6 +48,9 @@ class VisionTransformer(nn.Module):
     # > 0: encoder MLPs become switch-MoE (V-MoE style); expert weights
     # shard over a mesh axis for expert parallelism
     moe_experts: int = 0
+    # None -> transformer.default_attn(causal=False); or any (q, k, v) ->
+    # out attention fn, the same contract as TransformerLM.attn_fn
+    attn_fn: Optional[Callable] = None
     layer_names = ["logits", "pool", "encoded", "embed"]
 
     @nn.compact
@@ -73,7 +76,8 @@ class VisionTransformer(nn.Module):
         # shared dispatch rule with TransformerLM (transformer.default_attn):
         # flash kernel pair on a single TPU — S=196 pads to the 256 grid
         # with kv_valid masking — XLA dense under GSPMD sharding
-        attn = default_attn(False)
+        attn = (self.attn_fn if self.attn_fn is not None
+                else default_attn(False))
         from ..ops.quant import dense_cls
         for i in range(self.num_layers):
             x = _Block(self.num_heads, self.mlp_ratio, self.dtype, attn,

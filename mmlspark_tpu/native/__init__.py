@@ -40,15 +40,18 @@ MAX_JPEG_PIXELS = 178_956_970
 
 def build(force: bool = False) -> bool:
     """Compile the shared lib (make -C mmlspark_tpu/native).  Always runs
-    make (a no-op when fresh) so a stale .so picks up new entry points."""
+    make (a no-op when fresh) so a stale .so picks up new entry points;
+    `force` rebuilds from src/native.cpp unconditionally.  True only when
+    make itself succeeded: a failed build never passes off whatever
+    untracked .so happens to lie on disk as the result."""
     try:
         subprocess.run(
             ["make", "-C", _DIR] + (["-B"] if force else []),
             check=True, capture_output=True, timeout=120,
         )
-        return os.path.exists(_SO)
     except (subprocess.SubprocessError, FileNotFoundError):
-        return os.path.exists(_SO)
+        return False
+    return os.path.exists(_SO)
 
 
 def _load() -> Optional[ctypes.CDLL]:

@@ -177,8 +177,8 @@ class _VowpalWabbitBase(Estimator):
         if n_passes > 1:
             # all passes ride ONE dispatch (a scan over the jitted pass):
             # VW's multipass re-reads its cache file per pass; here the
-            # only per-pass cost was a host sync for the loss, and on a
-            # remote/tunneled device even that gates the loop
+            # only per-pass cost was a host sync for the loss, and even
+            # that gates the loop
             def scanned(w, g2):
                 def body(carry, _):
                     w, g2 = carry

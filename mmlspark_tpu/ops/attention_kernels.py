@@ -9,10 +9,11 @@ sibling; reference has no analog — its deepest attention is CNTK-era).
 
 Mosaic-friendly formulation (same playbook as pallas_kernels.py):
   - Q/K/V reshaped OUTSIDE the kernel to [B*H, S, D] (no in-kernel
-    reshapes); head_dim runs NATIVE at 128-multiples and (probe-gated,
-    see _native_d64_ok) at 64-mod-128 dims — padding d=64 up to the
-    lane would double the QK^T MACs with zeros and materialize 2x-size
-    q/k/v/o copies around every call; other dims pad to the 128 lane.
+    reshapes); head_dim runs NATIVE at 64-multiples (`_kernel_d`; the
+    v5e compiler takes the 64-minor tiles, tests/test_aot_tpu_compile.py
+    holds it to that) — padding d=64 up to the lane would double the
+    QK^T MACs with zeros and materialize 2x-size q/k/v/o copies around
+    every call; other dims pad to the 128 lane.
   - grid = (B*H, S/block_q, S/block_k), K innermost: K/V blocks STREAM
     through VMEM while running max / normalizer / unnormalized output
     live in VMEM scratch across the K steps (online softmax, the true
@@ -31,13 +32,15 @@ dK/dV streaming Q blocks, one accumulates dQ streaming K blocks),
 recomputing each score block in VMEM from the forward's saved
 logsumexp — the dense-XLA backward materialized f32 [B, H, S, S]
 score tensors per layer and was measured to be 71% of the whole LM
-train step on a v5e (tools/lm_ablate.py).  Shapes the forward kernel
-rejects keep the exact XLA-recompute backward.
+train step on a v5e (tools/lm_ablate.py).  Shapes `kernel_ok` declines
+run the XLA composition forward and backward.  There is no other
+fallback: a shape `kernel_ok` admits and the compiler refuses raises
+(under an outer jit, when the outer program compiles).
 
-On CPU the kernel runs interpret=True (tests/CI); on TPU it compiles to
-Mosaic.  tests/test_attention_kernels.py holds the parity suite; the
-on-hardware compile check rides the same real-TPU gate as the image
-kernels.
+Off TPU the kernel runs interpret=True (tests/CI); on TPU it compiles to
+Mosaic.  tests/test_attention_kernels.py holds the parity suite,
+tests/test_aot_tpu_compile.py the compiles for a described v5e, and
+chip_smoke.py the run on the chip.
 """
 from __future__ import annotations
 
@@ -46,12 +49,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .pallas_kernels import (
-    PALLAS_IMAGE_VMEM_BUDGET,
-    _interpret,
-    _pad_up,
-    pallas_available,
-)
+from .pallas_kernels import PALLAS_IMAGE_VMEM_BUDGET, _interpret, _pad_up
 
 __all__ = ["fused_attention", "attention_fits_vmem", "kernel_ok"]
 
@@ -383,8 +381,6 @@ def kernel_ok(q) -> bool:
     """Public predicate: will fused_attention take the Pallas kernel for
     this (B, S, H, D) array, or fall back to the XLA composition?"""
     b, s, h, d = q.shape
-    if not pallas_available():
-        return False
     s_p = _padded_len(s)
     if s_p is None:
         return False
@@ -392,8 +388,6 @@ def kernel_ok(q) -> bool:
     # makes the kernel a net loss vs XLA dense — keep small heads on XLA
     if d < 64:
         return False
-    if _sig(s_p, d, q.dtype) in _REJECTED_FWD:
-        return False  # this signature's pallas lowering already failed
     return attention_fits_vmem(s_p, d, q.dtype.itemsize)
 
 
@@ -410,8 +404,8 @@ def fused_attention(q, k, v, causal: bool = True):
     TRUE head dim even when D pads to the 128 lane.  Differentiable:
     kernel-path shapes take the flash backward kernels (blockwise
     recompute from the saved logsumexp — matches the XLA gradients to
-    MXU precision, ~1e-3 on bf16 passes); fallback shapes keep the
-    exact XLA recompute.
+    MXU precision, ~1e-3 on bf16 passes); shapes `kernel_ok` declines
+    keep the exact XLA recompute.
     """
     return _fused_attention_fwd(q, k, v, causal)[0]
 
@@ -436,85 +430,12 @@ def _pad_seq(x, s_p):
     return jnp.pad(x, ((0, 0), (0, s_p - s), (0, 0)))
 
 
-_NATIVE_D64_OK = None
-# Per-shape self-healing (the process-wide probe runs one tiny shape;
-# Mosaic's tiling rules depend on the FULL (S, D, dtype) signature, so a
-# passing probe does not clear every production shape).  A pallas_call
-# that raises for a signature lands here and never retries:
-_REJECTED_NATIVE_D: set = set()   # head dims whose 64-mod native run failed
-_REJECTED_FWD: set = set()        # (s_pad, d, dtype) -> XLA composition
-_REJECTED_BWD: set = set()        # (s_pad, d_pad, dtype) -> XLA recompute
-
-
-def _sig(s_p, d, dtype) -> tuple:
-    return (int(s_p), int(d), jnp.dtype(dtype).str)
-
-
-def _native_d64_ok() -> bool:
-    """Can the kernels run with a 64-lane head dim natively (no pad to
-    128)?  The padded path doubles the QK^T contraction's MAC count with
-    zeros AND materializes 2x-size copies of q/k/v/o around every call —
-    for d_head=64 models (the LM and ViT-B flagship shapes) that is pure
-    waste when Mosaic takes the 64-minor tiles.  Probed ONCE per process
-    by compiling all three kernels on a tiny shape in the PRODUCTION
-    dtype (bf16 — Mosaic tiling is dtype-dependent: f32 (8, 128) tiles
-    passing says nothing about the (16, 128) bf16 tiles the real models
-    feed) and checking the forward numerically against the XLA
-    composition on RANDOM input (zeros compile-and-run can succeed while
-    the lowering is wrong: softmax over an all-zero score row hides any
-    normalization or masking bug).  A rejection self-heals to the padded
-    path, so this can never cost a bench run; shapes the probe wrongly
-    clears still self-heal per-signature via _REJECTED_NATIVE_D."""
-    global _NATIVE_D64_OK
-    if _NATIVE_D64_OK is None:
-        if _interpret():
-            _NATIVE_D64_OK = True  # interpret mode has no tiling rules
-        else:
-            _NATIVE_D64_OK = _probe_native_d64()
-    return _NATIVE_D64_OK
-
-
-def _probe_native_d64() -> bool:
-    # deliberate trace-time host work: this probe runs ONCE per process
-    # while the first d=64 attention call is being traced, on its own
-    # concrete arrays (never tracers) — the host RNG and blocking syncs
-    # are the point, not a hazard
-    import numpy as _np
-
-    rng = _np.random.default_rng(0)  # graftlint: disable=G103
-    try:
-        q, k, v, do = (jnp.asarray(rng.standard_normal((1, 128, 64)),
-                                   jnp.bfloat16) for _ in range(4))
-        st = jnp.zeros((1, 128, _LANE), jnp.float32)
-        o, lse = _attention_pallas(q, k, v, True, 0.125, None)
-        jax.block_until_ready(  # graftlint: disable=G106
-            _attention_bwd_dkdv(q, k, v, do, st, st, True, 0.125, None))
-        jax.block_until_ready(  # graftlint: disable=G106
-            _attention_bwd_dq(q, k, v, do, st, st, True, 0.125, None))
-        # graftlint: disable=G106
-        o = _np.asarray(jax.block_until_ready(o))
-    except Exception:  # noqa: BLE001 — any compile/run rejection
-        return False
-    # numerical parity with the XLA composition, same bhsd inputs: the
-    # tolerance covers the kernel's one extra rounding (probabilities
-    # cast to bf16 at the PV matmul), two orders below a real mask/
-    # normalization bug (O(1) error)
-    ref = _np.asarray(
-        _xla_attention(q[:, :, None, :], k[:, :, None, :],
-                       v[:, :, None, :], True))[:, :, 0, :]
-    return bool(_np.max(_np.abs(o - ref)) <= 5e-2)
-
-
 def _kernel_d(d: int) -> int:
-    """Head-dim the kernels run at: lane-multiple dims are native; the
-    64-mod-128 dims (64, 192, ...) stay native when the probe passes and
-    no production shape at this head dim has been rejected; everything
-    else pads up to the 128 lane."""
-    if d % _LANE == 0:
-        return d
-    if d % 64 == 0 and d not in _REJECTED_NATIVE_D and _native_d64_ok():
-        return d
-    return _pad_up(d, _LANE)
+    """Head-dim the kernels run at: 64-multiples are native (64, 128,
+    192, ... — all three kernels compile at the 64-minor tiles for a
+    v5e in bf16 and f32, tests/test_aot_tpu_compile.py); everything else
+    pads up to the 128 lane."""
+    return d if d % 64 == 0 else _pad_up(d, _LANE)
 
 
 def _run_kernel(q, k, v, causal: bool):
@@ -523,34 +444,18 @@ def _run_kernel(q, k, v, causal: bool):
     s_p = _padded_len(s)
     kv_valid = s if s_p != s else None
     scale = 1.0 / float(d) ** 0.5
-    try:
-        o, lse = _attention_pallas(
-            _pad_seq(_to_bhsd(q, d_p), s_p), _pad_seq(_to_bhsd(k, d_p), s_p),
-            _pad_seq(_to_bhsd(v, d_p), s_p), causal, scale, kv_valid)
-    except Exception:  # noqa: BLE001 — per-shape Mosaic rejection
-        if d_p % _LANE == 0:
-            raise  # already lane-padded: nothing gentler to retry
-        # the probe cleared 64-mod head dims on a tiny shape alone; THIS
-        # signature's lowering was rejected — cache and retry padded (a
-        # padded failure escapes to the forward's XLA fallback)
-        _REJECTED_NATIVE_D.add(d)
-        d_p = _pad_up(d, _LANE)
-        o, lse = _attention_pallas(
-            _pad_seq(_to_bhsd(q, d_p), s_p), _pad_seq(_to_bhsd(k, d_p), s_p),
-            _pad_seq(_to_bhsd(v, d_p), s_p), causal, scale, kv_valid)
+    o, lse = _attention_pallas(
+        _pad_seq(_to_bhsd(q, d_p), s_p), _pad_seq(_to_bhsd(k, d_p), s_p),
+        _pad_seq(_to_bhsd(v, d_p), s_p), causal, scale, kv_valid)
     # keep one lane of the broadcast lse as the backward residual
     return _from_bhsd(o[:, :s], b, s, h, d), lse[:, :s, 0]
 
 
 def _fused_attention_fwd(q, k, v, causal):
     if kernel_ok(q):
-        try:
-            out, lse = _run_kernel(q, k, v, causal)
-            return out, (q, k, v, out, lse)
-        except Exception:  # noqa: BLE001 — even padded pallas rejected
-            _REJECTED_FWD.add(_sig(_padded_len(q.shape[1]), q.shape[3],
-                                   q.dtype))
-    # fallback backward recomputes from q/k/v alone — saving `out` here
+        out, lse = _run_kernel(q, k, v, causal)
+        return out, (q, k, v, out, lse)
+    # the XLA backward recomputes from q/k/v alone — saving `out` here
     # would keep a dead [B, S, H, D] f32 alive until the backward
     return _xla_attention(q, k, v, causal), (q, k, v, None, None)
 
@@ -561,23 +466,12 @@ def _fused_attention_bwd(causal, res, g):
         _, vjp = jax.vjp(lambda q, k, v: _xla_attention(q, k, v, causal),
                          q, k, v)
         return vjp(g)
-    b, s, h, d = q.shape
-    d_p = _kernel_d(d)  # same decision as _run_kernel (cached probe)
-    s_p = _padded_len(s)
-    if _sig(s_p, d_p, q.dtype) not in _REJECTED_BWD:
-        try:
-            return _flash_bwd(q, k, v, out, lse, g, causal, d_p, s_p)
-        except Exception:  # noqa: BLE001 — per-shape Mosaic rejection of
-            # a backward kernel: cache it and recompute the exact XLA
-            # gradients from q/k/v (forward output is discarded)
-            _REJECTED_BWD.add(_sig(s_p, d_p, q.dtype))
-    _, vjp = jax.vjp(lambda q, k, v: _xla_attention(q, k, v, causal),
-                     q, k, v)
-    return vjp(g)
+    return _flash_bwd(q, k, v, out, lse, g, causal)
 
 
-def _flash_bwd(q, k, v, out, lse, g, causal, d_p, s_p):
+def _flash_bwd(q, k, v, out, lse, g, causal):
     b, s, h, d = q.shape
+    d_p, s_p = _kernel_d(d), _padded_len(s)  # the forward's decisions
     kv_valid = s if s_p != s else None
     scale = 1.0 / float(d) ** 0.5
     # delta = rowsum(dO * O) on the TRUE head dim (pad columns are zero).

@@ -247,14 +247,12 @@ class ImageTransformer(_BatchedImageStage):
         return run
 
     def _fuse_wanted(self) -> bool:
-        from .pallas_kernels import pallas_available
-
         f = self.get_or_default("fuse")
-        if f is False or not pallas_available():
-            return False
         if f is None:  # auto: interpret-mode Pallas on CPU is slower than XLA
-            return jax.default_backend() == "tpu"
-        return True
+            from .pallas_kernels import on_tpu
+
+            return on_tpu()
+        return bool(f)
 
     def _run_group(self, batch: np.ndarray) -> np.ndarray:
         if self._fuse_wanted():

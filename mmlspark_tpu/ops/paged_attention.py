@@ -43,11 +43,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .pallas_kernels import (
-    PALLAS_IMAGE_VMEM_BUDGET,
-    _interpret,
-    pallas_available,
-)
+from .pallas_kernels import PALLAS_IMAGE_VMEM_BUDGET, _interpret
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_int8",
            "paged_kernel_ok"]
@@ -65,7 +61,7 @@ def paged_kernel_ok(q, k_pool) -> bool:
     config must route to the gather, not die in Mosaic)."""
     import os
 
-    if not pallas_available() or os.environ.get("MMLSPARK_NO_PAGED_KERNEL"):
+    if os.environ.get("MMLSPARK_NO_PAGED_KERNEL"):
         return False
     b, h, d = q.shape
     np_, page, hk, dk = k_pool.shape

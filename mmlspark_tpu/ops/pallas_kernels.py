@@ -8,9 +8,9 @@ step before the backbone) is ONE fused VMEM-resident Pallas kernel — a
 single HBM read and write per image instead of XLA's worst case of separate
 normalize/transpose materializations.
 
-On CPU (tests/CI) the kernels run with `interpret=True`; on TPU they compile
-to Mosaic.  `fused_normalize_unroll` is numerically identical to the XLA
-composition (ops.image.normalize + hwc_to_chw_flat).
+Off TPU (tests/CI) the kernels run with `interpret=True`; on TPU they compile
+to Mosaic (`on_tpu` keys that on the devices the computation targets).
+`fused_normalize_unroll` is numerically identical to the XLA composition (ops.image.normalize + hwc_to_chw_flat).
 """
 from __future__ import annotations
 
@@ -21,21 +21,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["fused_normalize_unroll", "fused_resize_normalize",
-           "pallas_available"]
+from ..parallel.mesh import target_devices
+
+__all__ = ["fused_normalize_unroll", "fused_resize_normalize", "on_tpu",
+           "on_single_tpu"]
 
 
-def pallas_available() -> bool:
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
+def on_tpu(mesh=None) -> bool:
+    """Does the computation being traced run on TPU devices?  Asked of
+    the devices it targets (`parallel.mesh.target_devices`: the explicit
+    or entered mesh, else the default backend's), so a program lowered
+    for a described TPU compiles the Mosaic kernels and one pinned to
+    CPU devices on a TPU host keeps interpret mode."""
+    return target_devices(mesh)[0].platform == "tpu"
 
-        return True
-    except ImportError:  # pragma: no cover
-        return False
+
+def on_single_tpu() -> bool:
+    """... and on exactly ONE of them: where a kernel that GSPMD cannot
+    partition may sit in a plain jit (never the global device count
+    alone, so a one-device mesh on a four-chip host keeps its kernels)."""
+    devices = target_devices()
+    return len(devices) == 1 and devices[0].platform == "tpu"
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 @partial(jax.jit, static_argnames=("mean", "std"))
@@ -220,17 +230,17 @@ def fused_resize_normalize(batch: jnp.ndarray, h_out: int, w_out: int,
     """uint8/f32 [B,H,W,C] -> f32 [B,h,w,C]: cast + bilinear resize +
     per-channel normalize in one fused VMEM pass (the ImageTransformer
     resize/normalize tail of SURVEY P2; ImageTransformer.scala:127-146 +
-    the normalize feed).  Falls back to the XLA composition when Pallas is
-    unavailable, when the per-image block would overflow VMEM, or when no
-    resize is needed (identity-size inputs are a pure cast+normalize — two
-    identity matmuls would be wasted MXU work)."""
+    the normalize feed).  Takes the XLA composition when the per-image
+    block would overflow VMEM, or when no resize is needed (identity-size
+    inputs are a pure cast+normalize — two identity matmuls would be
+    wasted MXU work)."""
     batch = jnp.asarray(batch)
     _, h_in, w_in, c = batch.shape
     mean = tuple(float(m) for m in np.broadcast_to(np.asarray(mean), (c,)))
     std = tuple(float(s) for s in np.broadcast_to(np.asarray(std), (c,)))
     same_size = h_in == h_out and w_in == w_out
-    if (not pallas_available() or same_size
-            or not _fits_vmem(batch.shape, h_out, w_out, batch.dtype.itemsize)):
+    if same_size or not _fits_vmem(batch.shape, h_out, w_out,
+                                   batch.dtype.itemsize):
         from .image import normalize, resize
 
         x = batch.astype(jnp.float32)
@@ -255,7 +265,7 @@ def fused_normalize_unroll(batch: jnp.ndarray,
     c = batch.shape[-1]
     mean = tuple(float(m) for m in np.broadcast_to(np.asarray(mean), (c,)))
     std = tuple(float(s) for s in np.broadcast_to(np.asarray(std), (c,)))
-    if not pallas_available() or jax.default_backend() == "tpu":
+    if on_tpu():
         from .image import hwc_to_chw_flat, normalize
 
         return hwc_to_chw_flat(normalize(batch, mean, std))

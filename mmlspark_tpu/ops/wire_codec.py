@@ -152,15 +152,13 @@ def rle_kernel_ok() -> bool:
     default (the XLA repeat path is faster through CPU interpret mode);
     MMLSPARK_RLE_KERNEL=1 forces it so tier-1 tests exercise the kernel
     in interpret mode, MMLSPARK_NO_RLE_KERNEL wins over both."""
-    from .pallas_kernels import pallas_available
-
-    if not pallas_available() or os.environ.get("MMLSPARK_NO_RLE_KERNEL"):
+    if os.environ.get("MMLSPARK_NO_RLE_KERNEL"):
         return False
     if os.environ.get("MMLSPARK_RLE_KERNEL"):
         return True
-    import jax
+    from .pallas_kernels import on_tpu
 
-    return jax.default_backend() == "tpu"
+    return on_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -191,51 +189,58 @@ def _pallas_decode(r: int, n_pad: int):
     nw = r // w          # run windows (r is a pow2 >= 2*BLOCK)
     nb = n_pad // BLOCK  # output blocks
 
+    # Mosaic layout: the run tables enter as COLUMNS ([nw, w, 1] blocks
+    # of (w, 1)) and each output block leaves as a ROW ((1, BLOCK)), so
+    # runs lie along sublanes and output positions along lanes — every
+    # intermediate is a plain 2D [2w, BLOCK] tile, the two reductions
+    # run over sublanes, and nothing has to be transposed in the kernel
+    # (a (1, w) block of an [nw, w] table is not a legal TPU block).
     def kernel(fr_ref, v0_ref, v1_ref, e0_ref, e1_ref, o_ref):
         p = pl.program_id(0)
         w0 = fr_ref[p] // w
         # second window duplicates the first when clamped at the table's
         # edge — mask its contribution instead of double-counting
         dup = (jnp.minimum(w0 + 1, nw - 1) == w0)
-        ends = jnp.concatenate([e0_ref[0], e1_ref[0]]).astype(jnp.int32)
-        vals = jnp.concatenate([v0_ref[0], v1_ref[0]]).astype(jnp.int32)
+        ends = jnp.concatenate([e0_ref[0], e1_ref[0]], axis=0)  # [2w, 1]
+        vals = jnp.concatenate([v0_ref[0], v1_ref[0]], axis=0)
+        run = jax.lax.broadcasted_iota(jnp.int32, (2 * w, BLOCK), 0)
         pos = p * BLOCK + jax.lax.broadcasted_iota(
-            jnp.int32, (BLOCK, 2 * w), 0)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 2 * w), 1)
-        live = (lane < w) | ~dup
+            jnp.int32, (2 * w, BLOCK), 1)
+        live = (run < w) | ~dup
         # the run holding each position: count of window ends <= pos
         # (runs before the window all ended by first_run's definition)
-        covered = (ends[None, :] <= pos) & live
-        local = jnp.sum(covered.astype(jnp.int32), axis=1)  # [BLOCK]
-        onehot = (local[:, None] == lane) & live
-        o_ref[0] = jnp.sum(
-            jnp.where(onehot, vals[None, :], 0), axis=1).astype(jnp.uint8)
+        covered = (ends <= pos) & live
+        local = jnp.sum(covered.astype(jnp.int32), axis=0,
+                        keepdims=True)                          # [1, BLOCK]
+        onehot = (local == run) & live
+        o_ref[0] = jnp.sum(jnp.where(onehot, vals, 0), axis=0,
+                           keepdims=True)
+
+    def window(shift):
+        return pl.BlockSpec(
+            (1, w, 1),
+            lambda p, fr: (jnp.minimum(fr[p] // w + shift, nw - 1), 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # first_run
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, w), lambda p, fr: (fr[p] // w, 0)),
-            pl.BlockSpec((1, w),
-                         lambda p, fr: (jnp.minimum(fr[p] // w + 1, nw - 1),
-                                        0)),
-            pl.BlockSpec((1, w), lambda p, fr: (fr[p] // w, 0)),
-            pl.BlockSpec((1, w),
-                         lambda p, fr: (jnp.minimum(fr[p] // w + 1, nw - 1),
-                                        0)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda p, fr: (p, 0)),
+        in_specs=[window(0), window(1), window(0), window(1)],
+        out_specs=pl.BlockSpec((1, 1, BLOCK), lambda p, fr: (p, 0, 0)),
     )
 
     def decode(first_run, values, ends):
+        # the wire stays uint8/int32; the kernel computes in int32 (the
+        # widening and the final narrowing are XLA's, on R- and N-sized
+        # vectors beside a [2w, BLOCK] compare per output block)
+        vcol = values.astype(jnp.int32).reshape(nw, w, 1)
+        ecol = ends.astype(jnp.int32).reshape(nw, w, 1)
         out = pl.pallas_call(
             kernel,
-            out_shape=jax.ShapeDtypeStruct((nb, BLOCK), jnp.uint8),
+            out_shape=jax.ShapeDtypeStruct((nb, 1, BLOCK), jnp.int32),
             grid_spec=grid_spec,
             interpret=_interpret(),
-        )(first_run, values.reshape(nw, w), values.reshape(nw, w),
-          ends.reshape(nw, w), ends.reshape(nw, w))
-        return out.reshape(n_pad)
+        )(first_run, vcol, vcol, ecol, ecol)
+        return out.reshape(n_pad).astype(jnp.uint8)
 
     return jax.jit(decode)
 

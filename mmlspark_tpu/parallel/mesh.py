@@ -14,31 +14,12 @@ annotations; they ride ICI within a slice and DCN across slices.
 from __future__ import annotations
 
 import contextlib
-import inspect
 import math
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-
-try:  # jax >= 0.6 re-exports shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # the 0.4.x line keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_HAS_VMA = "check_vma" in inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, **kw):
-    """Version-compat `shard_map`: ONE import site for the whole package
-    (jax moved it out of experimental in 0.6 and renamed `check_rep` to
-    `check_vma` with the varying-manual-axes type system in 0.7 — every
-    caller goes through here so no module breaks on either line)."""
-    if "check_vma" in kw and not _SM_HAS_VMA:
-        kw["check_rep"] = kw.pop("check_vma")
-    elif "check_rep" in kw and _SM_HAS_VMA:
-        kw["check_vma"] = kw.pop("check_rep")
-    return _shard_map(f, **kw)
 import numpy as np
+from jax import shard_map  # re-exported: the package's one import site
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
@@ -46,12 +27,14 @@ __all__ = [
     "make_mesh",
     "MeshPlan",
     "default_mesh",
+    "target_devices",
     "MeshContext",
     "batch_sharding",
     "replicated_sharding",
     "addressable_shard_layout",
     "host_device_groups",
     "shard_batch",
+    "shard_map",
     "pad_to_multiple",
 ]
 
@@ -178,6 +161,19 @@ def default_mesh() -> Mesh:
     if _CURRENT["mesh"] is not None:
         return _CURRENT["mesh"]
     return make_mesh()
+
+
+def target_devices(mesh: Optional[Mesh] = None) -> List:
+    """The devices the computation being traced will run on — THE rule
+    every kernel-vs-XLA dispatch keys on (ops.pallas_kernels.on_tpu /
+    on_single_tpu): the explicit `mesh`'s devices, else
+    the entered MeshContext's, else every device of the default backend
+    (what `default_mesh()` lays the data axis over).  A one-device
+    computation on a multi-chip host therefore declares itself with
+    ``MeshContext(make_mesh(devices=[dev]))`` and keeps its kernels; the
+    global `jax.device_count()` never decides."""
+    mesh = mesh if mesh is not None else _CURRENT["mesh"]
+    return list(mesh.devices.flat) if mesh is not None else jax.devices()
 
 
 @contextlib.contextmanager

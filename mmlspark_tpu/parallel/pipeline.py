@@ -60,13 +60,8 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x: jnp.ndarray,
         params_local = jax.tree.map(lambda a: a[0], params_local)
         rank = jax.lax.axis_index(axis)
         # the carry becomes device-varying after the first ppermute; the
-        # zero init must carry the same varying-axes type (jax >= 0.7
-        # tracks varying manual axes — 0.4.x shard_map has no such type,
-        # so there the plain zeros carry is already correct)
-        buf = jnp.zeros_like(xs[0])
-        pcast = getattr(jax.lax, "pcast", None)
-        if pcast is not None:
-            buf = pcast(buf, (axis,), to="varying")
+        # zero init must carry the same varying-axes type
+        buf = jax.lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
 
         def body(buf, t):
             # stage 0 ingests microbatch t (while any remain); downstream
@@ -100,7 +95,7 @@ def gpipe_spmd_apply(stage_fn: Callable, stacked_params, x: jnp.ndarray,
     which is what lets it COMPOSE with data-parallel batch sharding and
     megatron tensor rules on one 3D mesh (shard_map bodies see local
     arrays; tensor-parallel collectives inside them would have to be
-    hand-written, and jax 0.4.x cannot mix auto axes in).
+    hand-written).
 
     ``x [M, mb, ...]`` microbatches; ``stacked_params`` leaves carry a
     leading stage dim P (any further leading dims — e.g. the

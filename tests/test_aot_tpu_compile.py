@@ -1,0 +1,280 @@
+"""Ask the chip's compiler before asking the chip: every Pallas kernel of
+chip_smoke.py's phases, at the smoke's real shapes, compiled for a
+DESCRIBED v5e (the TPU compiler is installed; no device is attached), plus
+the whole lm_train program and the serving decode step.  A kernel that had
+passed every interpret-mode test was refused this way (the RLE decoder's
+(1, w) blocks), so these guard every later PR at no chip time.
+
+Nothing runs here — a compile that passes is not a chip run.  The shapes
+come from chip_smoke.SIZES["full"], so the smoke and this file cannot
+drift apart.  Steering is the program's own: the kernels ask the devices
+their computation targets (ops.pallas_kernels.on_tpu), and the tests
+declare those with MeshContext(<described device>).  Named `aot_` so the
+file runs first: tier-1 is cut by its clock and a file named late guards
+nothing.  Skipped, not failed, where the topology cannot be described.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import chip_smoke
+from mmlspark_tpu.ops import attention_kernels as ak
+from mmlspark_tpu.ops import paged_attention as pa
+from mmlspark_tpu.ops import pallas_kernels as pk
+from mmlspark_tpu.ops import wire_codec as wc
+from mmlspark_tpu.parallel.mesh import MeshContext, make_mesh
+
+FULL = chip_smoke.SIZES["full"]
+LM, TRAIN, SERVE = FULL["lm"], FULL["lm_train"], FULL["lm_serve"]
+VIT, FEAT = FULL["vit"], FULL["featurize"]
+HEADS = LM["num_heads"]
+HEAD_DIM = LM["embed_dim"] // HEADS
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """MeshContext over one described v5e chip, compile cache off (such
+    an executable can be written to the cache but not read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    mesh = make_mesh(devices=[topo.devices[0]])
+    try:
+        with MeshContext(mesh):
+            yield mesh
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
+def _shape(mesh, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, P()))
+
+
+def _compile(fn, *args, **kw):
+    return fn.lower(*args, **kw).compile()
+
+
+def _assert_one_kernel(compiled):
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# ---- flash attention: forward, dK/dV, dQ ----------------------------------
+def _vit_seq():
+    s = (VIT["side"] // 16) ** 2          # ViT-B/16: 196 patches
+    return s, ak._padded_len(s)           # ... on the 256 grid
+
+
+# tag -> (B*H, S_grid, D, dtype, kv_valid, causal)
+ATTN_SHAPES = {
+    "lm_train_s1024_d64": (TRAIN["batch"] * HEADS, TRAIN["seq"], HEAD_DIM,
+                           jnp.bfloat16, None, True),
+    "vit_s256_kv196_d64": (VIT["batch"] * 12, _vit_seq()[1], 64,
+                           jnp.bfloat16, _vit_seq()[0], False),
+    "s1024_d128": (96, 1024, 128, jnp.bfloat16, None, True),
+    # the rule in ak._kernel_d: 64-multiples run native, unpadded
+    "s256_d192_native": (8, 256, 192, jnp.bfloat16, None, True),
+}
+
+
+def _attn_args(mesh, tag):
+    bh, s, d, dtype, kv_valid, causal = ATTN_SHAPES[tag]
+    q = _shape(mesh, (bh, s, d), dtype)
+    stat = _shape(mesh, (bh, s, 128), jnp.float32)
+    return q, stat, causal, 1.0 / float(d) ** 0.5, kv_valid
+
+
+@pytest.mark.parametrize("tag", list(ATTN_SHAPES))
+def test_attention_forward_compiles(v5e, tag):
+    q, _stat, causal, scale, kv_valid = _attn_args(v5e, tag)
+    _assert_one_kernel(_compile(ak._attention_pallas, q, q, q,
+                                causal, scale, kv_valid))
+
+
+@pytest.mark.parametrize("tag", list(ATTN_SHAPES))
+def test_attention_dkdv_compiles(v5e, tag):
+    q, stat, causal, scale, kv_valid = _attn_args(v5e, tag)
+    _assert_one_kernel(_compile(ak._attention_bwd_dkdv, q, q, q, q, stat,
+                                stat, causal, scale, kv_valid))
+
+
+@pytest.mark.parametrize("tag", list(ATTN_SHAPES))
+def test_attention_dq_compiles(v5e, tag):
+    q, stat, causal, scale, kv_valid = _attn_args(v5e, tag)
+    _assert_one_kernel(_compile(ak._attention_bwd_dq, q, q, q, q, stat,
+                                stat, causal, scale, kv_valid))
+
+
+def _serve_buckets():
+    buckets = set()
+    for n in SERVE["prompt_lens"]:
+        b = 16
+        while b < n:
+            b *= 2
+        buckets.add(b)
+    return sorted(buckets)
+
+
+@pytest.mark.parametrize("bucket", _serve_buckets())
+def test_serving_prefill_attention_compiles(v5e, bucket):
+    """lm_serve's admission prefill runs the f32 forward kernel at every
+    prompt bucket (short S: block_q = S)."""
+    q = _shape(v5e, (HEADS, bucket, HEAD_DIM), jnp.float32)
+    assert ak.kernel_ok(_shape(v5e, (1, bucket, HEADS, HEAD_DIM),
+                               jnp.float32))
+    _assert_one_kernel(_compile(ak._attention_pallas, q, q, q, True,
+                                0.125, None))
+
+
+# ---- fused resize + normalize (featurize) ---------------------------------
+_MEAN, _STD = (103.53, 116.28, 123.675), (57.375, 57.12, 58.395)
+_RESIZED = [hw for hw in FEAT["sizes"] if hw != (FEAT["side"], FEAT["side"])]
+
+
+@pytest.mark.parametrize("dtype", [jnp.uint8, jnp.float32],
+                         ids=["uint8", "f32"])
+@pytest.mark.parametrize("hw", _RESIZED, ids=lambda hw: f"{hw[0]}x{hw[1]}")
+def test_resize_normalize_compiles(v5e, hw, dtype):
+    h, w = hw
+    side = FEAT["side"]
+    assert pk._fits_vmem((1, h, w, 3), side, side, jnp.dtype(dtype).itemsize)
+    consts = [_shape(v5e, c.shape, c.dtype) for c in
+              pk._resize_consts(h, w, 3, side, side, _MEAN, _STD)]
+    _assert_one_kernel(_compile(
+        pk._fused_resize_normalize_run,
+        _shape(v5e, (FEAT["batch_size"], h, w, 3), dtype), *consts,
+        h_out=side, w_out=side))
+
+
+def test_fused_affine_apply_compiles(v5e):
+    """An ImageTransformer chain (resize + gray + normalize) composed
+    into the same two-matmul kernel, 3 channels in and 1 out."""
+    consts = pk.build_affine_pipeline(
+        [("resize", {"height": 224, "width": 224}),
+         ("colorFormat", {"format": "bgr2gray"}),
+         ("normalize", {"mean": [100.0], "std": [50.0]})], 256, 256, 3)
+    assert pk.affine_pipeline_fits_vmem(consts, 1)
+    padded = [_shape(v5e, c.shape, c.dtype)
+              for c in pk._affine_consts(*consts)]
+    _assert_one_kernel(_compile(
+        pk._fused_resize_normalize_run,
+        _shape(v5e, (64, 256, 256, 3), jnp.uint8), *padded,
+        h_out=224, w_out=224, c_out=1))
+
+
+# ---- paged decode attention (lm_serve) ------------------------------------
+def _paged_shapes(mesh, pool_dtype):
+    b, page = SERVE["max_slots"], SERVE["page_size"]
+    mp = LM["max_len"] // page
+    n_pages = b * mp + 1                  # the batcher's default pool
+    pool = _shape(mesh, (n_pages, page, HEADS, HEAD_DIM), pool_dtype)
+    scales = _shape(mesh, (n_pages, page, HEADS), jnp.float32)
+    return (b, mp, pool, scales, _shape(mesh, (b, mp), jnp.int32),
+            _shape(mesh, (b,), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_attention_compiles(v5e, dtype):
+    b, _mp, pool, _scales, table, pos = _paged_shapes(v5e, dtype)
+    q = _shape(v5e, (b, HEADS, HEAD_DIM), dtype)
+    assert pa.paged_kernel_ok(q, pool)
+    _assert_one_kernel(_compile(pa._paged_pallas, q, pool, pool, table, pos))
+
+
+def test_paged_attention_int8_compiles(v5e):
+    b, _mp, pool, scales, table, pos = _paged_shapes(v5e, jnp.int8)
+    q = _shape(v5e, (b, HEADS, HEAD_DIM), jnp.float32)
+    assert pa.paged_kernel_ok(q, pool)
+    _assert_one_kernel(_compile(pa._paged_pallas_int8, q, pool, scales,
+                                pool, scales, table, pos))
+
+
+# ---- RLE wire decoder ------------------------------------------------------
+def test_rle_decoder_compiles(v5e):
+    runs, n_pad = 4096, 4096 * wc.BLOCK
+    assert wc.rle_kernel_ok()
+    _assert_one_kernel(_compile(
+        wc._pallas_decode(runs, n_pad),
+        _shape(v5e, (n_pad // wc.BLOCK,), jnp.int32),
+        _shape(v5e, (runs,), jnp.uint8), _shape(v5e, (runs,), jnp.int32)))
+
+
+# ---- whole programs --------------------------------------------------------
+def _lm_variables(mesh, model, tokens_shape):
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros(tokens_shape, jnp.int32))["params"])
+    return jax.tree.map(lambda a: _shape(mesh, a.shape, a.dtype), params)
+
+
+def test_lm_train_epoch_compiles_with_36_kernels(v5e):
+    """The whole make_lm_train_epoch program at the bench width: 12
+    layers x (forward, dK/dV, dQ) custom calls, and it fits one chip."""
+    import optax
+
+    from mmlspark_tpu.models.training import make_lm_train_epoch
+
+    b, s, steps = TRAIN["batch"], TRAIN["seq"], TRAIN["steps"]
+    model = chip_smoke._lm(dict(LM, max_len=s), jnp.bfloat16)
+    opt = optax.adam(3e-4)
+    params = _lm_variables(v5e, model, (b, s))
+    opt_state = jax.eval_shape(opt.init, params)
+    opt_state = jax.tree.map(lambda a: _shape(v5e, a.shape, a.dtype),
+                             opt_state)
+    tokens = jax.ShapeDtypeStruct(
+        (steps, b, s), jnp.int32,
+        sharding=NamedSharding(v5e, P(None, "data")))
+    compiled = _compile(make_lm_train_epoch(model, opt, mesh=v5e,
+                                            donate=False),
+                        params, opt_state, tokens)
+    assert compiled.as_text().count("tpu_custom_call") == \
+        3 * LM["num_layers"] == 36
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+def test_paged_decode_step_compiles_with_12_kernels(v5e):
+    """The batcher's slot-decode program (TransformerLM.decode_step over
+    page pools): one page-walk kernel per layer."""
+    model = chip_smoke._lm(LM, jnp.float32)
+    b, _mp, pool, _scales, table, pos = _paged_shapes(v5e, jnp.float32)
+    variables = {"params": _lm_variables(v5e, model, (1, 8))}
+    cache = tuple((pool, pool) for _ in range(LM["num_layers"]))
+    step = jax.jit(lambda v, t, c, p, pt: model.apply(
+        v, t, c, p, pt, method=model.decode_step))
+    compiled = _compile(step, variables, _shape(v5e, (b, 1), jnp.int32),
+                        cache, pos, table)
+    assert compiled.as_text().count("tpu_custom_call") == LM["num_layers"]
+
+
+def test_described_context_does_not_leak(v5e):
+    """Inside the described-device MeshContext the kernels compile for
+    the chip; a nested context over the attached CPU devices returns to
+    interpret mode — and the SAME shape that compiled for the chip above
+    still runs there (the jit cache keys on the mesh context, so the two
+    traces never meet)."""
+    bh, s, d, dtype, kv_valid, causal = ATTN_SHAPES["s256_d192_native"]
+    assert pk.on_tpu()
+    with MeshContext(make_mesh()):
+        assert not pk.on_tpu()
+        q = jnp.ones((bh, s, d), dtype)
+        out, _lse = ak._attention_pallas(q, q, q, causal,
+                                         1.0 / float(d) ** 0.5, kv_valid)
+        assert np.isfinite(np.asarray(out, np.float32)).all()

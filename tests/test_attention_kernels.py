@@ -13,18 +13,11 @@ from mmlspark_tpu.ops.attention_kernels import (
 )
 from mmlspark_tpu.parallel.ring_attention import full_attention
 
-# On a real TPU the kernel's and the reference's matmuls both run on the
-# MXU, whose default f32 precision is bf16x3-pass accumulation — the two
-# paths round in different orders, so f32 "parity" is ~1e-3 there, not
-# 2e-5 (observed on-chip max abs diff 5e-3, tools/chip_logs/
-# 20260801T082912Z-tpu-tests.log). CPU interpret mode reproduces the XLA
-# composition at true f32, where the tight tolerance is the real test.
-_ON_TPU = jax.default_backend() == "tpu"
-# 2x margin over the observed on-chip diffs: forward max 5e-3, grad max
-# 0.036 (the sum-of-squares loss amplifies the forward's bf16 noise) —
-# tight enough that a Mosaic-only ~1e-2 forward regression still fails.
-F32_TOL = dict(atol=1e-2, rtol=1e-2) if _ON_TPU else dict(atol=2e-5, rtol=2e-5)
-GRAD_TOL = dict(atol=7.5e-2, rtol=7.5e-2) if _ON_TPU else dict(atol=1e-4, rtol=1e-4)
+# interpret mode reproduces the XLA composition at true f32, so the tight
+# tolerance is the real test; the run on the chip (MXU rounding, ~1e-2)
+# is chip_smoke.py's, with its own printed tolerances
+F32_TOL = dict(atol=2e-5, rtol=2e-5)
+GRAD_TOL = dict(atol=1e-4, rtol=1e-4)
 
 
 @pytest.fixture(scope="module")
@@ -189,139 +182,46 @@ def test_transformer_default_dispatch_uses_kernel(monkeypatch):
                                atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.skipif(not _ON_TPU,
-                    reason="Mosaic compile check needs a real TPU")
-def test_attention_kernel_compiles_on_tpu():
-    rng = np.random.default_rng(3)
-    q, k, v = (jnp.asarray(rng.normal(size=(1, 512, 4, 128)), jnp.bfloat16)
-               for _ in range(3))
-    out = fused_attention(q, k, v, True)
-    ref = full_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=0.02, rtol=0.02)
+# ---- no hidden fallback: a refused kernel raises ---------------------------
+
+def _boom(*a, **kw):
+    raise RuntimeError("Mosaic rejected this shape")
 
 
-# ---- per-shape Mosaic-rejection self-healing -------------------------------
-
-@pytest.fixture
-def _clean_rejection_caches():
-    """The rejection caches are process-global by design (self-heal once,
-    never retry); tests that poison them must restore the pre-test state."""
-    from mmlspark_tpu.ops import attention_kernels as ak
-
-    saved = (set(ak._REJECTED_NATIVE_D), set(ak._REJECTED_FWD),
-             set(ak._REJECTED_BWD))
-    yield
-    for cache, prev in zip((ak._REJECTED_NATIVE_D, ak._REJECTED_FWD,
-                            ak._REJECTED_BWD), saved):
-        cache.clear()
-        cache.update(prev)
-
-
-def test_forward_pallas_rejection_heals_to_xla(monkeypatch,
-                                               _clean_rejection_caches):
-    """A pallas_call that raises for a production shape must fall back to
-    the XLA composition (numerically, not just route), cache the
-    rejection, and flip kernel_ok for that signature."""
+def test_forward_kernel_failure_raises(monkeypatch):
+    """A shape `kernel_ok` admits whose pallas_call raises must surface
+    the error — never quietly run the XLA composition instead (a run
+    that "passes" that way says nothing about the kernel)."""
     from mmlspark_tpu.ops import attention_kernels as ak
 
     rng = np.random.default_rng(5)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 128, 2, 128)), jnp.float32)
                for _ in range(3))
     assert ak.kernel_ok(q)
-
-    def boom(*a, **kw):
-        raise RuntimeError("Mosaic rejected this shape")
-
-    monkeypatch.setattr(ak, "_attention_pallas", boom)
-    got = fused_attention(q, k, v, True)
-    ref = full_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-    assert not ak.kernel_ok(q)  # cached: never retried for this signature
-    # and with the kernel healthy again, OTHER signatures still take it
-    q2 = jnp.asarray(rng.normal(size=(1, 256, 2, 128)), jnp.float32)
-    assert ak.kernel_ok(q2)
+    monkeypatch.setattr(ak, "_attention_pallas", _boom)
+    with pytest.raises(RuntimeError, match="Mosaic rejected"):
+        fused_attention(q, k, v, True)
+    assert ak.kernel_ok(q)  # the predicate is shape-only: nothing cached
 
 
-def test_native_d64_rejection_retries_padded(monkeypatch,
-                                             _clean_rejection_caches):
-    """A per-shape failure of the NATIVE 64-lane path must retry padded
-    to the 128 lane (not collapse straight to XLA) and remember the head
-    dim, exactly the ADVICE.md scenario: d=192/320 enabled off the tiny
-    f32 probe alone."""
-    from mmlspark_tpu.ops import attention_kernels as ak
-
-    rng = np.random.default_rng(6)
-    q, k, v = (jnp.asarray(rng.normal(size=(1, 128, 2, 64)), jnp.float32)
-               for _ in range(3))
-    monkeypatch.setattr(ak, "_native_d64_ok", lambda: True)
-    assert ak._kernel_d(64) == 64
-
-    real = ak._attention_pallas
-    seen_d = []
-
-    def native_fails(qp, kp, vp, *a, **kw):
-        seen_d.append(qp.shape[-1])
-        if qp.shape[-1] % 128:
-            raise RuntimeError("Mosaic rejected the 64-minor tile")
-        return real(qp, kp, vp, *a, **kw)
-
-    monkeypatch.setattr(ak, "_attention_pallas", native_fails)
-    got = fused_attention(q, k, v, True)
-    ref = full_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-    assert seen_d == [64, 128]          # native try, then the padded retry
-    assert 64 in ak._REJECTED_NATIVE_D  # cached...
-    fused_attention(q, k, v, True)
-    assert seen_d == [64, 128, 128]     # ...so the retry never repeats
-
-
-def test_backward_pallas_rejection_heals_to_xla_grads(
-        monkeypatch, _clean_rejection_caches):
-    """A backward-kernel rejection must cache and recompute the exact XLA
-    gradients — training keeps running, with correct grads, on a shape
-    whose flash backward Mosaic refuses."""
+@pytest.mark.parametrize("kernel", ["_attention_bwd_dkdv",
+                                    "_attention_bwd_dq"])
+def test_backward_kernel_failure_raises(monkeypatch, kernel):
     from mmlspark_tpu.ops import attention_kernels as ak
 
     rng = np.random.default_rng(7)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 128, 2, 128)), jnp.float32)
                for _ in range(3))
-
-    def boom(*a, **kw):
-        raise RuntimeError("Mosaic rejected the dkdv kernel")
-
-    monkeypatch.setattr(ak, "_attention_bwd_dkdv", boom)
-
-    def loss_fused(q, k, v):
-        return jnp.sum(fused_attention(q, k, v, True) ** 2)
-
-    def loss_xla(q, k, v):
-        return jnp.sum(full_attention(q, k, v, causal=True) ** 2)
-
-    g1 = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4, rtol=1e-4)
-    assert ak._REJECTED_BWD
+    monkeypatch.setattr(ak, kernel, _boom)
+    with pytest.raises(RuntimeError, match="Mosaic rejected"):
+        jax.grad(lambda q: jnp.sum(fused_attention(q, k, v, True) ** 2))(q)
 
 
-def test_probe_parity_check_catches_wrong_numerics(monkeypatch):
-    """The d64 probe must fail a kernel that compiles and runs but
-    returns wrong numbers (the compile-on-zeros blind spot): a lowering
-    that silently zeroes the output passes block_until_ready and would
-    have enabled the native path under the old probe."""
+@pytest.mark.parametrize("d,want", [(64, 64), (128, 128), (192, 192),
+                                    (80, 128), (160, 256)])
+def test_kernel_head_dim_rule(d, want):
+    """64-multiples run native (what the described-v5e compiles in
+    tests/test_aot_tpu_compile.py admit); the rest pad to the lane."""
     from mmlspark_tpu.ops import attention_kernels as ak
 
-    assert ak._probe_native_d64() is True  # interpret-mode kernel is exact
-
-    real = ak._attention_pallas
-
-    def wrong(qp, kp, vp, *a, **kw):
-        o, lse = real(qp, kp, vp, *a, **kw)
-        return o * 0.0, lse
-
-    monkeypatch.setattr(ak, "_attention_pallas", wrong)
-    assert ak._probe_native_d64() is False
+    assert ak._kernel_d(d) == want

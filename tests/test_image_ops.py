@@ -275,26 +275,6 @@ def test_image_preprocess_sharded_fallbacks_stay_correct():
                                np.asarray(ref(x2)), atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.skipif("__import__('jax').default_backend() != 'tpu'",
-                    reason="Mosaic compile check needs a real TPU")
-def test_pallas_kernels_compile_on_tpu():
-    """Mosaic-path compile check — runs only on real TPU (the driver's
-    bench environment), validating the kernels outside interpret mode."""
-    import jax.numpy as jnp
-
-    from mmlspark_tpu.ops.pallas_kernels import (
-        fused_normalize_unroll,
-        fused_resize_normalize,
-    )
-
-    rng = np.random.default_rng(8)
-    x = jnp.asarray(rng.integers(0, 256, size=(4, 64, 64, 3), dtype=np.uint8))
-    out = fused_resize_normalize(x, 32, 32, (127.0,), (64.0,))
-    assert out.shape == (4, 32, 32, 3)
-    out2 = fused_normalize_unroll(jnp.asarray(out), (0.0,), (1.0,))
-    assert out2.shape == (4, 3 * 32 * 32)
-
-
 def test_pallas_vmem_gate_and_identity_shortcut():
     """Oversized inputs must fall back to XLA, never attempt a Mosaic
     compile that would overflow VMEM; identity-size inputs skip the

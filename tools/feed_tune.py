@@ -1,10 +1,10 @@
 """Feed autotuner: sweep chunk size x depth x shard strategy, record the
 winner into the feed's config.
 
-The right feed shape depends on the link, not the code: a high-latency
-tunneled chip wants deep pipelines and huge coalesced packs, a local
-multi-chip host wants per-shard parallel puts, and a thin wire wants the
-RLE compressed path's encode tax.  Rather than hardcode one guess, this
+The right feed shape depends on the host-to-device path, not the code:
+a high fixed cost per transfer wants deep pipelines and huge coalesced
+packs, a multi-chip host wants per-shard parallel puts, and a thin wire
+wants the RLE compressed path's encode tax.  Rather than hardcode one guess, this
 tool measures every combination on a synthetic workload shaped like the
 real one and persists the winner:
 
