@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU and never touch a chip:
+`python -m pytest benchmarks/tests -q` (not part of tier-1)."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (ROOT, BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
