@@ -522,9 +522,8 @@ class FlowGraph:
                 continue
             t0 = time.perf_counter()
             try:
-                # profiler annotation only when armed via
-                # enable_device_annotations() — same name as the
-                # record_span below so timelines and traces line up
+                # profiler annotation under the same name as the
+                # record_span below, so timelines and traces line up
                 with core_telemetry.device_annotation(
                         f"{self.span_prefix}.{stage.name}"):
                     out = stage.run_item(fi.value, point)

@@ -27,8 +27,10 @@ New surface (see docs/observability.md):
   ``render_chrome_trace()`` (``/trace.json`` → Perfetto).
 * device — ``track_compiles()`` / ``watch_compiles()`` (the XLA compile
   sentry), ``sample_device_memory()`` / ``start_memory_sampler()`` (HBM
-  + live-buffer gauges), ``enable_device_annotations()`` (opt-in
-  ``jax.profiler.TraceAnnotation`` on stage spans).
+  + live-buffer gauges); ``span()``, ``phase()`` and
+  ``device_annotation()`` enter a ``jax.profiler.TraceAnnotation`` of
+  their name whenever jax is imported, so a profiler capture holds the
+  program's spans on the device trace's clock.
 * goodput plane — ``STORE`` (:class:`timeseries.TimeSeriesStore`,
   bounded recent history with rate/delta/quantile-over-time) and
   ``LEDGER`` (:class:`goodput.GoodputLedger`, per-step timelines +
@@ -62,6 +64,7 @@ from .spans import (
     current_trace_id,
     extract_trace,
     get_trace,
+    phase,
     recent_spans,
     record_span,
     span,
@@ -101,7 +104,6 @@ from .device import (
     CompileSentry,
     MemorySampler,
     device_annotation,
-    enable_device_annotations,
     sample_device_memory,
     start_memory_sampler,
     track_compiles,
@@ -121,7 +123,7 @@ __all__ = [
     "BUCKET_FAMILIES", "HISTOGRAM_FAMILY", "buckets_for",
     "DECLARED_METRICS", "is_declared",
     # spans
-    "span", "record_span", "use_trace", "current_context",
+    "span", "phase", "record_span", "use_trace", "current_context",
     "current_trace_id", "trace_headers", "extract_trace", "get_trace",
     "span_tree", "recent_spans", "clear_spans",
     # exposition
@@ -139,7 +141,7 @@ __all__ = [
     # device (compile sentry, memory gauges, annotations)
     "SENTRY", "CompileSentry", "track_compiles", "watch_compiles",
     "sample_device_memory", "MemorySampler", "start_memory_sampler",
-    "enable_device_annotations", "device_annotation",
+    "device_annotation",
 ]
 
 

@@ -1,5 +1,5 @@
 """Device-level observability: the XLA compile sentry, HBM memory
-gauges, and opt-in `jax.profiler` trace annotations.
+gauges, and `jax.profiler` trace annotations.
 
 Everything host-side in this package watches OUR code; this module
 watches the runtime underneath it.  Three concerns:
@@ -9,7 +9,12 @@ watches the runtime underneath it.  Three concerns:
   that records every XLA compile as an `xla.compile` span — a child of
   the active trace when one is open, so a serving request that triggered
   a compile shows it in `/trace/<id>` — plus an `xla.compile.latency`
-  histogram observation and an `xla.compile.count` bump.  After the
+  histogram observation and an `xla.compile.count` bump.  The two
+  phases before the backend compile ride the same listener into
+  `xla.compile.trace.latency` (jaxpr tracing, SELF time: a function
+  traced inside another's trace is subtracted from the outer one) and
+  `xla.compile.lower.latency` (jaxpr to MLIR): with a warm persistent
+  cache they, not the backend compile, are what set-up pays.  After the
   caller DECLARES warmup over (`SENTRY.end_warmup()`), every further
   compile is flagged as a steady-state recompile: `xla.compile.hot_path`
   counter + WARNING log.  This jax version's monitoring events carry no
@@ -32,13 +37,15 @@ watches the runtime underneath it.  Three concerns:
   `start_memory_sampler(interval_s)` runs it on a daemon thread;
   `ServingServer` best-effort samples on every `/metrics` scrape.
 
-* **Device annotations** — `enable_device_annotations()` arms the span
-  layer so `span()` additionally enters a `jax.profiler.TraceAnnotation`
-  for matching span names (`training.step`, `pipeline.<stage>`, ...),
-  and `device_annotation(name)` gives already-measured sites
-  (`feed._device_put`) the same opt-in wrapper.  Off by default: on real
-  hardware under a profiler capture the device timeline then carries our
-  span names.
+* **Device annotations** — when jax is already imported, `span(name)`,
+  `phase(name)` and `device_annotation(name)` enter a
+  `jax.profiler.TraceAnnotation(name)`; when it is not, they do not.
+  There is no switch: with no capture running an annotation costs about
+  a microsecond, and under a capture it lands in the profiler's host
+  plane, on the one clock the device trace shares.  "Tracing on" is "a
+  profile is being captured".  `device_annotation(name)` is for
+  already-measured sites (`feed._device_put`) whose spans go through
+  `record_span`.
 
 This module imports no jax at module scope — the telemetry package must
 stay importable (and `/metrics` servable) in processes that never touch
@@ -46,9 +53,11 @@ a device.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import sys
 import threading
+import time
 from typing import Any, Dict, Iterable, Optional, Tuple
 
 from . import spans as _spans
@@ -59,13 +68,14 @@ from .records import log_verb, logger
 __all__ = ["CompileSentry", "SENTRY", "track_compiles", "watch_compiles",
            "describe_abstract_shapes", "sample_device_memory",
            "MemorySampler", "start_memory_sampler",
-           "enable_device_annotations", "device_annotation",
-           "DEFAULT_ANNOTATION_PREFIXES"]
+           "device_annotation"]
 
 # the one monitoring event that means "XLA produced an executable";
-# jaxpr tracing / MLIR lowering durations ride the same listener but are
+# jaxpr tracing / MLIR lowering durations ride the same listener as
 # phases of the same compile, not separate compiles
 _COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+_TRACE_EVENT_SUFFIX = "jaxpr_trace_duration"
+_LOWER_EVENT_SUFFIX = "jaxpr_to_mlir_module_duration"
 
 
 def describe_abstract_shapes(args: Iterable[Any],
@@ -107,6 +117,9 @@ class CompileSentry:
         self._listener_active = False
         self._steady = False
         self._compiles = 0
+        # per thread, the (start, duration) of trace events not yet
+        # enclosed by a later one: what an outer trace has to subtract
+        self._traces = threading.local()
 
     # ---- state ---------------------------------------------------------
     @property
@@ -172,6 +185,15 @@ class CompileSentry:
         # current_context() attributes the span to the request/step that
         # triggered it
         if not event.endswith(_COMPILE_EVENT_SUFFIX):
+            try:
+                if event.endswith(_TRACE_EVENT_SUFFIX):
+                    REGISTRY.histogram("xla.compile.trace.latency").observe(
+                        self._trace_self_time(float(duration)))
+                elif event.endswith(_LOWER_EVENT_SUFFIX):
+                    REGISTRY.histogram("xla.compile.lower.latency").observe(
+                        float(duration))
+            except Exception:
+                pass
             return
         with self._lock:
             self._compiles += 1
@@ -195,6 +217,25 @@ class CompileSentry:
         except Exception:
             # a telemetry listener must never break a compile
             pass
+
+    def _trace_self_time(self, duration: float) -> float:
+        """JAX fires a trace event for every jitted function, also for
+        one traced inside another's trace, so an outer duration contains
+        the inner ones: subtract the events already recorded on this
+        thread that the interval [now - duration, now] encloses, or the
+        histogram's sum counts nested traces twice.  (JAX stamps the
+        event with `time.time()`, so that is the clock here.)"""
+        open_ = getattr(self._traces, "open", None)
+        if open_ is None:
+            # bounded: an entry leaves only when an outer trace encloses
+            # it, and a top-level trace has none
+            open_ = self._traces.open = collections.deque(maxlen=4096)
+        start = time.time() - duration
+        nested = 0.0
+        while open_ and open_[-1][0] >= start - 1e-4:
+            nested += open_.pop()[1]
+        open_.append((start, duration))
+        return max(0.0, duration - nested)
 
     # ---- wrapper-side reporting ----------------------------------------
     def note_traced_compile(self, name: str, args: tuple,
@@ -395,40 +436,9 @@ def start_memory_sampler(interval_s: float = 5.0,
 
 # ---- device annotations ---------------------------------------------------
 
-# the stage spans worth seeing on a device timeline: the training step,
-# the h2d transfer, and the host-pipeline stages (recorded as
-# `pipeline.<stage>` spans and annotated as such)
-DEFAULT_ANNOTATION_PREFIXES: Tuple[str, ...] = (
-    "training.step", "feed.transfer", "io.pipeline", "pipeline.")
-
-
-def enable_device_annotations(
-        enabled: bool = True,
-        prefixes: Tuple[str, ...] = DEFAULT_ANNOTATION_PREFIXES) -> bool:
-    """Opt in (or out) of wrapping matching spans in
-    `jax.profiler.TraceAnnotation` so a real profiler capture shows our
-    span names on the device timeline.  Returns True when armed."""
-    if not enabled:
-        _spans.set_annotation_hook(None, ())
-        return False
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:
-        _spans.set_annotation_hook(None, ())
-        return False
-    _spans.set_annotation_hook(TraceAnnotation, tuple(prefixes))
-    return True
-
-
 def device_annotation(name: str):
-    """A TraceAnnotation context for `name` when annotations are armed
-    and the name matches, else a no-op context — for already-measured
-    sites (`feed._device_put`, pipeline workers) whose spans go through
-    `record_span` and so never pass through `span()`'s hook."""
-    factory, prefixes = _spans.get_annotation_hook()
-    if factory is None or not prefixes or not name.startswith(prefixes):
-        return contextlib.nullcontext()
-    try:
-        return factory(name)
-    except Exception:
-        return contextlib.nullcontext()
+    """A `jax.profiler.TraceAnnotation(name)` when jax is imported, else
+    a no-op context — for already-measured sites (`feed._device_put`,
+    pipeline workers) whose spans go through `record_span` and so never
+    pass through `span()`."""
+    return _spans._annotation_for(name)

@@ -81,6 +81,10 @@ DECLARED_METRICS: Dict[str, str] = {
     "flow.expired.prefill": "counter",
     "xla.compile.count": "counter",       # every observed XLA compile
     "xla.compile.hot_path": "counter",    # + .<fn> variants: steady-state
+    # -- counters: the continuous batcher's loop thread (serving/batcher.py)
+    "serving.batcher.prefill.tokens": "counter",         # real prompt tokens
+    "serving.batcher.prefill.padded_tokens": "counter",  # rows x bucket computed
+    "serving.batcher.live_tokens": "counter",   # K/V rows read, summed per tick
     # -- counters: fleet gateway event ledger (serving/fleet.py, PR 9)
     "serving.fleet.retry": "counter",
     "serving.fleet.eject": "counter",
@@ -125,6 +129,13 @@ DECLARED_METRICS: Dict[str, str] = {
     "models.training.step_latency": "histogram",
     "checkpoint.verify.latency": "histogram",
     "xla.compile.latency": "histogram",
+    "xla.compile.trace.latency": "histogram",   # jaxpr tracing, self time
+    "xla.compile.lower.latency": "histogram",   # jaxpr -> MLIR module
+    # the continuous batcher's loop thread (serving/batcher.py)
+    "serving.batcher.tick.latency": "histogram",    # decode tick less admit
+    "serving.batcher.tick.host": "histogram",       # ... less the fetch too
+    "serving.batcher.admit.latency": "histogram",   # one admission, all buckets
+    "serving.batcher.queue_wait": "histogram",      # submit() -> admission
     "serving.fleet.request.latency": "histogram",   # gateway e2e, labeled
     "serving.fleet.replica.latency": "histogram",   # labeled {replica=...}
     "fleet.scrape.latency": "histogram",    # one full federated pull+merge
@@ -216,6 +227,12 @@ HISTOGRAM_FAMILY: Dict[str, str] = {
     "models.training.step_latency": "latency",
     "checkpoint.verify.latency": "latency",
     "xla.compile.latency": "latency",
+    "xla.compile.trace.latency": "latency",
+    "xla.compile.lower.latency": "latency",
+    "serving.batcher.tick.latency": "latency",
+    "serving.batcher.tick.host": "latency",
+    "serving.batcher.admit.latency": "latency",
+    "serving.batcher.queue_wait": "latency",
     "serving.fleet.request.latency": "latency",
     "serving.fleet.replica.latency": "latency",
     "fleet.scrape.latency": "latency",

@@ -22,15 +22,15 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
+import sys
 import threading
 import time
 import uuid
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["span", "record_span", "use_trace", "current_context",
+__all__ = ["span", "phase", "record_span", "use_trace", "current_context",
            "current_trace_id", "trace_headers", "extract_trace",
            "get_trace", "span_tree", "recent_spans", "clear_spans",
-           "set_annotation_hook", "get_annotation_hook",
            "MAX_SPANS", "MAX_TRACES", "MAX_SPANS_PER_TRACE"]
 
 MAX_SPANS = 8192          # global recent-span ring
@@ -52,37 +52,51 @@ def _new_id() -> str:
     return uuid.uuid4().hex[:16]
 
 
-# Optional device-annotation hook (set by telemetry.device): a factory of
-# context managers (jax.profiler.TraceAnnotation) plus the span-name
-# prefixes it applies to.  When armed, span() additionally enters an
-# annotation for matching names so the device timeline in a real
-# profiler capture carries our span names.  Kept here (not in device.py)
-# so span() stays jax-import-free: the factory is injected, never looked
-# up.
-_ANNOTATION_FACTORY = None
-_ANNOTATION_PREFIXES: Tuple[str, ...] = ()
-
-
-def set_annotation_hook(factory, prefixes: Tuple[str, ...] = ()) -> None:
-    """Arm (or with factory=None disarm) the device-annotation hook."""
-    global _ANNOTATION_FACTORY, _ANNOTATION_PREFIXES
-    _ANNOTATION_FACTORY = factory
-    _ANNOTATION_PREFIXES = tuple(prefixes)
-
-
-def get_annotation_hook():
-    return _ANNOTATION_FACTORY, _ANNOTATION_PREFIXES
+_NO_ANNOTATION = contextlib.nullcontext()     # stateless: shared
 
 
 def _annotation_for(name: str):
-    if _ANNOTATION_FACTORY is None or not _ANNOTATION_PREFIXES:
-        return None
-    if not name.startswith(_ANNOTATION_PREFIXES):
-        return None
+    """A `jax.profiler.TraceAnnotation(name)` when jax is already
+    imported, else a no-op context: the one way onto the profiler's
+    clock.  With no capture running the annotation costs about a
+    microsecond; with one running it lands in the profiler's own host
+    plane, the only clock the device trace shares.  `sys.modules` and
+    not an import: this package stays importable, and `/metrics`
+    servable, without jax."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _NO_ANNOTATION
     try:
-        return _ANNOTATION_FACTORY(name)
+        return profiler.TraceAnnotation(name)
     except Exception:
-        return None
+        return _NO_ANNOTATION
+
+
+class phase:
+    """`span()`'s light sibling for loops: enters the profiler
+    annotation `name` and, given `hist` (a `Histogram`), adds the
+    elapsed `perf_counter` seconds to it on exit.  No ids, no ring, no
+    lock beyond the histogram's stripe, so a loop that ticks every few
+    milliseconds can wrap each of its phases in one.  `elapsed_s` holds
+    the last exit's reading."""
+
+    __slots__ = ("_annotation", "_hist", "_t0", "elapsed_s")
+
+    def __init__(self, name: str, hist=None):
+        self._annotation = _annotation_for(name)
+        self._hist = hist
+        self.elapsed_s = 0.0
+
+    def __enter__(self) -> "phase":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.elapsed_s = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(self.elapsed_s)
 
 
 def current_context() -> Optional[Tuple[str, str]]:
@@ -141,10 +155,7 @@ def span(name: str, parent_ctx: Optional[Tuple[str, str]] = None,
     t0 = time.perf_counter()
     err: Optional[str] = None
     try:
-        if annotation is not None:
-            with annotation:
-                yield sp
-        else:
+        with annotation:
             yield sp
     except BaseException as e:  # noqa: BLE001 — recorded, then re-raised
         err = type(e).__name__

@@ -453,9 +453,8 @@ class DeviceFeed:
 
         def attempt(a):
             fault_point("feed.device_put")
-            # no-op unless enable_device_annotations() armed the
-            # profiler hook: the transfer span itself is recorded
-            # after the fact via record_span, which can't annotate
+            # the transfer span itself is recorded after the fact
+            # via record_span, which can't annotate
             with core_telemetry.device_annotation("feed.transfer"):
                 return (jax.device_put(a, sharding)
                         if sharding is not None

@@ -547,8 +547,8 @@ def fit_epochs(
             for dbi, dbl in feed.stream(pipe.run(bounds),
                                         shardings=(img_sh, img_sh)):
                 t0 = time.perf_counter()
-                # the training.step span doubles as the device-timeline
-                # annotation hook when enable_device_annotations() is on
+                # the training.step span is also a profiler annotation:
+                # a capture shows it on the device trace's clock
                 with core_telemetry.span("training.step") as _sp:
                     state, ms = epoch_fn(state, dbi, dbl)
                     # one scanned dispatch = len(dbi) optimizer steps;

@@ -38,6 +38,24 @@ from ..utils.sync import make_rlock
 
 __all__ = ["ContinuousBatcher", "PrefillStage", "TokenStream"]
 
+# The loop thread's profiler annotations (`telemetry.phase`): under a
+# `jax.profiler` capture they land in the host plane, nested as below, on
+# the device trace's clock.  The names are a contract with the reducers
+# under benchmarks/ and with docs/observability.md; written once, here.
+TICK = "serving.batcher.tick"               # an iteration with work to do
+TICK_INTAKE = TICK + ".intake"              # control ops, intake -> buffer
+TICK_ADMIT = TICK + ".admit"                # one admission, all buckets
+ADMIT_PACK = "serving.batcher.admit.pack"   # host packing of one bucket
+ADMIT_PREFILL = "serving.batcher.admit.prefill"   # upload, forward, load
+ADMIT_FIRST_TOKEN = "serving.batcher.admit.first_token"   # blocking fetch
+TICK_GROW = TICK + ".grow"                  # just-in-time page growth
+TICK_DRAFT = TICK + ".draft"                # speculative: the draft's steps
+TICK_UPLOAD = TICK + ".upload"              # tok/pos(/table) in one put
+TICK_DISPATCH = TICK + ".dispatch"          # the call of the decode step
+TICK_FETCH = TICK + ".fetch"                # the host blocked on the device
+TICK_EMIT = TICK + ".emit"                  # position bumps, streams
+IDLE = "serving.batcher.idle"               # nothing live: wait on intake
+
 
 class PrefillStage(Stage):
     """Host-side prompt packing for admission prefill buckets, as a
@@ -93,6 +111,7 @@ class _Request:
         # to the submitting request's span (the loop is another thread)
         self.trace = telemetry.current_context()
         self.submitted_at = time.monotonic()
+        self.first_token_at = 0.0     # set by the admission that took it
 
 
 class ContinuousBatcher:
@@ -601,10 +620,12 @@ class ContinuousBatcher:
         batch shapes; pad rows compute garbage that the slot-indexed
         loads drop (out-of-range sentinel + mode='drop')."""
         now = time.monotonic()
+        queue_wait = telemetry.histogram("serving.batcher.queue_wait")
         for slot, req in batch:
+            queue_wait.observe(now - req.submitted_at)
             # slot-wait span on the SUBMITTER's trace (cross-thread hop)
             if req.trace is not None:
-                telemetry.record_span("serving.batcher.admit", req.trace,
+                telemetry.record_span("serving.batcher.wait", req.trace,
                                       now - req.submitted_at, slot=slot)
         by_bucket: dict = {}
         prefix_groups: dict = {}
@@ -625,16 +646,13 @@ class ContinuousBatcher:
             # pipeline so bucket i+1 packs while bucket i's prefill
             # forward occupies the device
             b, group = item
-            kb = len(group)
-            kp = 1
-            while kp < kb:
-                kp *= 2
-            kp = min(kp, self.max_slots)
-            padded = np.zeros((kp, b), np.int32)
-            slots = np.full(kp, self.max_slots, np.int32)  # OOB = dropped
-            for i, (slot, req) in enumerate(group):
-                padded[i, :len(req.prompt)] = req.prompt
-                slots[i] = slot
+            with telemetry.phase(ADMIT_PACK):
+                kp = self._pad_rows(len(group))
+                padded = np.zeros((kp, b), np.int32)
+                slots = np.full(kp, self.max_slots, np.int32)  # OOB = dropped
+                for i, (slot, req) in enumerate(group):
+                    padded[i, :len(req.prompt)] = req.prompt
+                    slots[i] = slot
             return group, kp, padded, slots
 
         buckets = sorted(by_bucket.items())
@@ -645,45 +663,76 @@ class ContinuousBatcher:
             packed = map(pack_bucket, buckets)
         for group, kp, padded, slots in packed:
             k = len(group)
-            # the upload rides the feed engine: counted bytes, transfer
-            # spans on the request trace, the feed.device_put fault point
-            d_padded = self._feed.put(padded)
-            logits, cache = self._prefill(self.variables, d_padded)
-            if self.draft_model is not None:
-                # the draft's cache must hold the same prompt history;
-                # its prefill logits are unused — the first pending token
-                # is the TARGET's (exactness requires it)
-                _dlg, d_rows = self._d_prefill(self.draft_variables,
-                                               d_padded)
-                self._d_cache = self._load_many(self._d_cache, d_rows,
-                                                jnp.asarray(slots))
-            if self.paged:
-                # allocate each slot's prompt pages and scatter all rows'
-                # prefill pages in one update; bucketing garbage inside
-                # the last page is masked/overwritten as in dense
-                ids = np.full((kp, self._mp), self._np, np.int32)
-                for i, (slot, req) in enumerate(group):
-                    need = -(-len(req.prompt) // self.page_size)
-                    pages = [self._free.pop() for _ in range(need)]
-                    self._slot_pages[slot] = pages
-                    self._slot_shared[slot] = 0
-                    self._table[slot].fill(0)
-                    self._table[slot, :need] = pages
-                    ids[i, :need] = pages
-                self._cache = self._load_paged_many(
-                    self._cache, cache, jnp.asarray(ids.reshape(-1)))
-            else:
-                self._cache = self._load_many(self._cache, cache,
-                                              jnp.asarray(slots))
-            firsts = np.asarray(jnp.argmax(logits[
-                jnp.arange(kp), jnp.asarray(
-                    [len(r.prompt) - 1 for _s, r in group]
-                    + [0] * (kp - k))], axis=-1), np.int32)
+            t_bucket = time.monotonic()
+            with telemetry.phase(ADMIT_PREFILL):
+                # the upload rides the feed engine: counted bytes, transfer
+                # spans on the request trace, the feed.device_put fault point
+                d_padded = self._feed.put(padded)
+                logits, cache = self._prefill(self.variables, d_padded)
+                if self.draft_model is not None:
+                    # the draft's cache must hold the same prompt history;
+                    # its prefill logits are unused — the first pending
+                    # token is the TARGET's (exactness requires it)
+                    _dlg, d_rows = self._d_prefill(self.draft_variables,
+                                                   d_padded)
+                    self._d_cache = self._load_many(self._d_cache, d_rows,
+                                                    jnp.asarray(slots))
+                if self.paged:
+                    # allocate each slot's prompt pages and scatter all
+                    # rows' prefill pages in one update; bucketing garbage
+                    # inside the last page is masked/overwritten as in dense
+                    ids = np.full((kp, self._mp), self._np, np.int32)
+                    for i, (slot, req) in enumerate(group):
+                        need = -(-len(req.prompt) // self.page_size)
+                        pages = [self._free.pop() for _ in range(need)]
+                        self._slot_pages[slot] = pages
+                        self._slot_shared[slot] = 0
+                        self._table[slot].fill(0)
+                        self._table[slot, :need] = pages
+                        ids[i, :need] = pages
+                    self._cache = self._load_paged_many(
+                        self._cache, cache, jnp.asarray(ids.reshape(-1)))
+                else:
+                    self._cache = self._load_many(self._cache, cache,
+                                                  jnp.asarray(slots))
+            with telemetry.phase(ADMIT_FIRST_TOKEN):
+                firsts = np.asarray(jnp.argmax(logits[
+                    jnp.arange(kp), jnp.asarray(
+                        [len(r.prompt) - 1 for _s, r in group]
+                        + [0] * (kp - k))], axis=-1), np.int32)
+            self._note_prefill(
+                [(slot, req, len(req.prompt)) for slot, req in group],
+                padded.shape[1], kp, t_bucket)
             for i, (slot, req) in enumerate(group):
                 self._live[slot] = req
                 self._pos[slot] = len(req.prompt)
                 self._tok[slot] = int(firsts[i])
                 self._emit(slot, int(firsts[i]))
+
+    def _pad_rows(self, k: int) -> int:
+        """Rows of a prefill program for `k` requests: the next power of
+        two, capped at the slots."""
+        kp = 1
+        while kp < k:
+            kp *= 2
+        return min(kp, self.max_slots)
+
+    def _note_prefill(self, rows, bucket: int, kp: int, t_bucket: float):
+        """Account one bucket's admission forward: `rows` is (slot,
+        request, prompt tokens the forward computed for it); the program
+        computed `kp * bucket`.  Each request gets the forward it rode
+        as a span on its submitter's trace, and its decode clock starts
+        here, with its first token."""
+        now = time.monotonic()
+        telemetry.incr("serving.batcher.prefill.tokens",
+                       sum(n for _s, _r, n in rows))
+        telemetry.incr("serving.batcher.prefill.padded_tokens", kp * bucket)
+        for slot, req, _n in rows:
+            req.first_token_at = now
+            if req.trace is not None:
+                telemetry.record_span("serving.batcher.prefill", req.trace,
+                                      now - t_bucket, bucket=bucket,
+                                      rows=kp, slot=slot)
 
     def _admit_prefix_groups(self, prefix_groups):
         """Admit shared-prefix requests: wire each slot's page table to
@@ -703,11 +752,7 @@ class ContinuousBatcher:
                     by_draft_bucket.setdefault(
                         self._bucket(len(req.prompt)), []).append((slot, req))
             for db, dgroup in sorted(by_draft_bucket.items()):
-                dk = len(dgroup)
-                dkp = 1
-                while dkp < dk:
-                    dkp *= 2
-                dkp = min(dkp, self.max_slots)
+                dkp = self._pad_rows(len(dgroup))
                 dpad = np.zeros((dkp, db), np.int32)
                 dslots = np.full(dkp, self.max_slots, np.int32)
                 for i, (slot, req) in enumerate(dgroup):
@@ -718,49 +763,54 @@ class ContinuousBatcher:
                 self._d_cache = self._load_many(self._d_cache, d_rows,
                                                 jnp.asarray(dslots))
         for rb, group in sorted(prefix_groups.items()):
+            t_bucket = time.monotonic()
             fill = []                  # rows that need a suffix forward
-            for slot, req in group:
-                rec = self._prefixes[req.prefix]
-                shared = rec["shared"]
-                shared_tokens = shared * self.page_size
-                n = len(req.prompt)
-                need = -(-n // self.page_size) - shared
-                pages = [self._free.pop() for _ in range(need)]
-                self._slot_pages[slot] = pages
-                self._slot_shared[slot] = shared
-                self._table[slot].fill(0)
-                self._table[slot, :shared] = rec["pages"]
-                self._table[slot, shared:shared + need] = pages
-                if n > shared_tokens:
-                    fill.append((slot, req, shared_tokens))
-                else:
-                    first = int(np.argmax(rec["last_logits"]))
-                    self._live[slot] = req
-                    self._pos[slot] = n
-                    self._tok[slot] = first
-                    self._emit(slot, first)
-            if not fill:
-                continue
-            k = len(fill)
-            kp = 1
-            while kp < k:
-                kp *= 2
-            kp = min(kp, self.max_slots)
-            toks = np.zeros((kp, rb), np.int32)
-            pos = np.zeros(kp, np.int32)
-            tables = np.zeros((kp, self._mp), np.int32)
-            for i, (slot, req, st) in enumerate(fill):
-                toks[i, :len(req.prompt) - st] = req.prompt[st:]
-                pos[i] = st
-                tables[i] = self._table[slot]
-            d_toks, d_fpos, d_tbls = self._feed.put_group(
-                [toks, pos, tables])
-            logits, self._cache = self._step(
-                self.variables, d_toks, self._cache, d_fpos, d_tbls)
-            firsts = np.asarray(jnp.argmax(logits[
-                jnp.arange(kp), jnp.asarray(
-                    [len(r.prompt) - st - 1 for _s, r, st in fill]
-                    + [0] * (kp - k))], axis=-1), np.int32)
+            with telemetry.phase(ADMIT_PACK):
+                for slot, req in group:
+                    rec = self._prefixes[req.prefix]
+                    shared = rec["shared"]
+                    shared_tokens = shared * self.page_size
+                    n = len(req.prompt)
+                    need = -(-n // self.page_size) - shared
+                    pages = [self._free.pop() for _ in range(need)]
+                    self._slot_pages[slot] = pages
+                    self._slot_shared[slot] = shared
+                    self._table[slot].fill(0)
+                    self._table[slot, :shared] = rec["pages"]
+                    self._table[slot, shared:shared + need] = pages
+                    if n > shared_tokens:
+                        fill.append((slot, req, shared_tokens))
+                    else:
+                        first = int(np.argmax(rec["last_logits"]))
+                        self._note_prefill([(slot, req, 0)], 0, 0, t_bucket)
+                        self._live[slot] = req
+                        self._pos[slot] = n
+                        self._tok[slot] = first
+                        self._emit(slot, first)
+                if not fill:
+                    continue
+                k = len(fill)
+                kp = self._pad_rows(k)
+                toks = np.zeros((kp, rb), np.int32)
+                pos = np.zeros(kp, np.int32)
+                tables = np.zeros((kp, self._mp), np.int32)
+                for i, (slot, req, st) in enumerate(fill):
+                    toks[i, :len(req.prompt) - st] = req.prompt[st:]
+                    pos[i] = st
+                    tables[i] = self._table[slot]
+            with telemetry.phase(ADMIT_PREFILL):
+                d_toks, d_fpos, d_tbls = self._feed.put_group(
+                    [toks, pos, tables])
+                logits, self._cache = self._step(
+                    self.variables, d_toks, self._cache, d_fpos, d_tbls)
+            with telemetry.phase(ADMIT_FIRST_TOKEN):
+                firsts = np.asarray(jnp.argmax(logits[
+                    jnp.arange(kp), jnp.asarray(
+                        [len(r.prompt) - st - 1 for _s, r, st in fill]
+                        + [0] * (kp - k))], axis=-1), np.int32)
+            self._note_prefill(
+                [(slot, req, len(req.prompt) - st) for slot, req, st in fill],
+                rb, kp, t_bucket)
             for i, (slot, req, _st) in enumerate(fill):
                 self._live[slot] = req
                 self._pos[slot] = len(req.prompt)
@@ -776,6 +826,11 @@ class ContinuousBatcher:
                 or int(self._pos[slot]) + 1 >= self.model.max_len)
         if done:
             req.stream._q.put(None)
+            if req.trace is not None:
+                telemetry.record_span(
+                    "serving.batcher.decode", req.trace,
+                    time.monotonic() - req.first_token_at,
+                    tokens=req.emitted, slot=slot)
             self._live[slot] = None
             # park the freed slot at position 0: a slot that finished
             # near max_len must not leave a stale pos that speculative
@@ -808,10 +863,17 @@ class ContinuousBatcher:
 
     def _try_admit(self):
         """Admit from the FIFO head into free slots — collected into ONE
-        batched prefill (_admit_batch).  Paged mode admits only while
-        the head's worst-case page reservation fits the unreserved
-        budget — strict FIFO (no skipping), so a big request can't be
-        starved by a stream of small ones."""
+        batched prefill (_admit_batch)."""
+        batch = self._plan_admit()
+        if batch:
+            self._admit_batch(batch)
+
+    def _plan_admit(self) -> list:
+        """The (slot, request) pairs the next admission takes, host work
+        only.  Paged mode admits only while the head's worst-case page
+        reservation fits the unreserved budget — strict FIFO (no
+        skipping), so a big request can't be starved by a stream of
+        small ones."""
         if self.paged:
             # fail-fast pre-pass: a prefix registered AFTER a request
             # passed submit()'s ceiling check can shrink the achievable
@@ -866,47 +928,85 @@ class ContinuousBatcher:
                 self._slot_reserved[slot] = worst
             self._buffer.popleft()
             batch.append((slot, req))  # each slot index visited once
-        if batch:
-            self._admit_batch(batch)
+        return batch
 
     def _loop(self):
         while self._running.is_set():
-            self._drain_intake()
-            self._try_admit()
-            active = [s for s in range(self.max_slots)
-                      if self._live[s] is not None]
-            if not active:
-                if not self._buffer:
+            if (not self._buffer and self._ctl.empty()
+                    and all(r is None for r in self._live)):
+                # nothing to decode and nothing waiting: this is not a
+                # tick.  A device idle for want of requests reads as
+                # this span, not as unexplained
+                with telemetry.phase(IDLE):
                     try:
                         self._buffer.append(
                             self._intake.get(timeout=self.idle_sleep_s))
                     except Empty:
                         continue
+            self._tick()
+
+    def _tick(self):
+        """One loop iteration with work to do: drain the intake, admit
+        into free slots, then ONE decode step for every live slot.  The
+        timers are the loop's own: `tick.latency` is the iteration
+        without its admission (which stalls every live reply, and is
+        timed as `admit.latency`), `tick.host` the same without the
+        blocking fetch.  An iteration that finds nothing to decode
+        (everything it admitted finished on its first token, or nothing
+        could be admitted) observes neither."""
+        t0 = time.perf_counter()
+        admit_s = 0.0
+        with telemetry.phase(TICK):
+            with telemetry.phase(TICK_INTAKE):
+                self._drain_intake()
+            batch = self._plan_admit()
+            if batch:
+                with telemetry.phase(TICK_ADMIT, telemetry.histogram(
+                        "serving.batcher.admit.latency")) as admit:
+                    self._admit_batch(batch)
+                admit_s = admit.elapsed_s
+            active = [s for s in range(self.max_slots)
+                      if self._live[s] is not None]
+            if not active:
                 # nothing live -> every reservation is released, so the
                 # head always fits; the next iteration admits it
-                continue
+                return
             telemetry.histogram("serving.batcher.batch_fill").observe(
                 len(active) / self.max_slots)
+            # the K/V rows this tick's attention has to read
+            telemetry.incr("serving.batcher.live_tokens",
+                           int(self._pos[active].sum()))
             if self.paged:
                 # grow each active slot's page list just-in-time for this
                 # tick's write positions — speculative mode writes up to
                 # pos + gamma (the admission reservation guarantees the
                 # free list can cover it)
-                for sl in active:
-                    idx = (int(self._pos[sl]) + self.gamma) // self.page_size
-                    while idx >= (self._slot_shared[sl]
-                                  + len(self._slot_pages[sl])):
-                        pg = self._free.pop()
-                        self._table[sl, self._slot_shared[sl]
-                                    + len(self._slot_pages[sl])] = pg
-                        self._slot_pages[sl].append(pg)
+                with telemetry.phase(TICK_GROW):
+                    for sl in active:
+                        idx = ((int(self._pos[sl]) + self.gamma)
+                               // self.page_size)
+                        while idx >= (self._slot_shared[sl]
+                                      + len(self._slot_pages[sl])):
+                            pg = self._free.pop()
+                            self._table[sl, self._slot_shared[sl]
+                                        + len(self._slot_pages[sl])] = pg
+                            self._slot_pages[sl].append(pg)
             if self.draft_model is not None:
-                self._speculative_tick(active)
-                continue
-            # ONE batched step for every slot (free slots compute too —
-            # their pos 0 writes are dead: dense mode overwrites the rows
-            # on admit, paged mode routes them to the trash page), fed by
-            # ONE packed upload of this tick's tok/pos(/table) vectors
+                fetch_s = self._speculative_tick(active)
+            else:
+                fetch_s = self._decode_tick(active)
+            tick_s = time.perf_counter() - t0 - admit_s
+        telemetry.histogram("serving.batcher.tick.latency").observe(tick_s)
+        telemetry.histogram("serving.batcher.tick.host").observe(
+            max(0.0, tick_s - fetch_s))
+
+    def _decode_tick(self, active) -> float:
+        """ONE batched step for every slot (free slots compute too —
+        their pos 0 writes are dead: dense mode overwrites the rows on
+        admit, paged mode routes them to the trash page), fed by ONE
+        packed upload of this tick's tok/pos(/table) vectors.  Returns
+        the seconds the host stood blocked on the device."""
+        with telemetry.phase(TICK_UPLOAD):
             if self.paged:
                 d_tok, d_pos, d_tbl = self._feed.put_group(
                     [self._tok[:, None], self._pos, self._table])
@@ -914,64 +1014,78 @@ class ContinuousBatcher:
                 d_tok, d_pos = self._feed.put_group(
                     [self._tok[:, None], self._pos])
                 d_tbl = None
+        with telemetry.phase(TICK_DISPATCH):
             lg, self._cache = self._step(
                 self.variables, d_tok, self._cache, d_pos, d_tbl)
+        with telemetry.phase(TICK_FETCH) as fetch:
             nxt = np.asarray(jnp.argmax(lg[:, 0], axis=-1), np.int32)
+        with telemetry.phase(TICK_EMIT):
             for slot in active:
                 self._pos[slot] += 1
                 self._tok[slot] = nxt[slot]
                 self._emit(slot, int(nxt[slot]))
+        return fetch.elapsed_s
 
-    def _speculative_tick(self, active):
+    def _speculative_tick(self, active) -> float:
         """One speculative round for ALL slots: (gamma+1) draft slot
         steps propose, ONE target slot-block step verifies, each slot
         emits its accepted prefix + the target's own next token — the
         per-slot speculative-decoding recurrence (speculative_generate's
         round, vectorized over co-tenant slots).  The +1 extra draft
         step writes the would-be-next K/V row so a fully-accepted round
-        leaves no hole in the draft cache."""
+        leaves no hole in the draft cache.  Returns the seconds the host
+        stood blocked on the device (both fetches)."""
         g = self.gamma
-        dpos = self._pos.copy()
-        # the round's first draft step is the only one that uploads host
-        # data (later steps chain device outputs): tok+pos ride one
-        # packed transfer; per-step position bumps re-upload through the
-        # feed so the telemetry sees every byte on the wire
-        d_tok, d_pos = self._feed.put_group([self._tok[:, None], dpos])
-        prop_list = []
-        for i in range(g + 1):
-            lg, self._d_cache = self._d_step(
-                self.draft_variables, d_tok, self._d_cache, d_pos)
-            nxt = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)
-            if i < g:
-                # keep proposals ON DEVICE: a host sync here would block
-                # async dispatch of the next draft step
-                prop_list.append(nxt)
-            d_tok = nxt[:, None]
-            dpos += 1
-            if i < g:
-                d_pos = self._feed.put(dpos)
-        props = np.asarray(jnp.stack(prop_list, axis=1), np.int32)  # [S, g]
+        with telemetry.phase(TICK_DRAFT):
+            dpos = self._pos.copy()
+            # the round's first draft step is the only one that uploads
+            # host data (later steps chain device outputs): tok+pos ride
+            # one packed transfer; per-step position bumps re-upload
+            # through the feed so the telemetry sees every byte on the wire
+            d_tok, d_pos = self._feed.put_group([self._tok[:, None], dpos])
+            prop_list = []
+            for i in range(g + 1):
+                lg, self._d_cache = self._d_step(
+                    self.draft_variables, d_tok, self._d_cache, d_pos)
+                nxt = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)
+                if i < g:
+                    # keep proposals ON DEVICE: a host sync here would
+                    # block async dispatch of the next draft step
+                    prop_list.append(nxt)
+                d_tok = nxt[:, None]
+                dpos += 1
+                if i < g:
+                    d_pos = self._feed.put(dpos)
+            with telemetry.phase(TICK_FETCH) as fetch_props:
+                props = np.asarray(jnp.stack(prop_list, axis=1),
+                                   np.int32)                    # [S, g]
         # ONE target forward verifies every slot's pending token + its g
         # proposals at the slot's own position: logits[:, j] predicts
         # position pos+j+1
-        block = np.concatenate([self._tok[:, None], props], axis=1)
-        if self.paged:
-            d_blk, d_vpos, d_tbl = self._feed.put_group(
-                [block, self._pos, self._table])
-        else:
-            d_blk, d_vpos = self._feed.put_group([block, self._pos])
-            d_tbl = None
-        lg, self._cache = self._step(
-            self.variables, d_blk, self._cache, d_vpos, d_tbl)
-        t_pred = np.asarray(jnp.argmax(lg, axis=-1), np.int32)  # [S, g+1]
-        for slot in active:
-            match = t_pred[slot, :g] == props[slot]
-            m = int(np.argmin(np.concatenate(
-                [match, np.zeros(1, bool)])))                   # 0..g
-            for j in range(m + 1):
-                tok = int(props[slot, j]) if j < m else int(t_pred[slot, m])
-                self._pos[slot] += 1
-                self._tok[slot] = tok
-                self._emit(slot, tok)
-                if self._live[slot] is None:
-                    break  # finished mid-block: discard the rest
+        with telemetry.phase(TICK_UPLOAD):
+            block = np.concatenate([self._tok[:, None], props], axis=1)
+            if self.paged:
+                d_blk, d_vpos, d_tbl = self._feed.put_group(
+                    [block, self._pos, self._table])
+            else:
+                d_blk, d_vpos = self._feed.put_group([block, self._pos])
+                d_tbl = None
+        with telemetry.phase(TICK_DISPATCH):
+            lg, self._cache = self._step(
+                self.variables, d_blk, self._cache, d_vpos, d_tbl)
+        with telemetry.phase(TICK_FETCH) as fetch:
+            t_pred = np.asarray(jnp.argmax(lg, axis=-1), np.int32)  # [S, g+1]
+        with telemetry.phase(TICK_EMIT):
+            for slot in active:
+                match = t_pred[slot, :g] == props[slot]
+                m = int(np.argmin(np.concatenate(
+                    [match, np.zeros(1, bool)])))                   # 0..g
+                for j in range(m + 1):
+                    tok = (int(props[slot, j]) if j < m
+                           else int(t_pred[slot, m]))
+                    self._pos[slot] += 1
+                    self._tok[slot] = tok
+                    self._emit(slot, tok)
+                    if self._live[slot] is None:
+                        break  # finished mid-block: discard the rest
+        return fetch_props.elapsed_s + fetch.elapsed_s

@@ -124,6 +124,22 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture(scope="session")
+def bench_trace_lib():
+    """benchmarks/lib/trace.py by path: the benchmark reducers' own trace
+    reader and matcher, for tests that hold the program to them."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_trace_lib", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmarks", "lib", "trace.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod       # its dataclass looks itself up
+    spec.loader.exec_module(mod)
+    return mod
+
+
 @pytest.fixture
 def small_table():
     from mmlspark_tpu import Table

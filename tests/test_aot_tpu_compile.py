@@ -30,6 +30,8 @@ from mmlspark_tpu.ops import pallas_kernels as pk
 from mmlspark_tpu.ops import wire_codec as wc
 from mmlspark_tpu.parallel.mesh import MeshContext, make_mesh
 
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
 FULL = chip_smoke.SIZES["full"]
 LM, TRAIN, SERVE = FULL["lm"], FULL["lm_train"], FULL["lm_serve"]
 VIT, FEAT = FULL["vit"], FULL["featurize"]
@@ -223,9 +225,10 @@ def _lm_variables(mesh, model, tokens_shape):
     return jax.tree.map(lambda a: _shape(mesh, a.shape, a.dtype), params)
 
 
-def test_lm_train_epoch_compiles_with_36_kernels(v5e):
-    """The whole make_lm_train_epoch program at the bench width: 12
-    layers x (forward, dK/dV, dQ) custom calls, and it fits one chip."""
+@pytest.fixture(scope="module")
+def epoch_program(v5e):
+    """The whole make_lm_train_epoch program at the bench width, compiled
+    once for the tests that read it."""
     import optax
 
     from mmlspark_tpu.models.training import make_lm_train_epoch
@@ -240,28 +243,87 @@ def test_lm_train_epoch_compiles_with_36_kernels(v5e):
     tokens = jax.ShapeDtypeStruct(
         (steps, b, s), jnp.int32,
         sharding=NamedSharding(v5e, P(None, "data")))
-    compiled = _compile(make_lm_train_epoch(model, opt, mesh=v5e,
-                                            donate=False),
-                        params, opt_state, tokens)
-    assert compiled.as_text().count("tpu_custom_call") == \
-        3 * LM["num_layers"] == 36
-    mem = compiled.memory_analysis()
-    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes) < V5E_HBM_BYTES
+    return _compile(make_lm_train_epoch(model, opt, mesh=v5e, donate=False),
+                    params, opt_state, tokens)
 
 
-def test_paged_decode_step_compiles_with_12_kernels(v5e):
+@pytest.fixture(scope="module")
+def decode_program(v5e):
     """The batcher's slot-decode program (TransformerLM.decode_step over
-    page pools): one page-walk kernel per layer."""
+    page pools), compiled once for the tests that read it."""
     model = chip_smoke._lm(LM, jnp.float32)
     b, _mp, pool, _scales, table, pos = _paged_shapes(v5e, jnp.float32)
     variables = {"params": _lm_variables(v5e, model, (1, 8))}
     cache = tuple((pool, pool) for _ in range(LM["num_layers"]))
     step = jax.jit(lambda v, t, c, p, pt: model.apply(
         v, t, c, p, pt, method=model.decode_step))
-    compiled = _compile(step, variables, _shape(v5e, (b, 1), jnp.int32),
-                        cache, pos, table)
-    assert compiled.as_text().count("tpu_custom_call") == LM["num_layers"]
+    return _compile(step, variables, _shape(v5e, (b, 1), jnp.int32),
+                    cache, pos, table)
+
+
+def test_lm_train_epoch_compiles_with_36_kernels(epoch_program):
+    """12 layers x (forward, dK/dV, dQ) custom calls, and it fits one
+    chip."""
+    assert epoch_program.as_text().count("tpu_custom_call") == \
+        3 * LM["num_layers"] == 36
+    mem = epoch_program.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+def test_paged_decode_step_compiles_with_12_kernels(decode_program):
+    """One page-walk kernel per layer."""
+    assert decode_program.as_text().count("tpu_custom_call") == \
+        LM["num_layers"]
+
+
+def _metric_pattern(name):
+    import json
+
+    with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
+        return json.load(f)["args"]["pattern"]
+
+
+# (program fixture, metric file, kernel, custom calls the pattern must find)
+KERNEL_NAMES = [
+    ("epoch_program", "flash_attn_ms", "_attention_pallas",
+     LM["num_layers"]),
+    ("epoch_program", "flash_attn_ms", "_attention_bwd_dkdv",
+     LM["num_layers"]),
+    ("epoch_program", "flash_attn_roofline", "_attention_bwd_dq",
+     LM["num_layers"]),
+    ("decode_program", "paged_attn_ms", "_paged_pallas", LM["num_layers"]),
+]
+
+
+@pytest.mark.parametrize("program,metric,kernel,calls", KERNEL_NAMES,
+                         ids=[f"{m}-{k}" for _p, m, k, _c in KERNEL_NAMES])
+def test_metric_patterns_find_the_kernels(request, bench_trace_lib, program,
+                                          metric, kernel, calls):
+    """The names the benchmark's trace metrics lean on.  The TPU runtime
+    names a device event by its whole HLO instruction, and the compiler
+    keeps the jitted Python function's name as the custom call's
+    instruction name (`%_paged_pallas.12 = ... custom-call(...)`), so the
+    compiled text stands in for the trace here: each tpu_custom_call
+    instruction goes through the reducers' own `op_name` and `ops`, and
+    the `pattern` of the metric file has to find every call of the kernel.
+    A rename of a kernel fails here, and not as a metric gone silent on
+    the chip.  (The patterns were written against the chip's trace, whose
+    form benchmarks/fixtures/lm-train-v5e.trace.json.gz shows; both
+    flash metric files carry one pattern.)"""
+    tr = bench_trace_lib
+    text = request.getfixturevalue(program).as_text()
+    events = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name, detail = tr.op_name(line.strip())
+            events.append((name, 0.0, 1.0, detail))
+    assert _metric_pattern("flash_attn_ms") == \
+        _metric_pattern("flash_attn_roofline")
+    found = [n for n, _s, _e in tr.ops(events, _metric_pattern(metric))]
+    mine = [n for n in found if n.split(".")[0] == kernel]
+    assert len(mine) == calls, (kernel, sorted({n.split(".")[0]
+                                                for n, *_ in events}))
 
 
 def test_described_context_does_not_leak(v5e):

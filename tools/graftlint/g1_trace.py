@@ -56,8 +56,9 @@ TRACE_WRAPPERS: Set[str] = {
 
 # telemetry / fault-machinery entry points: any of these inside a trace
 # records per-compile, not per-step (or takes a host lock mid-trace)
-_TELEMETRY_FNS = {"incr", "gauge", "histogram", "span", "record_span",
-                  "log_verb", "fault_point", "device_annotation",
+_TELEMETRY_FNS = {"incr", "gauge", "histogram", "span", "phase",
+                  "record_span", "log_verb", "fault_point",
+                  "device_annotation",
                   "counters", "reset_counters"}
 
 _HOST_SYNC_METHODS = {"item", "block_until_ready"}
