@@ -211,13 +211,16 @@ class _Block(nn.Module):
         dequant multiply fuses into the attention matmul's read.
 
         page_table [B, MP] int32 (slot decode only): the cache tuples are
-        PAGE POOLS [NP, page, Hkv, D] (+[NP, page, Hkv] scales for int8)
-        instead of per-slot rows — slot b's logical cache position p lives
-        at pool[page_table[b, p // page], p % page].  Physical page 0 is
-        the write-trash page: unallocated table entries point at it, so a
-        free slot's dead write can never corrupt a live slot's pages, and
-        gathered trash rows sit at logical positions > pos where the
-        validity mask already hides them.
+        FLAT PAGE POOLS [NP, page, Hkv*D] (+[NP, page, Hkv] scales for
+        int8) instead of per-slot rows — slot b's logical cache position p
+        lives at pool[page_table[b, p // page], p % page].  The heads are
+        folded into the minor axis because that is the shape whose default
+        device layout the row scatter and the page-walk kernel both read:
+        a donated pool is then updated in place (ops/paged_attention.py).
+        Physical page 0 is the write-trash page: unallocated table entries
+        point at it, so a free slot's dead write can never corrupt a live
+        slot's pages, and gathered trash rows sit at logical positions
+        > pos where the validity mask already hides them.
         """
         b, s, e = x.shape
         h = self.num_heads
@@ -272,12 +275,13 @@ class _Block(nn.Module):
             rows_mat = rows_b[:, None]                         # [B, 1]
             posmat = pos[:, None] + jnp.arange(s)[None]        # [B, s]
             if page_table is not None:
-                # PAGED slot decode: write one row into the owning page,
-                # gather each slot's pages back into a logical [B, L, H, D]
-                # view for the shared masked attention.  Storage is
-                # pay-per-page (the continuous-batching density win); the
-                # gather is XLA's — a Mosaic page-table kernel can replace
-                # it without touching this contract.
+                # PAGED slot decode: write one row into the owning page of
+                # the flat [NP, page, Hkv*D] pools; s=1 on one TPU walks
+                # the page table in a Mosaic kernel, everything else (block
+                # decode, GQA pools) gathers each slot's pages back into a
+                # logical [B, L, Hkv, D] view for the shared masked
+                # attention.  Storage is pay-per-page (the
+                # continuous-batching density win).
                 page = cache[0].shape[1]
                 mp = page_table.shape[1]
                 # block positions past the table (bucket padding in a
@@ -291,15 +295,23 @@ class _Block(nn.Module):
                                jnp.minimum(posmat // page, mp - 1)],
                     0)                                         # [B, s]
                 offmat = posmat % page
+
+                from ..ops.paged_attention import _gather_pages
+
+                def gathered(pool, width=None):
+                    # the slots' logical [B, L, H(, D)] view of a pool
+                    return _gqa_expand(
+                        _gather_pages(pool, page_table, width), h)
+
                 if len(cache) == 4:
                     from ..ops.quant import quantize_kv_row
 
                     kq, ks, vq, vs = cache
                     knew, ksc = quantize_kv_row(k)
                     vnew, vsc = quantize_kv_row(v)
-                    kq = kq.at[pgmat, offmat].set(knew)
+                    kq = kq.at[pgmat, offmat].set(knew.reshape(b, s, -1))
                     ks = ks.at[pgmat, offmat].set(ksc)
-                    vq = vq.at[pgmat, offmat].set(vnew)
+                    vq = vq.at[pgmat, offmat].set(vnew.reshape(b, s, -1))
                     vs = vs.at[pgmat, offmat].set(vsc)
                     cache = (kq, ks, vq, vs)
                     if s == 1 and _single_tpu():
@@ -314,22 +326,15 @@ class _Block(nn.Module):
                             pos)[:, None]
                     else:
                         a = _cache_attention(
-                            q,
-                            _gqa_expand(kq[page_table].reshape(
-                                b, mp * page, hkv, d), h),
-                            _gqa_expand(vq[page_table].reshape(
-                                b, mp * page, hkv, d), h),
-                            posmat, d,
-                            k_scale=_gqa_expand(ks[page_table].reshape(
-                                b, mp * page, hkv), h),
-                            v_scale=_gqa_expand(vs[page_table].reshape(
-                                b, mp * page, hkv), h))
+                            q, gathered(kq, d), gathered(vq, d),
+                            posmat, d, k_scale=gathered(ks),
+                            v_scale=gathered(vs))
                 else:
                     k_pool, v_pool = cache
                     k_pool = k_pool.at[pgmat, offmat].set(
-                        k.astype(k_pool.dtype))
+                        k.reshape(b, s, -1).astype(k_pool.dtype))
                     v_pool = v_pool.at[pgmat, offmat].set(
-                        v.astype(v_pool.dtype))
+                        v.reshape(b, s, -1).astype(v_pool.dtype))
                     cache = (k_pool, v_pool)
                     if s == 1 and _single_tpu():
                         # paged_decode_attention owns kernel-vs-gather
@@ -345,11 +350,7 @@ class _Block(nn.Module):
                             pos)[:, None]
                     else:
                         a = _cache_attention(
-                            q,
-                            _gqa_expand(k_pool[page_table].reshape(
-                                b, mp * page, hkv, d), h),
-                            _gqa_expand(v_pool[page_table].reshape(
-                                b, mp * page, hkv, d), h),
+                            q, gathered(k_pool, d), gathered(v_pool, d),
                             posmat, d)
             elif len(cache) == 4:
                 from ..ops.quant import quantize_kv_row

@@ -11,6 +11,14 @@ continuous-batching shape).  `paged=True` swaps the per-slot cache for
 shared page pools + a page table (vLLM paged KV): HBM is pay-per-page,
 so co-tenant density stops being bounded by max_slots * max_len.
 
+The cache is only ever updated IN PLACE.  Page pools are flat
+[NP, page, Hkv*D] from allocation on — the one shape whose default
+device layout the decode step's row scatter, the admission's page
+scatter and the page-walk kernel all read (ops/paged_attention.py) —
+and every program that takes the cache takes it DONATED: a call
+consumes `self._cache`'s buffers and hands back the same memory,
+updated.  Nothing may keep a reference to a pool across such a call.
+
 Host loop per tick: admit pending prompts into free slots (one prefill
 forward each; its padded cache rows overwrite the slot), one batched
 decode step for ALL slots, emit each live slot's token to its stream.
@@ -208,8 +216,9 @@ class ContinuousBatcher:
                         else s * self._mp + 1)      # default: dense parity
             if self._np < 2:
                 raise ValueError("num_pages must be >= 2 (page 0 is trash)")
-            shape4 = (self._np, self.page_size, h, d)
-            shape3 = (self._np, self.page_size, h)
+            # FLAT pools: heads folded into the minor axis (module doc)
+            shape_kv = (self._np, self.page_size, h * d)
+            shape_sc = (self._np, self.page_size, h)
             self._free: List[int] = list(range(1, self._np))
             self._avail = len(self._free)           # unreserved budget
             self._slot_pages: List[List[int]] = [[] for _ in range(s)]
@@ -219,19 +228,19 @@ class ContinuousBatcher:
             self._prefixes: dict = {}     # handle -> shared-prefix record
             self._next_prefix = 1
         else:
-            shape4, shape3 = (s, L, h, d), (s, L, h)
+            shape_kv, shape_sc = (s, L, h, d), (s, L, h)
         if kv_cache_dtype == "int8":
             # 4x the co-tenant density per HBM byte: int8 rows + f32
             # per-(pos, head) scales (ops/quant.quantize_kv_row)
             self._cache = tuple(
-                (jnp.zeros(shape4, jnp.int8),
-                 jnp.zeros(shape3, jnp.float32),
-                 jnp.zeros(shape4, jnp.int8),
-                 jnp.zeros(shape3, jnp.float32))
+                (jnp.zeros(shape_kv, jnp.int8),
+                 jnp.zeros(shape_sc, jnp.float32),
+                 jnp.zeros(shape_kv, jnp.int8),
+                 jnp.zeros(shape_sc, jnp.float32))
                 for _ in range(model.num_layers))
         else:
             self._cache = tuple(
-                (jnp.zeros(shape4, dt), jnp.zeros(shape4, dt))
+                (jnp.zeros(shape_kv, dt), jnp.zeros(shape_kv, dt))
                 for _ in range(model.num_layers))
         self._pos = np.zeros(s, np.int32)
         self._tok = np.zeros(s, np.int32)
@@ -262,9 +271,12 @@ class ContinuousBatcher:
         # loop thread runs, and _exec_release_prefix re-acquires it
         self._submit_lock = make_rlock("serving.batcher.submit")
         self._thread: Optional[threading.Thread] = None
+        # the cache argument of every program below is DONATED (module
+        # doc): each call site rebinds `self._cache` from the result
         self._step = jax.jit(
             lambda v, t, c, p, pt: self.model.apply(
-                v, t, c, p, pt, method=self.model.decode_step))
+                v, t, c, p, pt, method=self.model.decode_step),
+            donate_argnums=(2,))
         # admission prefill as ONE program per (rows, bucket) shape: run
         # eagerly it is an op-by-op dispatch (and a compile per op per
         # shape) of the whole forward on the admission path
@@ -281,18 +293,18 @@ class ContinuousBatcher:
             lambda c, rows, slots: jax.tree.map(
                 lambda dst, src: dst.at[slots].set(
                     src.astype(dst.dtype), mode="drop"),
-                c, rows))
-        # paged admit: each row's prefill reshapes into [MP, page, ...]
-        # blocks and scatters into the pools at its page ids (flat
-        # [K*MP]); blocks past an allocation carry the out-of-range id
-        # NP and drop
+                c, rows), donate_argnums=(0,))
+        # paged admit: each row's prefill [K, L, Hkv(, D)] reshapes into
+        # [K*MP, page, Hkv(*D)] blocks and scatters into the flat pools
+        # at its page ids (flat [K*MP]); blocks past an allocation carry
+        # the out-of-range id NP and drop
         self._load_paged_many = jax.jit(
             lambda c, rows, ids: jax.tree.map(
                 lambda pool, r: pool.at[ids].set(
                     r.reshape(ids.shape[0], pool.shape[1],
-                              *r.shape[2:]).astype(pool.dtype),
+                              -1).astype(pool.dtype),
                     mode="drop"),
-                c, rows))
+                c, rows), donate_argnums=(0,))
         if draft_model is not None:
             # speculative mode: the draft keeps a plain DENSE f32/bf16
             # slot cache (it is the small/cheap model; paging and int8
@@ -311,7 +323,8 @@ class ContinuousBatcher:
                 for _ in range(draft_model.num_layers))
             self._d_step = jax.jit(
                 lambda v, t, c, p: self.draft_model.apply(
-                    v, t, c, p, None, method=self.draft_model.decode_step))
+                    v, t, c, p, None, method=self.draft_model.decode_step),
+                donate_argnums=(2,))
             self._d_prefill = jax.jit(lambda v, toks: _prefill_cache(
                 self.draft_model, v, toks))
 
@@ -422,7 +435,15 @@ class ContinuousBatcher:
                 self._cache = self._load_paged_many(self._cache, cache,
                                                     jnp.asarray(page_ids))
         except Exception:
-            # a failed prefill must not leak the pool allocation
+            # a failed prefill must not leak the pool allocation.  The
+            # pools are whole here unless the load program itself died
+            # on the device: the load is the block's last call and
+            # donates only when it is dispatched, so a prefill, trace,
+            # compile or upload error leaves `self._cache` bound to live
+            # buffers that hold no row of this prefix.  A load that
+            # fails AFTER dispatch has consumed the pools (the next step
+            # raises "Array has been deleted"): freeing pages cannot
+            # mend that, and the batcher has to be rebuilt
             self._free.extend(pages)
             self._avail += shared
             raise

@@ -185,7 +185,7 @@ def _paged_shapes(mesh, pool_dtype):
     b, page = SERVE["max_slots"], SERVE["page_size"]
     mp = LM["max_len"] // page
     n_pages = b * mp + 1                  # the batcher's default pool
-    pool = _shape(mesh, (n_pages, page, HEADS, HEAD_DIM), pool_dtype)
+    pool = _shape(mesh, (n_pages, page, HEADS * HEAD_DIM), pool_dtype)
     scales = _shape(mesh, (n_pages, page, HEADS), jnp.float32)
     return (b, mp, pool, scales, _shape(mesh, (b, mp), jnp.int32),
             _shape(mesh, (b,), jnp.int32))
@@ -247,18 +247,72 @@ def epoch_program(v5e):
                     params, opt_state, tokens)
 
 
+# lm-serve-closed's own pools (benchmarks/configs/gpt2-medium.json under
+# workloads/lm-serve-closed.json): 32 slots x 16 pages of 64 + the trash
+# page, 16 heads of 64, bf16.  Two layers show what every layer does.
+CELL_LM = dict(vocab_size=8192, embed_dim=1024, num_layers=2, num_heads=16,
+               max_len=1024)
+CELL_SERVE = dict(max_slots=32, page_size=64)
+# (LM widths, serving sizes, dtype): chip_smoke's full sizes and the cell's
+POOL_PROGRAMS = {"smoke": (LM, SERVE, jnp.float32),
+                 "cell": (CELL_LM, CELL_SERVE, jnp.bfloat16)}
+
+
+def _batcher_programs(mesh, lm, serve, dtype, load_rows=2):
+    """The batcher's OWN decode step and page-load programs (its jits,
+    with their donation), compiled for the described chip at the pools'
+    real shape.  The batcher is built over a two-page pool, so nothing
+    of size is allocated here: the programs are lowered from shapes.
+    -> (decode step, load program, the pools' bytes, the pool's shape
+    as the compiled text writes it)."""
+    from mmlspark_tpu.serving.batcher import ContinuousBatcher
+
+    model = chip_smoke._lm(lm, dtype)
+    variables = {"params": jax.tree.map(
+        lambda a: _shape(mesh, a.shape, dtype),
+        _lm_variables(mesh, model, (1, 8)))}
+    b, page = serve["max_slots"], serve["page_size"]
+    mp = lm["max_len"] // page
+    batcher = ContinuousBatcher(model, variables, max_slots=b, paged=True,
+                                page_size=page, num_pages=2)
+    n_pages = b * mp + 1                  # the batcher's default pool
+    cache = jax.tree.map(
+        lambda a: _shape(mesh, (n_pages, *a.shape[1:]), a.dtype),
+        batcher._cache)
+    step = _compile(batcher._step, variables,
+                    _shape(mesh, (b, 1), jnp.int32), cache,
+                    _shape(mesh, (b,), jnp.int32),
+                    _shape(mesh, (b, mp), jnp.int32))
+    heads, head_dim = lm["num_heads"], lm["embed_dim"] // lm["num_heads"]
+    rows = jax.tree.map(
+        lambda a: _shape(mesh, (load_rows, lm["max_len"], heads, head_dim),
+                         a.dtype), batcher._cache)
+    load = _compile(batcher._load_paged_many, cache, rows,
+                    _shape(mesh, (load_rows * mp,), jnp.int32))
+    pools = jax.tree.leaves(cache)
+    hlo = {"float32": "f32", "bfloat16": "bf16"}[jnp.dtype(dtype).name]
+    return (step, load, sum(a.size * a.dtype.itemsize for a in pools),
+            f"{hlo}[{','.join(map(str, pools[0].shape))}]")
+
+
 @pytest.fixture(scope="module")
-def decode_program(v5e):
+def pool_programs(v5e):
+    """name -> _batcher_programs(...), compiled on first use."""
+    made = {}
+
+    def get(name):
+        if name not in made:
+            made[name] = _batcher_programs(v5e, *POOL_PROGRAMS[name])
+        return made[name]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def decode_program(pool_programs):
     """The batcher's slot-decode program (TransformerLM.decode_step over
-    page pools), compiled once for the tests that read it."""
-    model = chip_smoke._lm(LM, jnp.float32)
-    b, _mp, pool, _scales, table, pos = _paged_shapes(v5e, jnp.float32)
-    variables = {"params": _lm_variables(v5e, model, (1, 8))}
-    cache = tuple((pool, pool) for _ in range(LM["num_layers"]))
-    step = jax.jit(lambda v, t, c, p, pt: model.apply(
-        v, t, c, p, pt, method=model.decode_step))
-    return _compile(step, variables, _shape(v5e, (b, 1), jnp.int32),
-                    cache, pos, table)
+    page pools) at chip_smoke's full sizes."""
+    return pool_programs("smoke")[0]
 
 
 def test_lm_train_epoch_compiles_with_36_kernels(epoch_program):
@@ -275,6 +329,30 @@ def test_paged_decode_step_compiles_with_12_kernels(decode_program):
     """One page-walk kernel per layer."""
     assert decode_program.as_text().count("tpu_custom_call") == \
         LM["num_layers"]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "load_paged_many"])
+@pytest.mark.parametrize("sizes", list(POOL_PROGRAMS))
+def test_pool_programs_update_the_pools_in_place(pool_programs, sizes,
+                                                 program):
+    """The mechanism's counter, and it is static: the page pools keep the
+    layout their consumers read, and are donated.  So no program that
+    takes the pools holds a `copy` whose result has a pool's shape (a
+    [NP, page, H, D] pool was relaid four times a layer: to row-major
+    for the scatter and the page walk, and back), and every byte of the
+    pools is aliased from argument to result."""
+    import re
+
+    step, load, pool_bytes, pool_shape = pool_programs(sizes)
+    compiled = step if program == "decode_step" else load
+    copies = [line.strip()[:160] for line in compiled.as_text().splitlines()
+              if re.search(r"= " + re.escape(pool_shape)
+                           + r"\S* copy(-start)?\(", line)]
+    assert copies == []
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+    if program == "decode_step":
+        assert compiled.as_text().count("tpu_custom_call") == \
+            POOL_PROGRAMS[sizes][0]["num_layers"]
 
 
 def _metric_pattern(name):

@@ -553,6 +553,126 @@ def test_generate_stream_one_call_paged_speculative(lm, draft_lm):
     assert got == _reference(model, variables, [3, 1, 4], 6), got
 
 
+# ------------------------------------- the cache is updated in place only
+
+# what each mode's pools look like: (constructor options, speculative?)
+POOL_MODES = {
+    "dense": ({}, False),
+    "paged": (dict(paged=True, page_size=8), False),
+    "paged_int8": (dict(paged=True, page_size=8, kv_cache_dtype="int8"),
+                   False),
+    "paged_spec": (dict(paged=True, page_size=8, gamma=3), True),
+    "dense_spec": (dict(gamma=3), True),
+}
+
+
+@pytest.mark.parametrize("mode", list(POOL_MODES))
+def test_cache_buffers_are_consumed_by_every_program(lm, draft_lm, mode):
+    """Every program that takes the cache takes it donated: after an
+    admission and a tick, the buffers the batcher was built with are
+    gone (their memory is the new cache's), for the target's pools and
+    the draft's cache alike — and the streams are still generate()'s.
+    Paged pools are flat [NP, page, Hkv*D] (scale pools [NP, page,
+    Hkv])."""
+    model, variables = lm
+    kw, spec = POOL_MODES[mode]
+    if spec:
+        draft, dv = draft_lm
+        kw = dict(kw, draft_model=draft, draft_variables=dv)
+    batcher = ContinuousBatcher(model, variables, max_slots=2, **kw)
+    old = jax.tree.leaves(batcher._cache)
+    if spec:
+        old += jax.tree.leaves(batcher._d_cache)
+    if batcher.paged:
+        hd = model.kv_heads * (model.embed_dim // model.num_heads)
+        widths = {hd} | ({model.kv_heads}
+                         if kw.get("kv_cache_dtype") else set())
+        assert {leaf.shape[2:] for layer in batcher._cache
+                for leaf in layer} == {(w,) for w in widths}
+    batcher.start()
+    try:
+        prompts = [[3, 1, 4], [1, 5, 9, 2, 6], [5]]
+        got = [st.tokens() for st in
+               [batcher.submit(p, max_new_tokens=6) for p in prompts]]
+    finally:
+        batcher.stop()
+    assert all(leaf.is_deleted() for leaf in old)
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves(batcher._cache))
+    kv = kw.get("kv_cache_dtype")
+    for p, toks in zip(prompts, got):
+        ref = np.asarray(generate(
+            model, variables, jnp.asarray(p)[None], max_new_tokens=6,
+            kv_cache_dtype=kv))[0, len(p):].tolist()
+        assert toks == ref, (p, toks, ref)
+
+
+def test_prefix_registration_consumes_the_pools(lm):
+    """register_prefix loads pages through the same donated program,
+    inline on the caller's thread when no loop runs: the pools it found
+    are consumed, the ones it leaves are live and serve the prefix."""
+    model, variables = lm
+    batcher = ContinuousBatcher(model, variables, max_slots=2, paged=True,
+                                page_size=8)
+    old = jax.tree.leaves(batcher._cache)
+    prefix = [7, 3, 1, 4, 1, 5, 9, 2, 6, 5]
+    h = batcher.register_prefix(prefix)
+    assert all(leaf.is_deleted() for leaf in old)
+    batcher.start()
+    try:
+        toks = batcher.submit([8, 9], max_new_tokens=5, prefix=h).tokens()
+    finally:
+        batcher.stop()
+    assert toks == _reference(model, variables, prefix + [8, 9], 5)
+
+
+@pytest.fixture(scope="module")
+def wide_lm():
+    """Two heads of 64: the narrowest LM whose flat pools (H*D = 128)
+    the page-walk kernel takes."""
+    model = transformer_lm(vocab_size=64, embed_dim=128, num_layers=2,
+                           num_heads=2, max_len=64, dtype=jnp.float32)
+    variables = model.init({"params": jax.random.PRNGKey(1)},
+                           jnp.zeros((1, 4), jnp.int32), train=False)
+    return model, {c: v for c, v in variables.items() if c != "kvcache"}
+
+
+@pytest.mark.parametrize("kv", [None, "int8"], ids=["f32", "int8"])
+def test_paged_streams_through_the_page_walk_kernel(wide_lm, monkeypatch,
+                                                    kv):
+    """The batcher's paged step with the Pallas page walk in it (forced
+    on the CPU, interpret mode; only the paged arm: the prefill keeps
+    the XLA attention), over the batcher's own flat pools: streams still
+    match generate() token for token."""
+    from mmlspark_tpu.models import transformer
+    from mmlspark_tpu.ops import paged_attention as pa
+
+    model, variables = wide_lm
+    real = transformer.default_attn(True)
+    monkeypatch.setattr(transformer, "_single_tpu", lambda: True)
+    monkeypatch.setattr(transformer, "default_attn", lambda causal: real)
+    walked = []
+    for name in ("_paged_pallas", "_paged_pallas_int8"):
+        fn = getattr(pa, name)
+        monkeypatch.setattr(pa, name, lambda *a, _fn=fn, _n=name:
+                            (walked.append(_n), _fn(*a))[1])
+    prompts = [[3, 1, 4], [1, 5, 9, 2, 6, 5, 3, 5, 8, 9], [5]]
+    batcher = ContinuousBatcher(model, variables, max_slots=2, paged=True,
+                                page_size=8, kv_cache_dtype=kv).start()
+    try:
+        got = [st.tokens() for st in
+               [batcher.submit(p, max_new_tokens=7) for p in prompts]]
+    finally:
+        batcher.stop()
+    assert set(walked) == {"_paged_pallas_int8" if kv else "_paged_pallas"}
+    monkeypatch.undo()
+    for p, toks in zip(prompts, got):
+        ref = np.asarray(generate(
+            model, variables, jnp.asarray(p)[None], max_new_tokens=7,
+            kv_cache_dtype=kv))[0, len(p):].tolist()
+        assert toks == ref, (p, toks, ref)
+
+
 # ------------------------------------------------------ prefix caching
 
 def test_prefix_caching_streams_exact_and_pages_shared(lm):

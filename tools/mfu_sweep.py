@@ -281,8 +281,8 @@ def decode_child() -> int:
     if os.environ.get("DECODE_SWEEP_SMALL"):  # CPU interpret-mode cost
         h, d, page, mp, np_, nb = 2, 64, 8, 4, 6, 2
     q = jnp.asarray(rng.normal(size=(nb, h, d)), jnp.bfloat16)
-    kp = jnp.asarray(rng.normal(size=(np_, page, h, d)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(np_, page, h, d)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=(np_, page, h * d)), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=(np_, page, h * d)), jnp.bfloat16)
     tbl = jnp.asarray(np.tile(np.arange(mp) % (np_ - 1) + 1, (nb, 1)),
                       jnp.int32).at[:, 2:].set(0)  # 2 live pages/slot
     pos = jnp.full((nb,), 2 * page - 1, jnp.int32)
