@@ -496,3 +496,141 @@ def _flash_bwd(q, k, v, out, lse, g, causal):
 
 
 fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
+
+
+# ---- prefill: causal, grouped heads, optional window (forward only) -------
+@partial(jax.jit, static_argnames=("group", "window", "scale"))
+def _prefill_attention_pallas(q, k, v, group: int, window, scale: float):
+    """q [B*H, S, D], k/v [B*Hkv, S, D] (H = Hkv * group; query head
+    b*H + h reads KV head (b*H + h) // group) -> [B*H, S, D] f32.
+
+    The flash forward again, for serving's admission prefill: causal,
+    and with `window` (static) only keys in (query - window, query].  The
+    K axis of the grid is RELATIVE: step j of query block i reads key
+    block first(i) + j, first(i) the block of the oldest key the window
+    still shows to the block's first query, so a window layer visits
+    (window + block_q) / block_k + 1 key blocks a query block whatever
+    the prompt's length, and a full layer all of them (those above the
+    diagonal skipped, copy and compute)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, s, d = q.shape
+    block_q = min(_BLOCK_Q, s)
+    block_k = min(256, s) if window is not None else _pick_block_k(s)
+    n_kb = s // block_k
+    n_rel = n_kb
+    if window is not None:
+        n_rel = min(n_kb, (window + block_q - 2) // block_k + 2)
+
+    def first(qi):
+        if window is None:
+            return 0
+        return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+
+    def kernel(q_ref, k_ref, v_ref, o_ref, o_acc, m_acc, l_acc):
+        qi = pl.program_id(1)
+        j = pl.program_id(2)
+        kb_i = first(qi) + j
+
+        @pl.when(j == 0)
+        def _init():
+            o_acc[...] = jnp.zeros_like(o_acc)
+            m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
+            l_acc[...] = jnp.zeros_like(l_acc)
+
+        @pl.when(kb_i * block_k <= qi * block_q + block_q - 1)
+        def _update():
+            qb, kb, vb = q_ref[0], k_ref[0], v_ref[0]
+            sc = jax.lax.dot_general(
+                qb, kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            rows = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, sc.shape, 0)
+            cols = kb_i * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, sc.shape, 1)
+            seen = rows >= cols
+            if window is not None:
+                seen = seen & (cols > rows - window)
+            sc = jnp.where(seen, sc, _NEG_INF)
+            m_prev = jnp.max(m_acc[...], axis=-1, keepdims=True)
+            l_prev = jnp.max(l_acc[...], axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # a row whose every key of this block is masked keeps m at the
+            # stand-in: its p must be 0, not exp(0)
+            p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
+            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            o_acc[...] = o_acc[...] * corr + jax.lax.dot_general(
+                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_acc[...] = jnp.broadcast_to(m_new, m_acc.shape)
+            l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape)
+
+        @pl.when(j == n_rel - 1)
+        def _finish():
+            l_fin = jnp.max(l_acc[...], axis=-1, keepdims=True)
+            o_ref[0] = o_acc[...] / jnp.maximum(l_fin, 1e-20)
+
+    def kv_block(b, i, j):
+        # blocks above the diagonal park on the diagonal's: no new copy
+        diag = (i * block_q + block_q - 1) // block_k
+        return (b // group, jnp.minimum(first(i) + j, diag), 0)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
+        grid=(bh, s // block_q, n_rel),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, _LANE), jnp.float32),
+            pltpu.VMEM((block_q, _LANE), jnp.float32),
+        ],
+        interpret=_interpret(),
+    )(q, k, v)
+
+
+def _xla_prefill_attention(q, k, v, window):
+    """Dense composition of the same: [B, S, H|Hkv, D] -> [B, S, H, D]
+    f32."""
+    b, s, h, d = q.shape
+    group = h // k.shape[2]
+    k = jnp.repeat(k, group, axis=2)
+    v = jnp.repeat(v, group, axis=2)
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                    preferred_element_type=jnp.float32) / jnp.sqrt(
+                        jnp.float32(d))
+    rows, cols = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = rows >= cols
+    if window is not None:
+        seen = seen & (cols > rows - window)
+    p = jax.nn.softmax(jnp.where(seen[None, None], sc, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def prefill_attention_ok(q) -> bool:
+    """[B, S, H, D]: lane-wide heads and a sequence the blocks tile."""
+    _b, s, _h, d = q.shape
+    return d % _LANE == 0 and s % 8 == 0 and (s <= _BLOCK_Q or s % 256 == 0)
+
+
+def prefill_attention(q, k, v, window=None, kernel: bool = True):
+    """Causal attention of a whole prompt with grouped heads and an
+    optional window: q [B, S, H, D], k/v [B, S, Hkv, D] -> [B, S, H, D]
+    f32.  `kernel` False (or a shape the kernel declines) takes the XLA
+    composition."""
+    b, s, h, d = q.shape
+    hkv = k.shape[2]
+    if not (kernel and prefill_attention_ok(q)):
+        return _xla_prefill_attention(q, k, v, window)
+    o = _prefill_attention_pallas(
+        _to_bhsd(q, d), _to_bhsd(k, d), _to_bhsd(v, d), group=h // hkv,
+        window=window, scale=1.0 / float(d) ** 0.5)
+    return _from_bhsd(o, b, s, h, d)

@@ -54,6 +54,8 @@ next step retrace).
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -68,10 +70,10 @@ _SUBLANE = 8
 
 
 def paged_kernel_ok(q, k_pool) -> bool:
-    """Will the Pallas page-walk kernel take this shape?  q [B, H, D],
-    k_pool [NP, page, Hkv*D].  Conservative: MHA pools only (a GQA pool
-    is narrower than q's H*D — the XLA gather path serves it), a
-    lane-aligned flat width, a page of whole sublane tiles (8 rows: the
+    """Will a Pallas page-walk kernel take this shape?  q [B, H, D],
+    k_pool [NP, page, Hkv*D].  Conservative: MHA pools (`_page_walk`) or
+    grouped-query pools whose head size is a lane multiple
+    (`_page_walk_gqa`), a lane-aligned flat width, a page of whole sublane tiles (8 rows: the
     described v5e compiles f32, bf16 and int8 blocks alike from there),
     and the per-step working set must fit the VMEM budget (an oversized
     page config must route to the gather, not die in Mosaic)."""
@@ -82,9 +84,15 @@ def paged_kernel_ok(q, k_pool) -> bool:
     b, h, d = q.shape
     _, page, hd = k_pool.shape
     item = k_pool.dtype.itemsize
-    if hd != h * d or hd % _LANE:
-        return False
     if page % _SUBLANE:
+        return False
+    if hd != h * d:
+        # grouped-query pools go to `_page_walk_gqa`: whole query groups
+        # on KV heads that are lane tiles of their own; its blocks are a
+        # page of K and V and a [Hkv, G, D] query, far inside the budget
+        return (d % _LANE == 0 and hd % d == 0 and h % (hd // d) == 0
+                and page * hd * item * 4 <= PALLAS_IMAGE_VMEM_BUDGET)
+    if hd % _LANE:
         return False
     h_pad = -(-h // _LANE) * _LANE      # [page, H] tiles pad H to the lanes
     staged = (4 * page * hd * item      # K + V page blocks, double-buffered
@@ -236,6 +244,132 @@ def _paged_pallas_int8(q, kq_pool, ks_pool, vq_pool, vs_pool,
                       pos)
 
 
+def _ring_first(p_b, window, page):
+    """First logical page a window layer's walk visits at write position
+    p_b: the page of the oldest key still inside the window."""
+    return jnp.maximum(p_b - window + 1, 0) // page
+
+
+def _page_walk_gqa(q, k_pool, v_pool, page_table, pos, window=None):
+    """The page walk over grouped-query pools.  q [B, H, D]; pools [NP,
+    page, Hkv*D] with D a lane multiple; table [B, MP] i32; pos [B] i32
+    -> [B, H, D] f32.  Query head h reads KV head h // (H / Hkv).
+
+    Each KV head is a lane tile of its own, so a grid step slices the
+    page block per KV head and runs two small MXU products for the
+    head's whole query group ([G, D] x [D, page], [G, page] x [page, D];
+    bf16 operands, f32 accumulation and softmax statistics), the group
+    padded to a sublane tile outside the kernel.
+
+    `window` (static): the table is a RING of MP = window / page + 1
+    entries, logical page lp living at entry lp % MP (serving/batcher.py
+    recycles a slot's window pages that way).  The walk then starts at
+    the page of the oldest key inside the window and visits MP pages,
+    not the context's; keys at or before pos - window are masked."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, d = q.shape
+    _, page, hd = k_pool.shape
+    hkv = hd // d
+    g = h // hkv
+    gp = -(-g // _SUBLANE) * _SUBLANE
+    if k_pool.dtype == jnp.bfloat16:
+        gp = -(-g // 16) * 16
+    mp = page_table.shape[1]
+    scale = 1.0 / float(d) ** 0.5
+    qg = jnp.zeros((b, hkv, gp, d), k_pool.dtype).at[:, :, :g].set(
+        q.reshape(b, hkv, g, d).astype(k_pool.dtype))
+
+    def logical(j, p_b):
+        return j if window is None else _ring_first(p_b, window, page) + j
+
+    def kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+               o_acc, m_acc, l_acc):
+        bi = pl.program_id(0)
+        j = pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _init():
+            o_acc[...] = jnp.zeros_like(o_acc)
+            m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
+            l_acc[...] = jnp.zeros_like(l_acc)
+
+        p_b = pos_ref[bi]
+        lp = logical(j, p_b)
+
+        @pl.when(lp * page <= p_b)
+        def _update():
+            cols = lp * page + jax.lax.broadcasted_iota(
+                jnp.int32, (gp, page), 1)
+            seen = cols <= p_b
+            if window is not None:
+                seen = seen & (cols > p_b - window)
+            for kv in range(hkv):
+                lanes = slice(kv * d, (kv + 1) * d)
+                qb = q_ref[0, kv]                     # [Gp, D]
+                kb = k_ref[0, :, lanes]               # [page, D]
+                vb = v_ref[0, :, lanes]
+                sc = jax.lax.dot_general(
+                    qb, kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(seen, sc, _NEG_INF)    # [Gp, page]
+                m_prev = jnp.max(m_acc[kv], axis=-1, keepdims=True)
+                l_prev = jnp.max(l_acc[kv], axis=-1, keepdims=True)
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(sc, axis=-1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(sc - m_new)
+                l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+                o_acc[kv] = o_acc[kv] * corr + jax.lax.dot_general(
+                    p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_acc[kv] = jnp.broadcast_to(m_new, (gp, _LANE))
+                l_acc[kv] = jnp.broadcast_to(l_new, (gp, _LANE))
+
+        @pl.when(j == mp - 1)
+        def _finish():
+            for kv in range(hkv):
+                l_fin = jnp.max(l_acc[kv], axis=-1, keepdims=True)
+                o_ref[0, kv] = o_acc[kv] / jnp.maximum(l_fin, 1e-20)
+
+    def page_of(bi, j, tbl, pos):
+        # pages past the write position park on the trash page 0: same
+        # block as their neighbours, so their copy is skipped
+        lp = logical(j, pos[bi])
+        entry = lp if window is None else lp % mp
+        return (jnp.where(lp * page <= pos[bi], tbl[bi * mp + entry], 0),
+                0, 0)
+
+    q_spec = pl.BlockSpec((1, hkv, gp, d), lambda bi, j, tbl, pos:
+                          (bi, 0, 0, 0))
+    page_spec = pl.BlockSpec((1, page, hd), page_of)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, mp),
+            in_specs=[q_spec, page_spec, page_spec], out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((hkv, gp, d), jnp.float32),
+                            pltpu.VMEM((hkv, gp, _LANE), jnp.float32),
+                            pltpu.VMEM((hkv, gp, _LANE), jnp.float32)]),
+        interpret=_interpret(),
+    )(page_table.reshape(-1), pos, qg, k_pool, v_pool)
+    return out[:, :, :g].reshape(b, h, d)
+
+
+# Two names for one walk, so that the trace tells a window layer's calls
+# from a full layer's (`paged_attn_window_ms`, `paged_attn_full_ms`).
+@jax.jit
+def _paged_gqa_full(q, k_pool, v_pool, page_table, pos):
+    return _page_walk_gqa(q, k_pool, v_pool, page_table, pos)
+
+
+@partial(jax.jit, static_argnames=("window",))
+def _paged_gqa_window(q, k_pool, v_pool, page_table, pos, window: int):
+    return _page_walk_gqa(q, k_pool, v_pool, page_table, pos, window)
+
+
 def _gather_pages(pool, page_table, d):
     """pool [NP, page, Hkv*D] (or scales [NP, page, Hkv], d=None) ->
     the slots' logical view [B, MP*page, Hkv, D] ([B, MP*page, Hkv])."""
@@ -270,7 +404,8 @@ def paged_decode_attention_int8(q, kq_pool, ks_pool, vq_pool, vs_pool,
                                 page_table, pos):
     """int8 paged decode attention (the 4-tuple cache form): page-walk
     kernel when eligible, quant-factored XLA gather otherwise."""
-    if paged_kernel_ok(q, kq_pool):
+    if (kq_pool.shape[2] == q.shape[1] * q.shape[2]
+            and paged_kernel_ok(q, kq_pool)):
         return _paged_pallas_int8(q, kq_pool, ks_pool, vq_pool, vs_pool,
                                   page_table.astype(jnp.int32),
                                   pos.astype(jnp.int32))
@@ -278,10 +413,11 @@ def paged_decode_attention_int8(q, kq_pool, ks_pool, vq_pool, vs_pool,
                            page_table, pos)
 
 
-def _xla_paged(q, k_pool, v_pool, page_table, pos):
-    """Reference semantics: gather pages -> masked softmax attention.
-    Mirrors models/transformer._cache_attention for the paged branch.
-    GQA pools (hk < h) expand to the query head count after the gather."""
+def _gathered_attention(q, k_pool, v_pool, page_table, valid):
+    """Softmax attention of q [B, H, D] over each slot's gathered pages,
+    keys masked by `valid` [B, MP*page].  Mirrors
+    models/transformer._cache_attention for the paged branch.  GQA pools
+    (hk < h) expand to the query head count after the gather."""
     b, h, d = q.shape
     k_log = _gather_pages(k_pool, page_table, d)
     v_log = _gather_pages(v_pool, page_table, d)
@@ -291,19 +427,51 @@ def _xla_paged(q, k_pool, v_pool, page_table, pos):
         v_log = jnp.repeat(v_log, h // hk, axis=2)
     sc = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
                     k_log.astype(jnp.float32)) / jnp.sqrt(jnp.float32(d))
-    valid = jnp.arange(k_log.shape[1])[None, None, :] <= pos[:, None, None]
-    sc = jnp.where(valid, sc, -jnp.inf)
+    sc = jnp.where(valid[:, None, :], sc, -jnp.inf)
     p = jax.nn.softmax(sc, axis=-1)
     return jnp.einsum("bhk,bkhd->bhd", p, v_log.astype(jnp.float32))
 
 
-def paged_decode_attention(q, k_pool, v_pool, page_table, pos):
+def _xla_paged(q, k_pool, v_pool, page_table, pos):
+    """Reference semantics: gather pages -> masked softmax attention
+    over positions <= pos."""
+    n_keys = page_table.shape[1] * k_pool.shape[1]
+    return _gathered_attention(q, k_pool, v_pool, page_table,
+                               jnp.arange(n_keys)[None, :] <= pos[:, None])
+
+
+def _xla_paged_window(q, k_pool, v_pool, page_table, pos, window: int):
+    """Gather semantics of a window layer's RING table: entry e of slot
+    b holds the newest logical page lp <= pos // page with lp % MP == e.
+    Keys outside (pos - window, pos] are masked, which also hides the
+    rows of a recycled page that the new page has not overwritten yet."""
+    page, mp = k_pool.shape[1], page_table.shape[1]
+    cur = pos[:, None] // page                            # [B, 1]
+    lp = cur - (cur - jnp.arange(mp)[None]) % mp          # [B, MP]
+    kpos = (lp[:, :, None] * page
+            + jnp.arange(page)[None, None]).reshape(-1, mp * page)
+    valid = ((kpos >= 0) & (kpos <= pos[:, None])
+             & (kpos > pos[:, None] - window))
+    return _gathered_attention(q, k_pool, v_pool, page_table, valid)
+
+
+def paged_decode_attention(q, k_pool, v_pool, page_table, pos,
+                           window=None):
     """Single-token paged decode attention: q [B, H, D] over page pools
     [NP, page, Hkv*D] addressed by table [B, MP] at per-slot positions
     `pos` [B].  Pallas page-walk kernel when the shape allows, XLA
-    gather otherwise — identical numerics either way."""
+    gather otherwise — identical numerics either way.  `window` (static)
+    makes the table a ring of window / page + 1 pages (`_page_walk_gqa`)
+    and hides keys at or before pos - window."""
+    tbl, pos = page_table.astype(jnp.int32), pos.astype(jnp.int32)
     if paged_kernel_ok(q, k_pool):
-        return _paged_pallas(q, k_pool, v_pool,
-                             page_table.astype(jnp.int32),
-                             pos.astype(jnp.int32))
+        if k_pool.shape[2] != q.shape[1] * q.shape[2]:
+            if window is not None:
+                return _paged_gqa_window(q, k_pool, v_pool, tbl, pos,
+                                         window=window)
+            return _paged_gqa_full(q, k_pool, v_pool, tbl, pos)
+        if window is None:
+            return _paged_pallas(q, k_pool, v_pool, tbl, pos)
+    if window is not None:
+        return _xla_paged_window(q, k_pool, v_pool, tbl, pos, window)
     return _xla_paged(q, k_pool, v_pool, page_table, pos)

@@ -27,6 +27,28 @@ Greedy decode — the serving-stream shape; outputs are exactly
 
 Compose with serving: `stream_reply(lambda row: batcher.stream_text(...))`
 gives token-by-token HTTP with cross-request batching on the device.
+
+KINDS OF CACHE.  A model may say what state each layer keeps
+(`cache_kinds`, `layer_kinds`; models/moe_lm.py): the whole context
+("full") or only the last `window` positions ("window").  The batcher
+then keeps a pool set, a page table and a free list PER KIND, and an
+admission reserves in both.  The full kind is the state described above;
+a window slot's table is a RING of window / page + 1 pages
+(`_WindowPages`): logical page lp lives at entry lp % ring, so the page
+that has fallen wholly behind the window is the one the next page
+overwrites — recycled while the request runs, never more than the ring
+held.  Such a model also brings its own admission forward (`prefill`:
+the last position's logits only, cache rows of the bucket's length, at
+most `max_len` prompt tokens a program) and may name statistics
+(`stat_counters`), which ride back with the tick's token fetch.
+A model without the description (TransformerLM) is the case of one kind.
+Each arm below asks for the one thing it needs: `_own_prefill` (the
+model's admission forward), `_packed` (the step hands back tokens and
+statistics in one vector), `_win` (a second kind of page).
+
+`teacher_force` replays given requests through the same host path and
+the same programs, with what the programs computed handed back: the
+verifier's view of what is served (benchmarks/drivers/laguna_serve.py).
 """
 from __future__ import annotations
 
@@ -74,6 +96,92 @@ class PrefillStage(Stage):
 
     name = "prefill"
     credits = 4
+
+
+class _WindowPages:
+    """Host bookkeeping of the window kind: per slot a ring of at most
+    `ring` = window / page + 1 physical pages.  Loop-thread-owned, like
+    the full kind's free list and table."""
+
+    def __init__(self, window: int, page: int, slots: int,
+                 num_pages: Optional[int] = None):
+        if window % page:
+            raise ValueError(f"page_size {page} must divide the attention "
+                             f"window {window}")
+        self.window, self.page = int(window), int(page)
+        self.ring = self.window // self.page + 1
+        self.np = (int(num_pages) if num_pages is not None
+                   else slots * self.ring + 1)     # page 0 is trash here too
+        self.free: List[int] = list(range(1, self.np))
+        self.avail = len(self.free)
+        self.slot_pages: List[List[int]] = [[] for _ in range(slots)]
+        self.slot_reserved = [0] * slots
+        self.table = np.zeros((slots, self.ring), np.int32)
+
+    def worst(self, tokens: int) -> int:
+        """Pages a request of `tokens` positions can ever hold."""
+        return min(-(-tokens // self.page), self.ring)
+
+    def admit(self, slot: int, n: int) -> list:
+        """Allocate the pages a prompt of n tokens leaves live: its last
+        `ring` logical pages.  -> [(logical page, physical page)] to load."""
+        cur = (n - 1) // self.page
+        pages = [self.free.pop() for _ in range(min(cur + 1, self.ring))]
+        self.slot_pages[slot] = pages
+        self.table[slot].fill(0)
+        self.table[slot, :len(pages)] = pages
+        return [(lp, pages[lp % self.ring])
+                for lp in range(max(0, cur - self.ring + 1), cur + 1)]
+
+    def grow(self, slot: int, pos: int) -> int:
+        """Make the page of write position `pos` exist.  -> 1 when that
+        write starts overwriting a recycled page, else 0."""
+        lp = pos // self.page
+        pages = self.slot_pages[slot]
+        if lp < self.ring:
+            while lp >= len(pages):
+                pages.append(self.free.pop())
+                self.table[slot, len(pages) - 1] = pages[-1]
+            return 0
+        return int(pos % self.page == 0)
+
+    def release(self, slot: int) -> None:
+        self.free.extend(self.slot_pages[slot])
+        self.slot_pages[slot] = []
+        self.table[slot].fill(0)
+        self.avail += self.slot_reserved[slot]
+        self.slot_reserved[slot] = 0
+
+
+def _sum_stats(stats, names) -> jax.Array:
+    """A model's `stats` collection -> int32 [len(names)], each summed
+    over the layers that sowed it."""
+    flat = jax.tree_util.tree_flatten_with_path(stats)[0]
+    return jnp.stack([
+        sum((v.astype(jnp.int32) for path, v in flat
+             if getattr(path[-1], "key", None) == name),
+            jnp.zeros((), jnp.int32)) for name in names])
+
+
+def _by_tap(routing) -> dict:
+    """A model's `routing` collection ({layerN: {...: {tap: (value,)}}})
+    -> {tap: [layers that sowed it, in layer order, ...]}."""
+    flat = jax.tree_util.tree_flatten_with_path(routing)[0]
+    taps: dict = {}
+    for path, v in sorted(flat, key=lambda pv: int(pv[0][0].key[5:])):
+        tap = next(p.key for p in reversed(path) if hasattr(p, "key"))
+        taps.setdefault(tap, []).append(v)
+    return {tap: jnp.stack(vs) for tap, vs in taps.items()}
+
+
+def _paged_rows(rows, n_blocks: int, page: int):
+    """[K, S, W] cache rows -> [n_blocks, page, W] page blocks, S padded
+    up to whole pages."""
+    k, s_, w = rows.shape
+    pad = -s_ % page
+    if pad:
+        rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
+    return rows.reshape(n_blocks, page, w)
 
 
 class TokenStream:
@@ -152,6 +260,14 @@ class ContinuousBatcher:
                              f"got {kv_cache_dtype!r}")
         if (draft_model is None) != (draft_variables is None):
             raise ValueError("draft_model and draft_variables go together")
+        # a model that brings its own admission forward and cache
+        # description (module doc, KINDS OF CACHE)
+        self._own_prefill = hasattr(model, "prefill")
+        if self._own_prefill and (not paged or kv_cache_dtype is not None
+                         or draft_model is not None):
+            raise ValueError(
+                f"{type(model).__name__} is served over page pools in its "
+                "own dtype: paged=True, no kv_cache_dtype, no draft model")
         if draft_model is not None:
             if draft_model.vocab_size != model.vocab_size:
                 raise ValueError("draft and target must share a vocabulary")
@@ -191,9 +307,19 @@ class ContinuousBatcher:
         self.paged = bool(paged)
         self.draft_model = draft_model
         self.gamma = int(gamma) if draft_model is not None else 0
+        # a model's own admission program computes at most `max_len`
+        # prompt tokens (rows x bucket): a larger group of one bucket is
+        # split, so its temporaries are those of one full-length prompt
+        self._prefill_cap = model.max_len if self._own_prefill else None
+        self._packed = False       # set with the model's own programs
         s, L = self.max_slots, model.max_len
         h = model.kv_heads
-        d = model.embed_dim // model.num_heads
+        d = getattr(model, "head_dim", None) or (model.embed_dim
+                                                 // model.num_heads)
+        kinds = getattr(model, "cache_kinds", (("full", None),))
+        self._layer_kinds = tuple(getattr(model, "layer_kinds",
+                                          (0,) * model.num_layers))
+        self._win: Optional[_WindowPages] = None
         dt = jnp.float32 if model.dtype == jnp.float32 else model.dtype
         if self.paged:
             # vLLM-style paged KV: per-layer PAGE POOLS shared by every
@@ -227,6 +353,8 @@ class ContinuousBatcher:
             self._table = np.zeros((s, self._mp), np.int32)
             self._prefixes: dict = {}     # handle -> shared-prefix record
             self._next_prefix = 1
+            if len(kinds) > 1:
+                self._win = _WindowPages(kinds[1][1], self.page_size, s)
         else:
             shape_kv, shape_sc = (s, L, h, d), (s, L, h)
         if kv_cache_dtype == "int8":
@@ -239,9 +367,11 @@ class ContinuousBatcher:
                  jnp.zeros(shape_sc, jnp.float32))
                 for _ in range(model.num_layers))
         else:
+            shapes = [shape_kv] + ([(self._win.np, *shape_kv[1:])]
+                                   if self._win is not None else [])
             self._cache = tuple(
-                (jnp.zeros(shape_kv, dt), jnp.zeros(shape_kv, dt))
-                for _ in range(model.num_layers))
+                (jnp.zeros(shapes[kind], dt), jnp.zeros(shapes[kind], dt))
+                for kind in self._layer_kinds)
         self._pos = np.zeros(s, np.int32)
         self._tok = np.zeros(s, np.int32)
         self._live: List[Optional[_Request]] = [None] * s
@@ -305,6 +435,8 @@ class ContinuousBatcher:
                               -1).astype(pool.dtype),
                     mode="drop"),
                 c, rows), donate_argnums=(0,))
+        if self._own_prefill:
+            self._build_own_programs()
         if draft_model is not None:
             # speculative mode: the draft keeps a plain DENSE f32/bf16
             # slot cache (it is the small/cheap model; paging and int8
@@ -327,6 +459,135 @@ class ContinuousBatcher:
                 donate_argnums=(2,))
             self._d_prefill = jax.jit(lambda v, toks: _prefill_cache(
                 self.draft_model, v, toks))
+
+    def _build_own_programs(self):
+        """The programs of a model with its own `prefill`: the decode
+        step and the admission forward hand back ONE int32 vector, the
+        greedy tokens followed by the model's statistics (one fetch a
+        tick, as before), and the page load takes one id vector per
+        cache kind."""
+        self._stat_counters = tuple(getattr(self.model, "stat_counters", ()))
+        self._packed = True
+        self._step, self._prefill_last = self._own_programs(taps=False)
+
+        def load(c, rows, ids):
+            return tuple(
+                tuple(pool.at[ids[kind]].set(
+                    _paged_rows(r, ids[kind].shape[0],
+                                self.page_size).astype(pool.dtype),
+                    mode="drop") for pool, r in zip(pools, layer_rows))
+                for pools, layer_rows, kind in zip(c, rows,
+                                                   self._layer_kinds))
+
+        self._load_kinds = jax.jit(load, donate_argnums=(0,))
+
+    def _own_programs(self, taps: bool):
+        """(decode step, admission forward) over the model's own methods.
+        `taps`: the same functions also hand back the logits and the
+        model's `routing` collection (what `teacher_force` reads)."""
+        model = self.model
+        names = tuple(name for name, _counter in self._stat_counters)
+        asked = ["stats", "routing"] if taps else ["stats"]
+
+        def out(logits, kept):
+            packed = jnp.concatenate([
+                jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                _sum_stats(kept.get("stats", {}), names)])
+            if taps:
+                return packed, logits, _by_tap(kept.get("routing", {}))
+            return packed
+
+        def step(v, t, c, p, pt):
+            (lg, cache), kept = model.apply(
+                v, t, c, p, pt, method=model.decode_step, mutable=asked)
+            return out(lg[:, 0], kept), cache
+
+        def prefill(v, toks, last):
+            (lg, rows), kept = model.apply(
+                v, toks, last, method=model.prefill, mutable=asked)
+            return out(lg, kept), rows
+
+        return jax.jit(step, donate_argnums=(2,)), jax.jit(prefill)
+
+    def _note_stats(self, values) -> None:
+        for (_name, counter), value in zip(self._stat_counters, values):
+            telemetry.incr(counter, int(value))
+
+    def teacher_force(self, pairs) -> list:
+        """Replay (prompt ids, reply ids) pairs as they would be served:
+        the same host path (reservation, page tables of every kind,
+        just-in-time growth, the ring), the same program functions at
+        the same shapes (every prompt admitted alone, all replies decoded
+        together among the idle slots), but each step is fed the GIVEN
+        reply token, and the programs hand back what they computed.
+
+        -> per pair {"logits": [len(reply), V] (row j is what chose reply
+        token j), "routing": {tap: [routed layers, P, ...]}} over the
+        P = len(prompt) + len(reply) - 1 positions fed, the taps being
+        the model's `routing` collection.  For a stopped (or never
+        started) batcher whose model brings its own programs."""
+        if not self._packed:
+            raise ValueError("teacher_force needs a model with its own "
+                             "prefill and decode programs")
+        if self._thread is not None and self._thread.is_alive():
+            raise RuntimeError("stop the loop before teacher_force")
+        if len(pairs) > self.max_slots:
+            raise ValueError(f"{len(pairs)} pairs on {self.max_slots} slots")
+        served = self._step, self._prefill_last
+        seen: list = []
+
+        def handing_back(program):
+            def call(*args):
+                (packed, logits, routing), rest = program(*args)
+                seen.append((logits, routing))
+                return packed, rest
+            return call
+
+        self._step, self._prefill_last = map(handing_back,
+                                             self._own_programs(taps=True))
+        try:
+            live, out = {}, []
+            for prompt, reply in pairs:
+                req = _Request(np.asarray(prompt, np.int32).reshape(-1),
+                               len(reply), None)
+                self._buffer.append(req)
+                batch = self._plan_admit()
+                if len(batch) != 1:
+                    self._buffer.remove(req)
+                    raise RuntimeError("no free slot or pages to replay in")
+                slot = batch[0][0]
+                self._admit_batch(batch)
+                logits, routing = seen.pop()
+                n = len(req.prompt)
+                rec = {"logits": [np.asarray(logits[0])],
+                       "routing": {tap: [np.asarray(v[:, 0, :n])]
+                                   for tap, v in routing.items()}}
+                out.append(rec)
+                if self._live[slot] is req:
+                    live[slot] = (req, reply, rec)
+            while live:
+                active = sorted(live)
+                for slot in active:
+                    req, reply, _rec = live[slot]
+                    self._tok[slot] = reply[req.emitted - 1]
+                self._grow_pages(active)
+                self._decode_tick(active)
+                logits, routing = seen.pop()
+                logits = np.asarray(logits)
+                routing = {tap: np.asarray(v) for tap, v in routing.items()}
+                for slot in active:
+                    req, _reply, rec = live[slot]
+                    rec["logits"].append(logits[slot])
+                    for tap, v in routing.items():
+                        rec["routing"][tap].append(v[:, slot])
+                    if self._live[slot] is not req:
+                        del live[slot]
+        finally:
+            self._step, self._prefill_last = served
+        return [{"logits": np.stack(rec["logits"]),
+                 "routing": {tap: np.concatenate(parts, axis=1)
+                             for tap, parts in rec["routing"].items()}}
+                for rec in out]
 
     def _page_ceiling(self) -> int:
         """Pages that can EVER be simultaneously free for one request:
@@ -392,6 +653,9 @@ class ContinuousBatcher:
         Returns a handle for submit()/release_prefix()."""
         if not self.paged:
             raise ValueError("prefix caching needs paged=True")
+        if self._own_prefill:
+            raise ValueError("shared prefixes need slot BLOCK decode, which "
+                             f"{type(self.model).__name__} does not have")
         ids = np.asarray(prefix_ids, np.int32).reshape(-1)
         if len(ids) < 1:
             raise ValueError("empty prefix")
@@ -677,6 +941,10 @@ class ContinuousBatcher:
             return group, kp, padded, slots
 
         buckets = sorted(by_bucket.items())
+        if self._prefill_cap is not None:
+            buckets = [(b, group[i:i + cap]) for b, group in buckets
+                       for cap in [self._rows_cap(b)]
+                       for i in range(0, len(group), cap)]
         if len(buckets) > 1:
             packed = FlowGraph([PrefillStage(fn=pack_bucket)],
                                label="prefill").run(buckets)
@@ -685,6 +953,11 @@ class ContinuousBatcher:
         for group, kp, padded, slots in packed:
             k = len(group)
             t_bucket = time.monotonic()
+            if self._own_prefill:
+                firsts = self._admit_bucket_own(group, kp, padded)
+                self._finish_admit(group, firsts, padded.shape[1], kp,
+                                   t_bucket)
+                continue
             with telemetry.phase(ADMIT_PREFILL):
                 # the upload rides the feed engine: counted bytes, transfer
                 # spans on the request trace, the feed.device_put fault point
@@ -704,13 +977,9 @@ class ContinuousBatcher:
                     # inside the last page is masked/overwritten as in dense
                     ids = np.full((kp, self._mp), self._np, np.int32)
                     for i, (slot, req) in enumerate(group):
-                        need = -(-len(req.prompt) // self.page_size)
-                        pages = [self._free.pop() for _ in range(need)]
-                        self._slot_pages[slot] = pages
-                        self._slot_shared[slot] = 0
-                        self._table[slot].fill(0)
-                        self._table[slot, :need] = pages
-                        ids[i, :need] = pages
+                        pages = self._take_prompt_pages(slot,
+                                                        len(req.prompt))
+                        ids[i, :len(pages)] = pages
                     self._cache = self._load_paged_many(
                         self._cache, cache, jnp.asarray(ids.reshape(-1)))
                 else:
@@ -721,14 +990,78 @@ class ContinuousBatcher:
                     jnp.arange(kp), jnp.asarray(
                         [len(r.prompt) - 1 for _s, r in group]
                         + [0] * (kp - k))], axis=-1), np.int32)
-            self._note_prefill(
-                [(slot, req, len(req.prompt)) for slot, req in group],
-                padded.shape[1], kp, t_bucket)
+            self._finish_admit(group, firsts, padded.shape[1], kp, t_bucket)
+
+    def _take_prompt_pages(self, slot: int, n: int) -> list:
+        """Allocate the full-kind pages of an n-token prompt to `slot`
+        and wire its table."""
+        pages = [self._free.pop() for _ in range(-(-n // self.page_size))]
+        self._slot_pages[slot] = pages
+        self._slot_shared[slot] = 0
+        self._table[slot].fill(0)
+        self._table[slot, :len(pages)] = pages
+        return pages
+
+    def _finish_admit(self, group, firsts, bucket: int, kp: int,
+                      t_bucket: float):
+        """A bucket's forward is loaded: account it, make its slots live
+        and emit their first tokens."""
+        self._note_prefill(
+            [(slot, req, len(req.prompt)) for slot, req in group],
+            bucket, kp, t_bucket)
+        for i, (slot, req) in enumerate(group):
+            self._live[slot] = req
+            self._pos[slot] = len(req.prompt)
+            self._tok[slot] = int(firsts[i])
+            self._emit(slot, int(firsts[i]))
+
+    def _admit_bucket_own(self, group, kp: int, padded) -> np.ndarray:
+        """One bucket through the model's own `prefill`: the logits of
+        each row's last position only, cache rows of the bucket's length,
+        loaded into the pages of every kind (a window layer keeps only
+        the prompt's last ring of pages).  -> the first tokens."""
+        page, win = self.page_size, self._win
+        n_pg = -(-padded.shape[1] // page)
+        with telemetry.phase(ADMIT_PREFILL):
+            last = np.full(kp, -1, np.int32)       # a pad row has no token
+            ids = [np.full((kp, n_pg), self._np, np.int32)]
+            if win is not None:
+                ids.append(np.full((kp, n_pg), win.np, np.int32))
             for i, (slot, req) in enumerate(group):
-                self._live[slot] = req
-                self._pos[slot] = len(req.prompt)
-                self._tok[slot] = int(firsts[i])
-                self._emit(slot, int(firsts[i]))
+                n = len(req.prompt)
+                last[i] = n - 1
+                pages = self._take_prompt_pages(slot, n)
+                ids[0][i, :len(pages)] = pages
+                # (query, key) pairs a layer of each kind attends: in
+                # all (with the decode ticks'), and the admissions' own
+                pairs = n * (n + 1) // 2
+                telemetry.incr("serving.batcher.attended.full", pairs)
+                telemetry.incr("serving.batcher.prefill.attended.full", pairs)
+                if win is not None:
+                    for lp, pg in win.admit(slot, n):
+                        ids[1][i, lp] = pg
+                    w = min(n, win.window)
+                    pairs = w * (w + 1) // 2 + (n - w) * w
+                    telemetry.incr("serving.batcher.attended.window", pairs)
+                    telemetry.incr("serving.batcher.prefill.attended.window",
+                                   pairs)
+            d_padded, d_last = self._feed.put_group([padded, last])
+            out, rows = self._prefill_last(self.variables, d_padded, d_last)
+            self._cache = self._load_kinds(
+                self._cache, rows,
+                tuple(jnp.asarray(x.reshape(-1)) for x in ids))
+        with telemetry.phase(ADMIT_FIRST_TOKEN):
+            out = np.asarray(out)
+        self._note_stats(out[kp:])
+        return out[:kp]
+
+    def _rows_cap(self, bucket: int) -> int:
+        """Most rows of one admission program of this bucket under
+        `_prefill_cap` prompt tokens: a power of two, at least one."""
+        cap = 1
+        while cap * 2 * bucket <= self._prefill_cap:
+            cap *= 2
+        return cap
 
     def _pad_rows(self, k: int) -> int:
         """Rows of a prefill program for `k` requests: the next power of
@@ -865,6 +1198,8 @@ class ContinuousBatcher:
                 self._table[slot].fill(0)
                 self._avail += self._slot_reserved[slot]
                 self._slot_reserved[slot] = 0
+                if self._win is not None:
+                    self._win.release(slot)
                 if req.prefix is not None:
                     with self._submit_lock:
                         self._prefixes[req.prefix]["refs"] -= 1
@@ -945,6 +1280,12 @@ class ContinuousBatcher:
                                           shared)
                 if worst > self._avail:
                     break
+                if self._win is not None:
+                    worst_w = self._win.worst(len(req.prompt) + req.max_new)
+                    if worst_w > self._win.avail:
+                        break
+                    self._win.avail -= worst_w
+                    self._win.slot_reserved[slot] = worst_w
                 self._avail -= worst
                 self._slot_reserved[slot] = worst
             self._buffer.popleft()
@@ -1003,15 +1344,7 @@ class ContinuousBatcher:
                 # pos + gamma (the admission reservation guarantees the
                 # free list can cover it)
                 with telemetry.phase(TICK_GROW):
-                    for sl in active:
-                        idx = ((int(self._pos[sl]) + self.gamma)
-                               // self.page_size)
-                        while idx >= (self._slot_shared[sl]
-                                      + len(self._slot_pages[sl])):
-                            pg = self._free.pop()
-                            self._table[sl, self._slot_shared[sl]
-                                        + len(self._slot_pages[sl])] = pg
-                            self._slot_pages[sl].append(pg)
+                    self._grow_pages(active)
             if self.draft_model is not None:
                 fetch_s = self._speculative_tick(active)
             else:
@@ -1021,6 +1354,34 @@ class ContinuousBatcher:
         telemetry.histogram("serving.batcher.tick.host").observe(
             max(0.0, tick_s - fetch_s))
 
+    def _grow_pages(self, active) -> None:
+        """Make the pages of this tick's write positions exist, in every
+        kind of pool, and count the two page populations (pages in use,
+        summed a tick as `live_tokens` is) with the K/V rows a layer of
+        each kind attends."""
+        for sl in active:
+            idx = (int(self._pos[sl]) + self.gamma) // self.page_size
+            while idx >= (self._slot_shared[sl]
+                          + len(self._slot_pages[sl])):
+                pg = self._free.pop()
+                self._table[sl, self._slot_shared[sl]
+                            + len(self._slot_pages[sl])] = pg
+                self._slot_pages[sl].append(pg)
+        pos = self._pos[active].astype(np.int64) + 1
+        telemetry.incr("serving.batcher.pages.full",
+                       sum(len(self._slot_pages[sl]) for sl in active))
+        telemetry.incr("serving.batcher.attended.full", int(pos.sum()))
+        win = self._win
+        if win is None:
+            return
+        recycled = sum(win.grow(sl, int(self._pos[sl])) for sl in active)
+        telemetry.incr("serving.batcher.pages.window",
+                       sum(len(win.slot_pages[sl]) for sl in active))
+        telemetry.incr("serving.batcher.attended.window",
+                       int(np.minimum(pos, win.window).sum()))
+        if recycled:
+            telemetry.incr("serving.batcher.pages.window_recycled", recycled)
+
     def _decode_tick(self, active) -> float:
         """ONE batched step for every slot (free slots compute too —
         their pos 0 writes are dead: dense mode overwrites the rows on
@@ -1028,7 +1389,13 @@ class ContinuousBatcher:
         packed upload of this tick's tok/pos(/table) vectors.  Returns
         the seconds the host stood blocked on the device."""
         with telemetry.phase(TICK_UPLOAD):
-            if self.paged:
+            if self._packed:           # one table per kind of cache
+                tables = [self._table] + ([self._win.table]
+                                          if self._win is not None else [])
+                d_tok, d_pos, *d_tbl = self._feed.put_group(
+                    [self._tok[:, None], self._pos, *tables])
+                d_tbl = tuple(d_tbl)
+            elif self.paged:
                 d_tok, d_pos, d_tbl = self._feed.put_group(
                     [self._tok[:, None], self._pos, self._table])
             else:
@@ -1039,8 +1406,13 @@ class ContinuousBatcher:
             lg, self._cache = self._step(
                 self.variables, d_tok, self._cache, d_pos, d_tbl)
         with telemetry.phase(TICK_FETCH) as fetch:
-            nxt = np.asarray(jnp.argmax(lg[:, 0], axis=-1), np.int32)
+            if self._packed:           # tokens and statistics in one vector
+                nxt = np.asarray(lg)
+            else:
+                nxt = np.asarray(jnp.argmax(lg[:, 0], axis=-1), np.int32)
         with telemetry.phase(TICK_EMIT):
+            if self._packed:
+                self._note_stats(nxt[self.max_slots:])
             for slot in active:
                 self._pos[slot] += 1
                 self._tok[slot] = nxt[slot]

@@ -355,6 +355,119 @@ def test_pool_programs_update_the_pools_in_place(pool_programs, sizes,
             POOL_PROGRAMS[sizes][0]["num_layers"]
 
 
+# ---- Laguna-S-2.1: decode step, one admission, the page load ---------------
+# benchmarks/configs/laguna-s-2.1.json at its published widths and its
+# share (128 experts held, 50,176 vocabulary rows), cut to TWO layers:
+# layer 0 (dense MLP, full attention, 48 heads) and layer 1 (routed
+# experts, window attention, 72 heads) show both kinds of pool, both page
+# walks and the grouped matmul.  laguna-serve-mixed's own pools: 32 slots,
+# pages of 64, 4,096 positions (64 pages a slot; a ring of 9 in the window
+# kind).
+LAGUNA_SERVE = dict(max_slots=32, page_size=64, context=4096)
+LAGUNA_ADMISSION = (4, 512)            # rows x bucket of one admission
+
+
+@pytest.fixture(scope="module")
+def laguna_programs(v5e):
+    """-> {"decode_step", "admission", "load"} compiled for the described
+    chip, the pools' bytes and their shapes as the compiled text writes
+    them."""
+    import json
+
+    from mmlspark_tpu.models.moe_lm import MoELM
+    from mmlspark_tpu.serving.batcher import ContinuousBatcher
+
+    with open(os.path.join(BENCH, "configs", "laguna-s-2.1.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    b, page, ctx = (LAGUNA_SERVE[k] for k in ("max_slots", "page_size",
+                                              "context"))
+    model = MoELM.from_config(cfg, ctx, jnp.bfloat16)
+    variables = {"params": _lm_variables(v5e, model, (1, 8))}
+    batcher = ContinuousBatcher(model, variables, max_slots=b, paged=True,
+                                page_size=page, num_pages=2)
+    mp, ring = ctx // page, batcher._win.ring
+    assert ring == cfg["sliding_window"] // page + 1 == 9
+    pages = (b * mp + 1, batcher._win.np)
+    cache = tuple(
+        tuple(_shape(v5e, (pages[kind], page, model.kv_width), jnp.bfloat16)
+              for _ in range(2)) for kind in batcher._layer_kinds)
+    tables = (_shape(v5e, (b, mp), jnp.int32),
+              _shape(v5e, (b, ring), jnp.int32))
+    k, bucket = LAGUNA_ADMISSION
+    rows = tuple(tuple(_shape(v5e, (k, bucket, model.kv_width), jnp.bfloat16)
+                       for _ in range(2)) for _ in batcher._layer_kinds)
+    ids = tuple(_shape(v5e, (k * bucket // page,), jnp.int32)
+                for _ in range(2))
+    pools = jax.tree.leaves(cache)
+    # what `teacher_force` runs: the same functions handing back logits
+    # and the routed layers' taps, a whole-context prompt admitted alone
+    replay_step, replay_admission = batcher._own_programs(taps=True)
+    return {
+        "replay_step": _compile(
+            replay_step, variables, _shape(v5e, (b, 1), jnp.int32), cache,
+            _shape(v5e, (b,), jnp.int32), tables),
+        "replay_admission": _compile(
+            replay_admission, variables, _shape(v5e, (1, ctx), jnp.int32),
+            _shape(v5e, (1,), jnp.int32)),
+        "decode_step": _compile(
+            batcher._step, variables, _shape(v5e, (b, 1), jnp.int32), cache,
+            _shape(v5e, (b,), jnp.int32), tables),
+        "admission": _compile(
+            batcher._prefill_last, variables,
+            _shape(v5e, (k, bucket), jnp.int32), _shape(v5e, (k,), jnp.int32)),
+        "load": _compile(batcher._load_kinds, cache, rows, ids),
+        "pool_bytes": sum(a.size * a.dtype.itemsize for a in pools),
+        "pool_shapes": [f"bf16[{n},{page},{model.kv_width}]" for n in pages],
+    }
+
+
+@pytest.fixture(scope="module")
+def laguna_decode_program(laguna_programs):
+    return laguna_programs["decode_step"]
+
+
+@pytest.fixture(scope="module")
+def laguna_admission_program(laguna_programs):
+    return laguna_programs["admission"]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "load"])
+def test_laguna_pool_programs_update_the_pools_in_place(laguna_programs,
+                                                        program):
+    """Both kinds of pool are donated and keep their layout: every byte
+    aliased from argument to result, no `copy` of a pool's shape."""
+    import re
+
+    compiled = laguna_programs[program]
+    text = compiled.as_text()
+    for shape in laguna_programs["pool_shapes"]:
+        assert [line.strip()[:160] for line in text.splitlines()
+                if re.search(r"= " + re.escape(shape)
+                             + r"\S* copy(-start)?\(", line)] == []
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        laguna_programs["pool_bytes"]
+
+
+@pytest.mark.parametrize("program,calls", [("decode_step", 4),
+                                           ("admission", 4),
+                                           ("replay_step", 4),
+                                           ("replay_admission", 4)])
+def test_laguna_programs_carry_their_kernels(laguna_programs, program,
+                                             calls):
+    """Two attention calls (page walks, or the prefill flash forward) and
+    the sparse layer's two grouped matmuls; and the admission, which makes
+    no [rows, bucket, vocabulary] logits, fits the chip beside the whole
+    share's weights.  The programs `teacher_force` replays with are the
+    same calls with more handed back."""
+    compiled = laguna_programs[program]
+    assert compiled.as_text().count("tpu_custom_call") == calls
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9
+    if program == "admission":
+        k, _bucket = LAGUNA_ADMISSION
+        assert f"f32[{k},50176]" in compiled.as_text()
+
+
 def _metric_pattern(name):
     import json
 
@@ -371,6 +484,25 @@ KERNEL_NAMES = [
     ("epoch_program", "flash_attn_roofline", "_attention_bwd_dq",
      LM["num_layers"]),
     ("decode_program", "paged_attn_ms", "_paged_pallas", LM["num_layers"]),
+    # Laguna's two layers: one full and one window page walk, and the gate/up
+    # and down calls of the one sparse layer's grouped matmul
+    ("laguna_decode_program", "paged_attn_full_ms", "_paged_gqa_full", 1),
+    ("laguna_decode_program", "paged_attn_window_ms", "_paged_gqa_window", 1),
+    ("laguna_decode_program", "paged_attn_roofline", "_paged_gqa_full", 1),
+    ("laguna_decode_program", "paged_attn_roofline", "_paged_gqa_window", 1),
+    ("laguna_decode_program", "moe_expert_ms", "_moe_gmm_decode", 2),
+    ("laguna_decode_program", "moe_expert_roofline", "_moe_gmm_decode", 2),
+    ("laguna_decode_program", "moe_expert_decode_ms", "_moe_gmm_decode", 2),
+    # the admission: the same two calls at the MXU's row tile, and each
+    # layer's flash forward
+    ("laguna_admission_program", "moe_expert_ms", "_moe_gmm_prefill", 2),
+    ("laguna_admission_program", "moe_expert_roofline", "_moe_gmm_prefill", 2),
+    ("laguna_admission_program", "moe_expert_prefill_ms", "_moe_gmm_prefill",
+     2),
+    ("laguna_admission_program", "prefill_attn_ms",
+     "_prefill_attention_pallas", 2),
+    ("laguna_admission_program", "prefill_attn_roofline",
+     "_prefill_attention_pallas", 2),
 ]
 
 

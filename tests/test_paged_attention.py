@@ -71,7 +71,11 @@ def test_kernel_pos_zero_single_row():
 def test_dispatch_predicate():
     q, k_pool, *_ = _setup()
     assert paged_kernel_ok(q, k_pool)
-    assert not paged_kernel_ok(q, k_pool[:, :, :2 * 128])   # GQA pool
+    # a grouped-query pool of lane-wide heads goes to _page_walk_gqa ...
+    assert paged_kernel_ok(q, k_pool[:, :, :2 * 128])
+    # ... one of narrower heads to the gather
+    assert not paged_kernel_ok(jnp.zeros((2, 4, 64), jnp.float32),
+                               jnp.zeros((4, 8, 2 * 64), jnp.float32))
     q65 = jnp.zeros((2, 4, 65), jnp.float32)
     assert not paged_kernel_ok(q65, jnp.zeros((4, 8, 4 * 65), jnp.float32))
     # the flat width is what has to fill the lanes, not the head's own
