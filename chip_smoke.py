@@ -411,18 +411,18 @@ def phase_lm_serve(size: dict, seed: int) -> dict:
                 for stream in [batcher.submit(p, max_new_tokens=2)
                                for p in wave]:
                     stream.tokens()
-            if batcher._prefill._cache_size() >= len(waves):
+            if batcher._prefill_first._cache_size() >= len(waves):
                 break
         else:
             raise RuntimeError("warmup never formed every admission shape")
         # then the decode program's evidence and the references (they
         # compile too)
+        # (the step as the loop calls it: the step before's tokens and
+        # positions, an override, the admissions' first tokens, tables)
         decode_calls = _custom_calls(batcher._step.lower(
-            batcher.variables,
-            jax.ShapeDtypeStruct((cfg["max_slots"], 1), jnp.int32),
-            batcher._cache,
-            jax.ShapeDtypeStruct((cfg["max_slots"],), jnp.int32),
-            jax.ShapeDtypeStruct(batcher._table.shape, jnp.int32)).compile())
+            batcher.variables, batcher._cache, batcher._d_out,
+            batcher._d_pos, batcher._keep, batcher._none,
+            batcher._d_tables).compile())
         reference = jax.jit(lambda v, t: generate(model, v, t, n_new))
         want = [np.asarray(reference(variables, jnp.asarray([p], jnp.int32))
                            )[0, len(p):].tolist() for p in prompts]

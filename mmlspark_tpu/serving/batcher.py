@@ -20,10 +20,46 @@ consumes `self._cache`'s buffers and hands back the same memory,
 updated.  Nothing may keep a reference to a pool across such a call.
 
 Host loop per tick: admit pending prompts into free slots (one prefill
-forward each; its padded cache rows overwrite the slot), one batched
-decode step for ALL slots, emit each live slot's token to its stream.
-Greedy decode — the serving-stream shape; outputs are exactly
-`generate()`'s for every stream regardless of co-tenancy (tested).
+forward each; its padded cache rows overwrite the slot), DISPATCH one
+batched decode step for ALL slots, and only then fetch what was
+dispatched before it and emit those tokens.  Greedy decode — the
+serving-stream shape; outputs are exactly `generate()`'s for every
+stream regardless of co-tenancy (tested).
+
+ONE STEP AHEAD.  The decode loop is a pipeline of depth one: step N+1
+is queued before step N's tokens have come back, so the device runs it
+while the host fetches, emits and accounts step N.  What that takes:
+
+* The next token and the positions live on the device.  The step picks
+  its tokens (argmax inside the program) and hands them back beside
+  `pos + 1`; the next step takes both vectors as they stand.  The host
+  uploads only what the device cannot know — a `[2, S]` override of
+  token and position (-1: keep) when a slot was admitted, parked or
+  fed a given token, and the page tables when one changed.  A tick in
+  which nothing changed uploads nothing.  `_tok`/`_pos` are the host's
+  mirrors: `_pos` is bumped at DISPATCH (growth and the counters read
+  the position the queued step writes), `_tok` follows a tick late.
+* An admission does not wait for its first tokens either: the prefill
+  program scatters them into a `[S]` vector the next step reads (-1:
+  none), and the host fetches them after that step is queued.  What is
+  dispatched and not yet fetched sits in `_flight`, oldest first, and
+  `_inflight[slot]` counts the slot's tokens in it.
+* A request's end the host can foresee (`max_new_tokens`, `max_len`:
+  `_Request.limit`) parks the slot — position 0 over a zeroed table
+  row, as a free slot is — in the first dispatch that would overrun.
+  An `eos_id` end is seen a tick late: the step already in flight
+  wrote one more row into a page the slot still owns (the reservation
+  covers every position up to `max_new_tokens`); its token is dropped
+  and counted (`tick.late_discards`), and the slot, its pages and its
+  reservation go back only after that fetch.
+* Order on the device does the rest: every program takes the pools
+  donated, so an admission's page load runs after the step in flight
+  and before the step that reads its rows.
+
+`teacher_force` feeds every step a GIVEN token and `_speculative_tick`
+verifies before it proposes again: both dispatch and fetch at once.
+`stop()` fetches whatever is in flight, so a stopped batcher's cache,
+mirrors and tables agree.
 
 Compose with serving: `stream_reply(lambda row: batcher.stream_text(...))`
 gives token-by-token HTTP with cross-request batching on the device.
@@ -43,8 +79,10 @@ most `max_len` prompt tokens a program) and may name statistics
 (`stat_counters`), which ride back with the tick's token fetch.
 A model without the description (TransformerLM) is the case of one kind.
 Each arm below asks for the one thing it needs: `_own_prefill` (the
-model's admission forward), `_packed` (the step hands back tokens and
-statistics in one vector), `_win` (a second kind of page).
+model's admission forward; its programs hand back the model's
+statistics behind their tokens), `_win` (a second kind of page).  The
+decode tick has no arms: every step takes the step before's vector and
+one page table per kind, and hands back tokens, then statistics.
 
 `teacher_force` replays given requests through the same host path and
 the same programs, with what the programs computed handed back: the
@@ -74,17 +112,20 @@ __all__ = ["ContinuousBatcher", "PrefillStage", "TokenStream"]
 # under benchmarks/ and with docs/observability.md; written once, here.
 TICK = "serving.batcher.tick"               # an iteration with work to do
 TICK_INTAKE = TICK + ".intake"              # control ops, intake -> buffer
-TICK_ADMIT = TICK + ".admit"                # one admission, all buckets
+TICK_ADMIT = TICK + ".admit"                # an admission: dispatch; fetch
 ADMIT_PACK = "serving.batcher.admit.pack"   # host packing of one bucket
 ADMIT_PREFILL = "serving.batcher.admit.prefill"   # upload, forward, load
 ADMIT_FIRST_TOKEN = "serving.batcher.admit.first_token"   # blocking fetch
 TICK_GROW = TICK + ".grow"                  # just-in-time page growth
 TICK_DRAFT = TICK + ".draft"                # speculative: the draft's steps
-TICK_UPLOAD = TICK + ".upload"              # tok/pos(/table) in one put
+TICK_UPLOAD = TICK + ".upload"              # what changed, in one put
 TICK_DISPATCH = TICK + ".dispatch"          # the call of the decode step
-TICK_FETCH = TICK + ".fetch"                # the host blocked on the device
-TICK_EMIT = TICK + ".emit"                  # position bumps, streams
+TICK_FETCH = TICK + ".fetch"                # blocked on the step before
+TICK_EMIT = TICK + ".emit"                  # fetched tokens -> streams
 IDLE = "serving.batcher.idle"               # nothing live: wait on intake
+# counters of the one-step-ahead loop (module doc)
+TICK_OVERLAPPED = TICK + ".overlapped"      # dispatched before that fetch
+TICK_LATE_DISCARDS = TICK + ".late_discards"   # dead rows' tokens dropped
 
 
 class PrefillStage(Stage):
@@ -174,6 +215,25 @@ def _by_tap(routing) -> dict:
     return {tap: jnp.stack(vs) for tap, vs in taps.items()}
 
 
+def _one_step_ahead(forward, slots: int):
+    """The decode step as the loop dispatches it, over `forward` (tokens
+    [S, 1], cache, positions, page tables -> (an int32 vector led by the
+    S tokens it chose, or a tuple led by that vector; cache)).  `prev`
+    and `pos` are the step before's two outputs as they stand on the
+    device; each slot takes its token from the host's override `ovr[0]`,
+    else from `adm` (an admission's first token), else from `prev`, and
+    its position from `ovr[1]` or `pos` (-1 throughout: not given).
+    -> (forward's first output, the next step's positions, cache): a
+    parked slot stays at position 0, every other moves on one."""
+    def step(v, c, prev, pos, ovr, adm, tables):
+        tok = jnp.where(ovr[0] >= 0, ovr[0],
+                        jnp.where(adm >= 0, adm, prev[:slots]))
+        pos = jnp.where(ovr[1] >= 0, ovr[1], pos)
+        out, cache = forward(v, tok[:, None], c, pos, tables)
+        return out, pos + (pos > 0), cache
+    return step
+
+
 def _paged_rows(rows, n_blocks: int, page: int):
     """[K, S, W] cache rows -> [n_blocks, page, W] page blocks, S padded
     up to whole pages."""
@@ -223,11 +283,28 @@ class _Request:
         self.deadline = deadline      # absolute monotonic admission budget
         self.stream = TokenStream()
         self.emitted = 0
+        self.limit = self.max_new     # tokens it can ever get (_go_live)
+        self.closed = False           # its stream has ended
         # submitter's trace context: admission latency is attributed back
         # to the submitting request's span (the loop is another thread)
         self.trace = telemetry.current_context()
         self.submitted_at = time.monotonic()
         self.first_token_at = 0.0     # set by the admission that took it
+
+
+class _Flight:
+    """A program dispatched whose tokens the host has not fetched: a
+    decode step or one bucket of an admission.  `out` is its int32
+    vector on the device, `n_tok` tokens then the model's statistics;
+    `rows` the (index into it, slot) pairs it made a token for;
+    `admitted` an admission's (group, bucket, rows of the program,
+    start) for `_note_prefill`, None for a decode step."""
+
+    __slots__ = ("out", "n_tok", "rows", "admitted")
+
+    def __init__(self, out, n_tok: int, rows, admitted=None):
+        self.out, self.n_tok, self.rows = out, n_tok, rows
+        self.admitted = admitted
 
 
 class ContinuousBatcher:
@@ -311,7 +388,6 @@ class ContinuousBatcher:
         # prompt tokens (rows x bucket): a larger group of one bucket is
         # split, so its temporaries are those of one full-length prompt
         self._prefill_cap = model.max_len if self._own_prefill else None
-        self._packed = False       # set with the model's own programs
         s, L = self.max_slots, model.max_len
         h = model.kv_heads
         d = getattr(model, "head_dim", None) or (model.embed_dim
@@ -372,8 +448,24 @@ class ContinuousBatcher:
             self._cache = tuple(
                 (jnp.zeros(shapes[kind], dt), jnp.zeros(shapes[kind], dt))
                 for kind in self._layer_kinds)
+        # loop-thread state of the decode pipeline (module doc, ONE STEP
+        # AHEAD).  Host mirrors: `_pos` the position the NEXT dispatch
+        # writes, `_tok` the last token fetched, `_give` a token the host
+        # knows and the device does not (-1: none), `_inflight` the
+        # slot's tokens dispatched and not fetched, `_flight` those
+        # programs.  What the device holds: `_d_out`/`_d_pos` the last
+        # step's two outputs (`_held`: the host's copy of `_d_pos`),
+        # `_adm` the admissions' first tokens since then, `_d_tables`
+        # the tables as last uploaded (`_sent`: the host's copy)
         self._pos = np.zeros(s, np.int32)
         self._tok = np.zeros(s, np.int32)
+        self._give = np.full(s, -1, np.int32)
+        self._inflight = np.zeros(s, np.int32)
+        self._flight: "deque[_Flight]" = deque()
+        self._stat_counters: tuple = ()
+        self._held = np.zeros(s, np.int32)
+        self._sent: Optional[list] = None
+        self._d_tables: tuple = ()
         self._live: List[Optional[_Request]] = [None] * s
         # the intake is a graftflow AdmissionStage: bounded shed at
         # submit() (Overloaded/503 past max_pending), expired-deadline
@@ -403,7 +495,17 @@ class ContinuousBatcher:
         self._thread: Optional[threading.Thread] = None
         # the cache argument of every program below is DONATED (module
         # doc): each call site rebinds `self._cache` from the result
-        self._step = jax.jit(
+        def greedy_step(v, t, c, p, tables):
+            lg, cache = self.model.apply(
+                v, t, c, p, tables[0] if tables else None,
+                method=self.model.decode_step)
+            return jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32), cache
+
+        self._step = jax.jit(_one_step_ahead(greedy_step, s),
+                             donate_argnums=(1,))
+        # slot BLOCK decode, logits out: a shared prefix's suffix forward
+        # and the speculative verification, whose callers pick per row
+        self._block_step = jax.jit(
             lambda v, t, c, p, pt: self.model.apply(
                 v, t, c, p, pt, method=self.model.decode_step),
             donate_argnums=(2,))
@@ -414,6 +516,18 @@ class ContinuousBatcher:
 
         self._prefill = jax.jit(lambda v, toks: _prefill_cache(
             self.model, v, toks, self.kv_cache_dtype))
+
+        def prefill_first(v, toks, last, slots, adm):
+            # an admission's forward: each row's first token, picked at
+            # its last prompt position and scattered into `adm` at its
+            # slot (a pad row's slot is out of range and drops)
+            logits, rows = _prefill_cache(self.model, v, toks,
+                                          self.kv_cache_dtype)
+            firsts = jnp.argmax(logits[jnp.arange(toks.shape[0]), last],
+                                axis=-1).astype(jnp.int32)
+            return firsts, adm.at[slots].set(firsts, mode="drop"), rows
+
+        self._prefill_first = jax.jit(prefill_first)
         # whole-slot overwrite: admitted requests' padded cache rows
         # replace their slots across every layer in one jitted update;
         # pad rows carry the OUT-OF-RANGE slot id S so mode="drop"
@@ -437,6 +551,13 @@ class ContinuousBatcher:
                 c, rows), donate_argnums=(0,))
         if self._own_prefill:
             self._build_own_programs()
+        # what a step reads when nothing is given: no override, no
+        # admission, and before the first step no step before
+        self._keep = jnp.full((2, s), -1, jnp.int32)
+        self._none = jnp.full(s, -1, jnp.int32)
+        self._adm = self._none
+        self._d_out = jnp.zeros(s + len(self._stat_counters), jnp.int32)
+        self._d_pos = jnp.zeros(s, jnp.int32)
         if draft_model is not None:
             # speculative mode: the draft keeps a plain DENSE f32/bf16
             # slot cache (it is the small/cheap model; paging and int8
@@ -463,11 +584,9 @@ class ContinuousBatcher:
     def _build_own_programs(self):
         """The programs of a model with its own `prefill`: the decode
         step and the admission forward hand back ONE int32 vector, the
-        greedy tokens followed by the model's statistics (one fetch a
-        tick, as before), and the page load takes one id vector per
-        cache kind."""
+        greedy tokens followed by the model's statistics (one fetch
+        each), and the page load takes one id vector per cache kind."""
         self._stat_counters = tuple(getattr(self.model, "stat_counters", ()))
-        self._packed = True
         self._step, self._prefill_last = self._own_programs(taps=False)
 
         def load(c, rows, ids):
@@ -497,17 +616,20 @@ class ContinuousBatcher:
                 return packed, logits, _by_tap(kept.get("routing", {}))
             return packed
 
-        def step(v, t, c, p, pt):
+        def forward(v, t, c, p, tables):
             (lg, cache), kept = model.apply(
-                v, t, c, p, pt, method=model.decode_step, mutable=asked)
+                v, t, c, p, tables, method=model.decode_step, mutable=asked)
             return out(lg[:, 0], kept), cache
 
-        def prefill(v, toks, last):
+        def prefill(v, toks, last, slots, adm):
             (lg, rows), kept = model.apply(
                 v, toks, last, method=model.prefill, mutable=asked)
-            return out(lg, kept), rows
+            made = out(lg, kept)
+            firsts = (made[0] if taps else made)[:toks.shape[0]]
+            return made, adm.at[slots].set(firsts, mode="drop"), rows
 
-        return jax.jit(step, donate_argnums=(2,)), jax.jit(prefill)
+        return (jax.jit(_one_step_ahead(forward, self.max_slots),
+                        donate_argnums=(1,)), jax.jit(prefill))
 
     def _note_stats(self, values) -> None:
         for (_name, counter), value in zip(self._stat_counters, values):
@@ -519,14 +641,15 @@ class ContinuousBatcher:
         just-in-time growth, the ring), the same program functions at
         the same shapes (every prompt admitted alone, all replies decoded
         together among the idle slots), but each step is fed the GIVEN
-        reply token, and the programs hand back what they computed.
+        reply token and fetched before the next is dispatched, and the
+        programs hand back what they computed.
 
         -> per pair {"logits": [len(reply), V] (row j is what chose reply
         token j), "routing": {tap: [routed layers, P, ...]}} over the
         P = len(prompt) + len(reply) - 1 positions fed, the taps being
         the model's `routing` collection.  For a stopped (or never
         started) batcher whose model brings its own programs."""
-        if not self._packed:
+        if not self._own_prefill:
             raise ValueError("teacher_force needs a model with its own "
                              "prefill and decode programs")
         if self._thread is not None and self._thread.is_alive():
@@ -538,9 +661,9 @@ class ContinuousBatcher:
 
         def handing_back(program):
             def call(*args):
-                (packed, logits, routing), rest = program(*args)
+                (packed, logits, routing), *rest = program(*args)
                 seen.append((logits, routing))
-                return packed, rest
+                return (packed, *rest)
             return call
 
         self._step, self._prefill_last = map(handing_back,
@@ -556,7 +679,8 @@ class ContinuousBatcher:
                     self._buffer.remove(req)
                     raise RuntimeError("no free slot or pages to replay in")
                 slot = batch[0][0]
-                self._admit_batch(batch)
+                for flight in self._admit_batch(batch):
+                    self._collect(flight)
                 logits, routing = seen.pop()
                 n = len(req.prompt)
                 rec = {"logits": [np.asarray(logits[0])],
@@ -569,9 +693,9 @@ class ContinuousBatcher:
                 active = sorted(live)
                 for slot in active:
                     req, reply, _rec = live[slot]
-                    self._tok[slot] = reply[req.emitted - 1]
+                    self._give[slot] = reply[req.emitted - 1]
                 self._grow_pages(active)
-                self._decode_tick(active)
+                self._collect(self._dispatch(active))
                 logits, routing = seen.pop()
                 logits = np.asarray(logits)
                 routing = {tap: np.asarray(v) for tap, v in routing.items()}
@@ -867,6 +991,12 @@ class ContinuousBatcher:
                 raise RuntimeError(
                     "ContinuousBatcher loop thread failed to exit within "
                     "300s; refusing to drain its queues concurrently")
+        # the loop left between two iterations, with a step (and maybe an
+        # admission) dispatched and not fetched: their tokens belong to
+        # their streams, and cache, mirrors and tables agree only once
+        # they are in (teacher_force replays in what is left)
+        while self._flight:
+            self._collect(self._flight.popleft())
         # unblock any consumers still waiting on admitted streams
         for req in self._live:
             if req is not None:
@@ -903,7 +1033,10 @@ class ContinuousBatcher:
         instead of one per request.  Row counts pad to powers of two
         (capped at max_slots) so each bucket compiles O(log max_slots)
         batch shapes; pad rows compute garbage that the slot-indexed
-        loads drop (out-of-range sentinel + mode='drop')."""
+        loads drop (out-of-range sentinel + mode='drop').  Nothing here
+        waits for the device: -> the buckets' `_Flight`s, whose first
+        tokens `_collect` fetches (a shared prefix's rows, whose first
+        tokens the host picks itself, are live on return)."""
         now = time.monotonic()
         queue_wait = telemetry.histogram("serving.batcher.queue_wait")
         for slot, req in batch:
@@ -950,19 +1083,33 @@ class ContinuousBatcher:
                                label="prefill").run(buckets)
         else:  # one bucket: nothing to overlap, skip the worker thread
             packed = map(pack_bucket, buckets)
+        flights = []
         for group, kp, padded, slots in packed:
-            k = len(group)
             t_bucket = time.monotonic()
             if self._own_prefill:
-                firsts = self._admit_bucket_own(group, kp, padded)
-                self._finish_admit(group, firsts, padded.shape[1], kp,
-                                   t_bucket)
+                firsts = self._admit_bucket_own(group, kp, padded, slots)
+                flights.append(self._finish_admit(
+                    group, firsts, padded.shape[1], kp, t_bucket))
                 continue
             with telemetry.phase(ADMIT_PREFILL):
                 # the upload rides the feed engine: counted bytes, transfer
                 # spans on the request trace, the feed.device_put fault point
-                d_padded = self._feed.put(padded)
-                logits, cache = self._prefill(self.variables, d_padded)
+                last = np.zeros(kp, np.int32)
+                last[:len(group)] = [len(r.prompt) - 1 for _s, r in group]
+                ups = [padded, last, slots]
+                if self.paged:
+                    # allocate each slot's prompt pages: all rows' prefill
+                    # pages scatter in one update below; bucketing garbage
+                    # inside the last page is masked/overwritten as in dense
+                    ids = np.full((kp, self._mp), self._np, np.int32)
+                    for i, (slot, req) in enumerate(group):
+                        pages = self._take_prompt_pages(slot,
+                                                        len(req.prompt))
+                        ids[i, :len(pages)] = pages
+                    ups.append(ids.reshape(-1))
+                d_padded, d_last, d_slots, *d_ids = self._feed.put_group(ups)
+                firsts, self._adm, cache = self._prefill_first(
+                    self.variables, d_padded, d_last, d_slots, self._adm)
                 if self.draft_model is not None:
                     # the draft's cache must hold the same prompt history;
                     # its prefill logits are unused — the first pending
@@ -970,27 +1117,16 @@ class ContinuousBatcher:
                     _dlg, d_rows = self._d_prefill(self.draft_variables,
                                                    d_padded)
                     self._d_cache = self._load_many(self._d_cache, d_rows,
-                                                    jnp.asarray(slots))
+                                                    d_slots)
                 if self.paged:
-                    # allocate each slot's prompt pages and scatter all
-                    # rows' prefill pages in one update; bucketing garbage
-                    # inside the last page is masked/overwritten as in dense
-                    ids = np.full((kp, self._mp), self._np, np.int32)
-                    for i, (slot, req) in enumerate(group):
-                        pages = self._take_prompt_pages(slot,
-                                                        len(req.prompt))
-                        ids[i, :len(pages)] = pages
-                    self._cache = self._load_paged_many(
-                        self._cache, cache, jnp.asarray(ids.reshape(-1)))
+                    self._cache = self._load_paged_many(self._cache, cache,
+                                                        *d_ids)
                 else:
                     self._cache = self._load_many(self._cache, cache,
-                                                  jnp.asarray(slots))
-            with telemetry.phase(ADMIT_FIRST_TOKEN):
-                firsts = np.asarray(jnp.argmax(logits[
-                    jnp.arange(kp), jnp.asarray(
-                        [len(r.prompt) - 1 for _s, r in group]
-                        + [0] * (kp - k))], axis=-1), np.int32)
-            self._finish_admit(group, firsts, padded.shape[1], kp, t_bucket)
+                                                  d_slots)
+            flights.append(self._finish_admit(
+                group, firsts, padded.shape[1], kp, t_bucket))
+        return flights
 
     def _take_prompt_pages(self, slot: int, n: int) -> list:
         """Allocate the full-kind pages of an n-token prompt to `slot`
@@ -1003,23 +1139,36 @@ class ContinuousBatcher:
         return pages
 
     def _finish_admit(self, group, firsts, bucket: int, kp: int,
-                      t_bucket: float):
-        """A bucket's forward is loaded: account it, make its slots live
-        and emit their first tokens."""
-        self._note_prefill(
-            [(slot, req, len(req.prompt)) for slot, req in group],
-            bucket, kp, t_bucket)
-        for i, (slot, req) in enumerate(group):
-            self._live[slot] = req
-            self._pos[slot] = len(req.prompt)
-            self._tok[slot] = int(firsts[i])
-            self._emit(slot, int(firsts[i]))
+                      t_bucket: float) -> _Flight:
+        """A bucket's forward and load are dispatched: its slots are
+        live from here, their first tokens in flight (`firsts`, on the
+        device: `kp` tokens, then the model's statistics)."""
+        for slot, req in group:
+            self._go_live(slot, req)
+        return _Flight(firsts, kp,
+                       [(i, slot) for i, (slot, _r) in enumerate(group)],
+                       admitted=(group, bucket, kp, t_bucket))
 
-    def _admit_bucket_own(self, group, kp: int, padded) -> np.ndarray:
+    def _go_live(self, slot: int, req: _Request, first: Optional[int] = None):
+        """The slot is `req`'s: the next dispatch writes the position
+        behind the prompt.  `first`: its first token where the host
+        picked it (the next step is given it); else that token is in
+        flight, and in `_adm` for the next step."""
+        self._live[slot] = req
+        req.limit = min(req.max_new, self.model.max_len - len(req.prompt))
+        self._pos[slot] = len(req.prompt)
+        if first is None:
+            self._inflight[slot] += 1
+        else:
+            self._tok[slot] = self._give[slot] = first
+            self._emit(slot, first)
+
+    def _admit_bucket_own(self, group, kp: int, padded, slots):
         """One bucket through the model's own `prefill`: the logits of
         each row's last position only, cache rows of the bucket's length,
         loaded into the pages of every kind (a window layer keeps only
-        the prompt's last ring of pages).  -> the first tokens."""
+        the prompt's last ring of pages).  -> the program's vector on
+        the device: the first tokens, then the statistics."""
         page, win = self.page_size, self._win
         n_pg = -(-padded.shape[1] // page)
         with telemetry.phase(ADMIT_PREFILL):
@@ -1045,15 +1194,12 @@ class ContinuousBatcher:
                     telemetry.incr("serving.batcher.attended.window", pairs)
                     telemetry.incr("serving.batcher.prefill.attended.window",
                                    pairs)
-            d_padded, d_last = self._feed.put_group([padded, last])
-            out, rows = self._prefill_last(self.variables, d_padded, d_last)
-            self._cache = self._load_kinds(
-                self._cache, rows,
-                tuple(jnp.asarray(x.reshape(-1)) for x in ids))
-        with telemetry.phase(ADMIT_FIRST_TOKEN):
-            out = np.asarray(out)
-        self._note_stats(out[kp:])
-        return out[:kp]
+            d_padded, d_last, d_slots, *d_ids = self._feed.put_group(
+                [padded, last, slots, *(x.reshape(-1) for x in ids)])
+            out, self._adm, rows = self._prefill_last(
+                self.variables, d_padded, d_last, d_slots, self._adm)
+            self._cache = self._load_kinds(self._cache, rows, tuple(d_ids))
+        return out
 
     def _rows_cap(self, bucket: int) -> int:
         """Most rows of one admission program of this bucket under
@@ -1137,10 +1283,7 @@ class ContinuousBatcher:
                     else:
                         first = int(np.argmax(rec["last_logits"]))
                         self._note_prefill([(slot, req, 0)], 0, 0, t_bucket)
-                        self._live[slot] = req
-                        self._pos[slot] = n
-                        self._tok[slot] = first
-                        self._emit(slot, first)
+                        self._go_live(slot, req, first)
                 if not fill:
                     continue
                 k = len(fill)
@@ -1155,7 +1298,7 @@ class ContinuousBatcher:
             with telemetry.phase(ADMIT_PREFILL):
                 d_toks, d_fpos, d_tbls = self._feed.put_group(
                     [toks, pos, tables])
-                logits, self._cache = self._step(
+                logits, self._cache = self._block_step(
                     self.variables, d_toks, self._cache, d_fpos, d_tbls)
             with telemetry.phase(ADMIT_FIRST_TOKEN):
                 firsts = np.asarray(jnp.argmax(logits[
@@ -1166,43 +1309,50 @@ class ContinuousBatcher:
                 [(slot, req, len(req.prompt) - st) for slot, req, st in fill],
                 rb, kp, t_bucket)
             for i, (slot, req, _st) in enumerate(fill):
-                self._live[slot] = req
-                self._pos[slot] = len(req.prompt)
-                self._tok[slot] = int(firsts[i])
-                self._emit(slot, int(firsts[i]))
+                self._go_live(slot, req, int(firsts[i]))
 
     def _emit(self, slot: int, tok: int):
+        """Hand `tok` to the slot's stream, and end the request where it
+        ends.  The slot goes back at once unless a token of its is still
+        in flight (an `eos_id` end, module doc): then `_collect`
+        releases it with that step's dropped token."""
         req = self._live[slot]
         req.emitted += 1
         req.stream._q.put(tok)
-        done = (req.emitted >= req.max_new
-                or (req.eos_id is not None and tok == req.eos_id)
-                or int(self._pos[slot]) + 1 >= self.model.max_len)
-        if done:
+        if (req.emitted >= req.limit
+                or (req.eos_id is not None and tok == req.eos_id)):
             req.stream._q.put(None)
+            req.closed = True
             if req.trace is not None:
                 telemetry.record_span(
                     "serving.batcher.decode", req.trace,
                     time.monotonic() - req.first_token_at,
                     tokens=req.emitted, slot=slot)
-            self._live[slot] = None
-            # park the freed slot at position 0: a slot that finished
-            # near max_len must not leave a stale pos that speculative
-            # lookahead (pos + gamma) could push past the cache bound
-            self._pos[slot] = 0
-            self._tok[slot] = 0
-            if self.paged:  # return OWNED pages + release the reservation
-                self._free.extend(self._slot_pages[slot])
-                self._slot_pages[slot] = []
-                self._slot_shared[slot] = 0
-                self._table[slot].fill(0)
-                self._avail += self._slot_reserved[slot]
-                self._slot_reserved[slot] = 0
-                if self._win is not None:
-                    self._win.release(slot)
-                if req.prefix is not None:
-                    with self._submit_lock:
-                        self._prefixes[req.prefix]["refs"] -= 1
+            if not self._inflight[slot]:
+                self._release(slot)
+
+    def _release(self, slot: int):
+        """A finished request's slot, pages and reservation go back."""
+        req = self._live[slot]
+        self._live[slot] = None
+        # park the freed slot at position 0: a slot that finished
+        # near max_len must not leave a stale pos that speculative
+        # lookahead (pos + gamma) could push past the cache bound
+        self._pos[slot] = 0
+        self._tok[slot] = 0
+        self._give[slot] = -1
+        if self.paged:  # return OWNED pages + release the reservation
+            self._free.extend(self._slot_pages[slot])
+            self._slot_pages[slot] = []
+            self._slot_shared[slot] = 0
+            self._table[slot].fill(0)
+            self._avail += self._slot_reserved[slot]
+            self._slot_reserved[slot] = 0
+            if self._win is not None:
+                self._win.release(slot)
+            if req.prefix is not None:
+                with self._submit_lock:
+                    self._prefixes[req.prefix]["refs"] -= 1
 
     def _drain_intake(self):
         while True:  # control ops first: admissions may depend on them
@@ -1309,50 +1459,68 @@ class ContinuousBatcher:
 
     def _tick(self):
         """One loop iteration with work to do: drain the intake, admit
-        into free slots, then ONE decode step for every live slot.  The
-        timers are the loop's own: `tick.latency` is the iteration
-        without its admission (which stalls every live reply, and is
-        timed as `admit.latency`), `tick.host` the same without the
-        blocking fetch.  An iteration that finds nothing to decode
-        (everything it admitted finished on its first token, or nothing
-        could be admitted) observes neither."""
+        into free slots, DISPATCH one decode step for every slot that
+        wants a token, then fetch and emit what was dispatched before it
+        (module doc, ONE STEP AHEAD).  The timers are the loop's own:
+        `admit.latency` is the admission (packing and dispatch, and the
+        fetch of its first tokens), `tick.latency` the iteration without
+        it, `tick.host` the same without the blocking fetch, which is
+        the PREVIOUS step's: what the host does beside the step it has
+        just queued.  An iteration that dispatches no step (nothing
+        could be admitted, or it only fetches the last tokens in flight)
+        observes neither."""
         t0 = time.perf_counter()
-        admit_s = 0.0
+        admit_s = fetch_s = 0.0
         with telemetry.phase(TICK):
             with telemetry.phase(TICK_INTAKE):
                 self._drain_intake()
             batch = self._plan_admit()
             if batch:
-                with telemetry.phase(TICK_ADMIT, telemetry.histogram(
-                        "serving.batcher.admit.latency")) as admit:
-                    self._admit_batch(batch)
+                with telemetry.phase(TICK_ADMIT) as admit:
+                    flights = self._admit_batch(batch)
+                    if self.draft_model is not None:
+                        # the speculative round proposes from the host's
+                        # tokens: it needs them now
+                        for flight in flights:
+                            self._collect(flight)
+                    else:
+                        self._flight.extend(flights)
                 admit_s = admit.elapsed_s
-            active = [s for s in range(self.max_slots)
-                      if self._live[s] is not None]
-            if not active:
-                # nothing live -> every reservation is released, so the
-                # head always fits; the next iteration admits it
-                return
-            telemetry.histogram("serving.batcher.batch_fill").observe(
-                len(active) / self.max_slots)
-            # the K/V rows this tick's attention has to read
-            telemetry.incr("serving.batcher.live_tokens",
-                           int(self._pos[active].sum()))
-            if self.paged:
-                # grow each active slot's page list just-in-time for this
-                # tick's write positions — speculative mode writes up to
-                # pos + gamma (the admission reservation guarantees the
-                # free list can cover it)
-                with telemetry.phase(TICK_GROW):
-                    self._grow_pages(active)
-            if self.draft_model is not None:
+            # the slots this iteration's step makes a token for: live,
+            # and short of their last token even with those in flight.
+            # Every other slot is parked in it.  (None live and nothing
+            # in flight -> every reservation is released, so the head
+            # always fits; the next iteration admits it)
+            active = [s for s, req in enumerate(self._live)
+                      if req is not None and not req.closed
+                      and req.emitted + self._inflight[s] < req.limit]
+            if active:
+                telemetry.histogram("serving.batcher.batch_fill").observe(
+                    len(active) / self.max_slots)
+                # the K/V rows this step's attention has to read
+                telemetry.incr("serving.batcher.live_tokens",
+                               int(self._pos[active].sum()))
+                if self.paged:
+                    # grow each active slot's page list just-in-time for
+                    # this step's write positions — speculative mode
+                    # writes up to pos + gamma (the admission reservation
+                    # guarantees the free list can cover it)
+                    with telemetry.phase(TICK_GROW):
+                        self._grow_pages(active)
+            if self.draft_model is None:
+                fetch_s, first_s = self._decode_tick(active)
+                admit_s += first_s
+            elif active:
                 fetch_s = self._speculative_tick(active)
-            else:
-                fetch_s = self._decode_tick(active)
             tick_s = time.perf_counter() - t0 - admit_s
-        telemetry.histogram("serving.batcher.tick.latency").observe(tick_s)
-        telemetry.histogram("serving.batcher.tick.host").observe(
-            max(0.0, tick_s - fetch_s))
+        if batch:
+            telemetry.histogram("serving.batcher.admit.latency").observe(
+                admit_s)
+        if active:
+            telemetry.histogram("serving.batcher.tick.latency").observe(
+                tick_s)
+            telemetry.histogram("serving.batcher.tick.host").observe(
+                max(0.0, tick_s - fetch_s))
 
     def _grow_pages(self, active) -> None:
         """Make the pages of this tick's write positions exist, in every
@@ -1382,41 +1550,94 @@ class ContinuousBatcher:
         if recycled:
             telemetry.incr("serving.batcher.pages.window_recycled", recycled)
 
-    def _decode_tick(self, active) -> float:
-        """ONE batched step for every slot (free slots compute too —
-        their pos 0 writes are dead: dense mode overwrites the rows on
-        admit, paged mode routes them to the trash page), fed by ONE
-        packed upload of this tick's tok/pos(/table) vectors.  Returns
-        the seconds the host stood blocked on the device."""
+    def _decode_tick(self, active):
+        """Dispatch ONE batched step for `active`, then fetch and emit
+        everything dispatched before it: the step of the iteration
+        before, and this iteration's admission.  -> (seconds blocked on
+        that step, seconds spent on the admission's first tokens)."""
+        older = list(self._flight)
+        self._flight.clear()
+        if active:
+            if any(f.admitted is None for f in older):
+                telemetry.incr(TICK_OVERLAPPED)
+            self._flight.append(self._dispatch(active))
+        fetch_s = first_s = 0.0
+        for flight in older:
+            if flight.admitted is None:
+                fetch_s += self._collect(flight)
+            else:
+                with telemetry.phase(TICK_ADMIT) as admit:
+                    self._collect(flight)
+                first_s += admit.elapsed_s
+        return fetch_s, first_s
+
+    def _dispatch(self, active) -> _Flight:
+        """Queue ONE batched step for every slot: an `active` slot
+        writes its row at its position, every other slot is PARKED
+        (position 0 over a zeroed table row: a free slot, or one whose
+        last token is still to be fetched, computes too, and its write
+        is dead — dense mode overwrites the rows on admit, paged mode
+        routes them to the trash page).  Token and position come from
+        the step before, on the device; the host uploads, in ONE packed
+        put, the override and the tables, and only when the device's
+        copies are stale."""
         with telemetry.phase(TICK_UPLOAD):
-            if self._packed:           # one table per kind of cache
-                tables = [self._table] + ([self._win.table]
-                                          if self._win is not None else [])
-                d_tok, d_pos, *d_tbl = self._feed.put_group(
-                    [self._tok[:, None], self._pos, *tables])
-                d_tbl = tuple(d_tbl)
-            elif self.paged:
-                d_tok, d_pos, d_tbl = self._feed.put_group(
-                    [self._tok[:, None], self._pos, self._table])
-            else:
-                d_tok, d_pos = self._feed.put_group(
-                    [self._tok[:, None], self._pos])
-                d_tbl = None
+            go = np.zeros(self.max_slots, bool)
+            go[active] = True
+            pos = np.where(go, self._pos, 0)
+            tables = [self._table] if self.paged else []
+            if self._win is not None:
+                tables.append(self._win.table)
+            tables = [np.where(go[:, None], t, 0) for t in tables]
+            stale = self._sent is None or not all(
+                map(np.array_equal, tables, self._sent))
+            ovr = np.stack([np.where(go, self._give, -1),
+                            np.where(pos != self._held, pos, -1)])
+            self._give[active] = -1
+            d_ovr = self._keep
+            if stale or (ovr >= 0).any():
+                d_ovr, *d_tables = self._feed.put_group([ovr, *tables])
+                self._d_tables, self._sent = tuple(d_tables), tables
+            self._held = pos + (pos > 0)    # what the step hands back
+            self._pos[active] += 1
+            self._inflight[active] += 1
         with telemetry.phase(TICK_DISPATCH):
-            lg, self._cache = self._step(
-                self.variables, d_tok, self._cache, d_pos, d_tbl)
-        with telemetry.phase(TICK_FETCH) as fetch:
-            if self._packed:           # tokens and statistics in one vector
-                nxt = np.asarray(lg)
-            else:
-                nxt = np.asarray(jnp.argmax(lg[:, 0], axis=-1), np.int32)
+            self._d_out, self._d_pos, self._cache = self._step(
+                self.variables, self._cache, self._d_out, self._d_pos,
+                d_ovr, self._adm, self._d_tables)
+            self._adm = self._none
+        return _Flight(self._d_out, self.max_slots,
+                       [(slot, slot) for slot in active])
+
+    def _collect(self, flight: _Flight) -> float:
+        """Fetch a dispatched program's tokens (and the model's
+        statistics behind them) and hand each slot's to its stream.  A
+        slot whose request ended while this ran (an `eos_id` in the
+        token before) gets nothing: the token is counted and dropped,
+        and the slot goes back with its last one.  -> the seconds
+        blocked on the device."""
+        admission = flight.admitted is not None
+        with telemetry.phase(ADMIT_FIRST_TOKEN if admission
+                             else TICK_FETCH) as fetch:
+            nxt = np.asarray(flight.out)
         with telemetry.phase(TICK_EMIT):
-            if self._packed:
-                self._note_stats(nxt[self.max_slots:])
-            for slot in active:
-                self._pos[slot] += 1
-                self._tok[slot] = nxt[slot]
-                self._emit(slot, int(nxt[slot]))
+            self._note_stats(nxt[flight.n_tok:])
+            if admission:
+                group, bucket, kp, t_bucket = flight.admitted
+                self._note_prefill(
+                    [(slot, req, len(req.prompt)) for slot, req in group],
+                    bucket, kp, t_bucket)
+            late = 0
+            for row, slot in flight.rows:
+                self._inflight[slot] -= 1
+                if self._live[slot].closed:
+                    late += 1
+                    if not self._inflight[slot]:
+                        self._release(slot)
+                else:
+                    self._tok[slot] = nxt[row]
+                    self._emit(slot, int(nxt[row]))
+            telemetry.incr(TICK_LATE_DISCARDS, late)
         return fetch.elapsed_s
 
     def _speculative_tick(self, active) -> float:
@@ -1464,7 +1685,7 @@ class ContinuousBatcher:
                 d_blk, d_vpos = self._feed.put_group([block, self._pos])
                 d_tbl = None
         with telemetry.phase(TICK_DISPATCH):
-            lg, self._cache = self._step(
+            lg, self._cache = self._block_step(
                 self.variables, d_blk, self._cache, d_vpos, d_tbl)
         with telemetry.phase(TICK_FETCH) as fetch:
             t_pred = np.asarray(jnp.argmax(lg, axis=-1), np.int32)  # [S, g+1]

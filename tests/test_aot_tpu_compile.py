@@ -258,6 +258,17 @@ POOL_PROGRAMS = {"smoke": (LM, SERVE, jnp.float32),
                  "cell": (CELL_LM, CELL_SERVE, jnp.bfloat16)}
 
 
+def _step_inputs(mesh, slots, stats):
+    """What the batcher's decode step takes between the cache and the
+    tables (serving/batcher.py, ONE STEP AHEAD): the step before's output
+    vector and positions, the host's [2, slots] override, the admissions'
+    first tokens."""
+    return (_shape(mesh, (slots + stats,), jnp.int32),
+            _shape(mesh, (slots,), jnp.int32),
+            _shape(mesh, (2, slots), jnp.int32),
+            _shape(mesh, (slots,), jnp.int32))
+
+
 def _batcher_programs(mesh, lm, serve, dtype, load_rows=2):
     """The batcher's OWN decode step and page-load programs (its jits,
     with their donation), compiled for the described chip at the pools'
@@ -279,10 +290,9 @@ def _batcher_programs(mesh, lm, serve, dtype, load_rows=2):
     cache = jax.tree.map(
         lambda a: _shape(mesh, (n_pages, *a.shape[1:]), a.dtype),
         batcher._cache)
-    step = _compile(batcher._step, variables,
-                    _shape(mesh, (b, 1), jnp.int32), cache,
-                    _shape(mesh, (b,), jnp.int32),
-                    _shape(mesh, (b, mp), jnp.int32))
+    step = _compile(batcher._step, variables, cache,
+                    *_step_inputs(mesh, b, 0),
+                    (_shape(mesh, (b, mp), jnp.int32),))
     heads, head_dim = lm["num_heads"], lm["embed_dim"] // lm["num_heads"]
     rows = jax.tree.map(
         lambda a: _shape(mesh, (load_rows, lm["max_len"], heads, head_dim),
@@ -355,6 +365,34 @@ def test_pool_programs_update_the_pools_in_place(pool_programs, sizes,
             POOL_PROGRAMS[sizes][0]["num_layers"]
 
 
+def _assert_runs_one_step_ahead(compiled, slots, stats, vocab):
+    """serving/batcher.py, ONE STEP AHEAD, as the compiled decode program
+    shows it: it takes the step before's tokens and positions, selects
+    between them and what the host gave itself, and hands back the tokens
+    it chose (with `stats` statistics behind them) and the next
+    positions, never [slots, vocab] logits: nothing of the tick is left
+    to a second program."""
+    import re
+
+    (_variables, _cache, before, pos, ovr, adm, _tables), _kw = \
+        compiled.args_info
+    assert (before.shape, pos.shape, ovr.shape, adm.shape) == \
+        ((slots + stats,), (slots,), (2, slots), (slots,))
+    chosen, pos_next, _pools = compiled.out_info
+    assert (chosen.shape, chosen.dtype) == ((slots + stats,), jnp.int32)
+    assert (pos_next.shape, pos_next.dtype) == ((slots,), jnp.int32)
+    assert not [o.shape for o in jax.tree.leaves(compiled.out_info)
+                if o.shape[-1:] == (vocab,)]
+    assert re.search(rf"= s32\[{slots}\]\S* select\(", compiled.as_text())
+
+
+@pytest.mark.parametrize("sizes", list(POOL_PROGRAMS))
+def test_decode_program_picks_and_feeds_its_own_tokens(pool_programs, sizes):
+    lm, serve, _dtype = POOL_PROGRAMS[sizes]
+    _assert_runs_one_step_ahead(pool_programs(sizes)[0], serve["max_slots"],
+                                0, lm["vocab_size"])
+
+
 # ---- Laguna-S-2.1: decode step, one admission, the page load ---------------
 # benchmarks/configs/laguna-s-2.1.json at its published widths and its
 # share (128 experts held, 50,176 vocabulary rows), cut to TWO layers:
@@ -402,22 +440,26 @@ def laguna_programs(v5e):
     # what `teacher_force` runs: the same functions handing back logits
     # and the routed layers' taps, a whole-context prompt admitted alone
     replay_step, replay_admission = batcher._own_programs(taps=True)
+    # the tokens lead the step's output vector, the model's statistics
+    # follow them: the next step takes the whole vector
+    given = _step_inputs(v5e, b, len(batcher._stat_counters))
+    adm = _shape(v5e, (b,), jnp.int32)
     return {
-        "replay_step": _compile(
-            replay_step, variables, _shape(v5e, (b, 1), jnp.int32), cache,
-            _shape(v5e, (b,), jnp.int32), tables),
+        "replay_step": _compile(replay_step, variables, cache, *given,
+                                tables),
         "replay_admission": _compile(
             replay_admission, variables, _shape(v5e, (1, ctx), jnp.int32),
-            _shape(v5e, (1,), jnp.int32)),
-        "decode_step": _compile(
-            batcher._step, variables, _shape(v5e, (b, 1), jnp.int32), cache,
-            _shape(v5e, (b,), jnp.int32), tables),
+            _shape(v5e, (1,), jnp.int32), _shape(v5e, (1,), jnp.int32), adm),
+        "decode_step": _compile(batcher._step, variables, cache, *given,
+                                tables),
         "admission": _compile(
             batcher._prefill_last, variables,
-            _shape(v5e, (k, bucket), jnp.int32), _shape(v5e, (k,), jnp.int32)),
+            _shape(v5e, (k, bucket), jnp.int32), _shape(v5e, (k,), jnp.int32),
+            _shape(v5e, (k,), jnp.int32), adm),
         "load": _compile(batcher._load_kinds, cache, rows, ids),
         "pool_bytes": sum(a.size * a.dtype.itemsize for a in pools),
         "pool_shapes": [f"bf16[{n},{page},{model.kv_width}]" for n in pages],
+        "stats": batcher._stat_counters,
     }
 
 
@@ -466,6 +508,13 @@ def test_laguna_programs_carry_their_kernels(laguna_programs, program,
     if program == "admission":
         k, _bucket = LAGUNA_ADMISSION
         assert f"f32[{k},50176]" in compiled.as_text()
+
+
+def test_laguna_decode_program_picks_and_feeds_its_own_tokens(
+        laguna_programs):
+    _assert_runs_one_step_ahead(
+        laguna_programs["decode_step"], LAGUNA_SERVE["max_slots"],
+        len(laguna_programs["stats"]), 50176)
 
 
 def _metric_pattern(name):

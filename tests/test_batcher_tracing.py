@@ -30,7 +30,8 @@ HISTS = ("serving.batcher.tick.latency", "serving.batcher.tick.host",
          "serving.batcher.admit.latency", "serving.batcher.queue_wait")
 COUNTERS = ("serving.batcher.prefill.tokens",
             "serving.batcher.prefill.padded_tokens",
-            "serving.batcher.live_tokens")
+            "serving.batcher.live_tokens",
+            batcher_mod.TICK_OVERLAPPED, batcher_mod.TICK_LATE_DISCARDS)
 
 # two waves, each submitted whole before the tick that admits it:
 # (prompt length, max_new_tokens)
@@ -129,13 +130,16 @@ def test_scripted_outputs_are_generates(lm, scripted):
             assert toks == _reference(model, variables, prompt, m)
 
 
+# a request decodes m - 1 times after its admission's first token, and a
+# wave admitted whole runs as long as its longest reply
+STEPS = sum(max(m for _n, m in w) - 1 for w in WAVES)
+
+
 @pytest.mark.parametrize("name,expected", [
-    # a request decodes m - 1 times after its admission's first token, and
-    # a wave admitted whole runs as long as its longest reply
-    ("serving.batcher.tick.latency",
-     sum(max(m for _n, m in w) - 1 for w in WAVES)),
-    ("serving.batcher.tick.host",
-     sum(max(m for _n, m in w) - 1 for w in WAVES)),
+    # the loop runs one step ahead: the iteration that only fetches a
+    # wave's last tokens dispatches no step and observes neither timer
+    ("serving.batcher.tick.latency", STEPS),
+    ("serving.batcher.tick.host", STEPS),
     ("serving.batcher.admit.latency", len(WAVES)),
     ("serving.batcher.queue_wait", sum(len(w) for w in WAVES)),
 ])
@@ -164,6 +168,10 @@ def test_tick_host_is_within_tick_latency_each_time(scripted):
     # a request sits at positions n .. n + m - 2 over its m - 1 decode ticks
     ("serving.batcher.live_tokens",
      sum(n + j for w in WAVES for n, m in w for j in range(m - 1))),
+    # every step but a wave's first is dispatched with the step before it
+    # still unfetched; no reply here ends by an eos
+    (batcher_mod.TICK_OVERLAPPED, STEPS - len(WAVES)),
+    (batcher_mod.TICK_LATE_DISCARDS, 0),
 ])
 def test_counters_are_exact(scripted, name, expected):
     assert scripted["counters"][name] == expected

@@ -398,6 +398,115 @@ def test_teacher_force_replays_what_was_served(ref, params, served):
                                              for r in batcher._live)
 
 
+# ---- the decode loop one step ahead, over both kinds of pool ----------------
+@pytest.fixture(scope="module")
+def mixed(ref, params):
+    """test_serving_model.MIXED through a model's own programs: page 4 and a
+    window of 8, so the ring of three pages is shorter than most replies
+    and turns over with a step in flight."""
+    from test_serving_model import mixed_requests, run_mixed
+
+    from mmlspark_tpu.serving import batcher as batcher_mod
+
+    model = _model()
+    arch = ref.arch_of(CFG)
+    fwd = jax.jit(lambda t: ref.logits(params, t, arch))
+    margins = []
+
+    def reference(prompt, n):
+        # greedy over the plain forward, one token at a time at a fixed
+        # width (causal: the padding is unseen)
+        seq = list(prompt)
+        for _ in range(n):
+            toks = np.zeros(64, np.int32)
+            toks[:len(seq)] = seq
+            row = np.asarray(fwd(jnp.asarray(toks)))[len(seq) - 1]
+            second, best = np.sort(row)[-2:]
+            margins.append(float(best - second))
+            seq.append(int(row.argmax()))
+        return seq[len(prompt):]
+
+    requests = mixed_requests(np.random.default_rng(3), 128, 64, reference)
+    names = (batcher_mod.TICK_OVERLAPPED, batcher_mod.TICK_LATE_DISCARDS)
+    before = {n: telemetry.counters().get(n, 0) for n in names}
+    batcher = ContinuousBatcher(model, {"params": params}, max_slots=3,
+                                paged=True, page_size=4)
+    try:
+        replies, steps = run_mixed(batcher, requests)
+    finally:
+        batcher.stop()
+    counted = {n: telemetry.counters().get(n, 0) - before[n] for n in names}
+    return batcher, requests, replies, steps, counted, min(margins)
+
+
+def test_mixed_replies_are_the_references(mixed):
+    """Token for token and in length, where the reference's own choice
+    is not a near tie (the served programs and the plain forward round
+    differently in the last digits)."""
+    _b, requests, replies, _steps, _counted, margin = mixed
+    assert margin > 1e-4              # no tie to fall the other way here
+    assert [len(r) for r in replies] == [len(w) for *_x, w in requests]
+    assert replies == [w for *_x, w in requests]
+    prompt, _m, _eos, want = requests[0]
+    assert len(prompt) + len(want) == 64          # it ends at max_len
+
+
+def test_mixed_run_counts_and_gives_everything_back(mixed):
+    from test_serving_model import late_ends
+
+    from mmlspark_tpu.serving import batcher as batcher_mod
+
+    batcher, requests, _replies, steps, counted, _margin = mixed
+    assert steps == len(requests[0][3]) - 1
+    assert counted[batcher_mod.TICK_OVERLAPPED] == steps - 1
+    assert counted[batcher_mod.TICK_LATE_DISCARDS] == late_ends(requests) == 2
+    assert not batcher._flight and not batcher._inflight.any()
+    test_free_lists_return_to_full((batcher,))
+    assert batcher._win.slot_reserved == batcher._slot_reserved == [0] * 3
+
+
+def test_stop_with_a_step_in_flight_leaves_a_batcher_to_replay_in(params):
+    """The loop is stopped between two iterations, a step dispatched and
+    not fetched; its two requests keep their slots.  `teacher_force` in
+    the two other slots hands back the logits a never-started batcher's
+    replay does."""
+    model = _model()
+    rng = np.random.default_rng(12)
+    pairs = [(rng.integers(0, 128, n).tolist(),
+              rng.integers(0, 128, m).tolist())
+             for n, m in ((6, 15), (11, 9))]
+
+    def make():
+        return ContinuousBatcher(model, {"params": params}, max_slots=4,
+                                 paged=True, page_size=4,
+                                 idle_sleep_s=0.0005)
+
+    fresh = make().teacher_force(pairs)
+    batcher = make().start()
+    try:
+        streams = [batcher.submit(rng.integers(0, 128, n).tolist(),
+                                  max_new_tokens=40) for n in (5, 14)]
+        for stream in streams:
+            it = iter(stream)
+            for _ in range(14):       # past the ring's first turn
+                next(it)
+    finally:
+        batcher.stop()
+    assert not batcher._thread.is_alive()
+    assert not batcher._flight and not batcher._inflight.any()
+    assert [r is not None for r in batcher._live] == [True, True, False, False]
+    held = (len(batcher._free), batcher._avail, len(batcher._win.free),
+            batcher._win.avail)
+    replayed = batcher.teacher_force(pairs)
+    for got, want in zip(replayed, fresh):
+        np.testing.assert_allclose(got["logits"], want["logits"], atol=1e-5)
+        for tap, v in got["routing"].items():
+            if tap != "experts":
+                np.testing.assert_allclose(v, want["routing"][tap], atol=1e-5)
+    assert held == (len(batcher._free), batcher._avail,
+                    len(batcher._win.free), batcher._win.avail)
+
+
 def test_unsupported_modes_are_refused(params):
     model = _model()
     with pytest.raises(ValueError, match="paged=True"):
