@@ -6,6 +6,9 @@ published widths, each phase compared with a plain reference:
 
   featurize  ImageFeaturizer(ResNet-50).transform over seeded JPEGs,
              fused-resize kernel vs the same featurizer at use_pallas=False
+             (since PR 29 the JPEGs are benchmarks/lib/images.jpeg_blobs'
+             photograph-like pixels, not uniform noise: a max_diff or a
+             decode time from before then was read on other input)
   vit        ViT-B/16 forward through default_attn (S=196 pads to 256)
              vs the same weights on full_attention
   lm_train   transformer_lm d768/L12 through make_lm_train_epoch (flash
@@ -40,7 +43,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-# One LM width for lm_train, lm_serve and lm3d: bench.py's d768/L12 GPT.
+# One LM width for lm_train, lm_serve and lm3d: a d768/L12 GPT.
 _LM_FULL = dict(vocab_size=8192, embed_dim=768, num_layers=12, num_heads=12,
                 max_len=1024)
 _LM_TINY = dict(vocab_size=64, embed_dim=32, num_layers=2, num_heads=2,
@@ -150,8 +153,8 @@ def phase_featurize(size: dict, seed: int) -> dict:
     import jax
     import numpy as np
 
-    from bench import _synthetic_jpeg_table
-    from mmlspark_tpu import native
+    from benchmarks.lib.images import jpeg_blobs
+    from mmlspark_tpu import Table, native
     from mmlspark_tpu.core import telemetry
     from mmlspark_tpu.io.feed import FEED_TELEMETRY, FeedTelemetry
     from mmlspark_tpu.models.bundle import FlaxBundle
@@ -167,7 +170,7 @@ def phase_featurize(size: dict, seed: int) -> dict:
         raise RuntimeError("native lib built without libjpeg: the streaming "
                            "decode path would not run")
     side = cfg["side"]
-    table = _synthetic_jpeg_table(cfg["n"], sizes=cfg["sizes"], seed=seed)
+    table = Table({"image": jpeg_blobs(cfg["n"], cfg["sizes"], seed)})
     bundle = FlaxBundle(cfg["builder"], {"num_classes": 1000},
                         input_shape=(side, side, 3), seed=seed)
 

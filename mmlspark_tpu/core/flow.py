@@ -73,8 +73,7 @@ _POLL_S = 0.05  # cancel-aware queue/credit wait quantum
 
 # The runtime sanitizer's observer (tools/graftsan), or None.  Installed
 # via set_sanitizer(); every hook site below is a plain attribute read
-# plus a None check, priced by bench.py's `sanitizer_overhead_frac`
-# contract (< 1% on the per-item flow path when disabled).
+# plus a None check.
 _SAN = None
 
 

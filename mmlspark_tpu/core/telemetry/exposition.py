@@ -148,9 +148,9 @@ def export_snapshot(registry: MetricsRegistry = REGISTRY,
     backend + device count when jax is loaded, process uptime),
     counters, gauges, histogram snapshots (keyed `name` or
     `name{k="v"}`), and (optionally) the recent-span ring with
-    non-serializable attrs degraded to repr().  `bench.py` and
-    `tools/chaos_soak.py` report through this; saved to a file it is
-    what `tools/obs_report.py` renders."""
+    non-serializable attrs degraded to repr().  `tools/chaos_soak.py`
+    reports through this; saved to a file it is what
+    `tools/obs_report.py` renders."""
     hists: Dict[str, Any] = {}
     for (name, labels), h in registry.histograms().items():
         snap = h.snapshot()

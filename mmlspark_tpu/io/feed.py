@@ -32,8 +32,8 @@ JAX-first:
     (default 2, tunable).
   * **Telemetry.**  Bytes moved, transfer calls/seconds, per-stage
     stall seconds, and wall time accumulate in `FEED_TELEMETRY`;
-    `bench.py` folds the derived `overlap_frac`/`stall_s`/`h2d_gbps`
-    into its JSON line.  See docs/performance.md ("The h2d feed").
+    `FeedTelemetry.summarize` derives `overlap_frac`/`stall_s`/
+    `h2d_gbps` from a delta.  See docs/performance.md ("The h2d feed").
   * **Fault tolerance.**  Every `device_put` sits behind the
     `feed.device_put` fault point with a bounded retry
     (`transfer_retries`, tiny backoff — a transient link hiccup costs
@@ -289,7 +289,7 @@ class FeedTelemetry:
 
     @staticmethod
     def summarize(d: Dict[str, float]) -> Dict[str, Any]:
-        """Derived metrics from a counter delta — the bench.py fields.
+        """Derived metrics from a counter delta.
 
         overlap_frac: fraction of feed wall time NOT spent blocked on
         host-side feeding (decode stalls + transfer dispatch).  1.0
@@ -340,7 +340,8 @@ class FeedTelemetry:
         return out
 
 
-# process-wide default sink: bench.py and tests read deltas off this
+# process-wide default sink: the benchmark's reducers and tests read
+# deltas off this
 FEED_TELEMETRY = FeedTelemetry()
 
 
@@ -498,8 +499,8 @@ class DeviceFeed:
         """`arr` through the sharded engine, or None when this put is not
         eligible (strategy, degraded, uneven batch, single target) — the
         caller continues on the coalesced path.  Ineligibility of a
-        genuinely multi-device put is counted as a fallback group: that
-        is the `h2d_path="fallback"` signal bench and feed_bench report."""
+        genuinely multi-device put is counted as a fallback group
+        (`fallback_groups`)."""
         from .shard_put import ShardTransferError
 
         if self.shard_degraded or self.shard_strategy == "coalesced":
@@ -840,7 +841,7 @@ class DeviceFeed:
                     pass
             # dispatch time; the blocked remainder of device compute
             # lands in stall_drain_s — the sum is the forward's
-            # host-visible cost (bench.py's forward_ms)
+            # host-visible cost
             tel.add(compute_s=time.perf_counter() - t0)
             inflight.append((ys, [n for _c, n in group], slot))
             while len(inflight) > (0 if self.degraded else self.depth):

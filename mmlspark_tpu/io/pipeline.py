@@ -1,10 +1,8 @@
 """HostPipeline: the streaming input pipeline engine.
 
-BENCH_r05 measured the gap this module closes: the ResNet-50 forward
-sustains 11,167 images/sec while end-to-end ImageFeaturizer delivers
-134.4 — the host stages (decode -> assemble -> h2d -> forward) ran
-largely serially per batch, so e2e throughput was the SUM of stage
-times instead of the MAX.  This is the pipelined-prefetch argument of
+The gap this module closes: with the host stages (decode -> assemble ->
+h2d -> forward) run serially per batch, end-to-end throughput is the SUM
+of stage times instead of the MAX.  This is the pipelined-prefetch argument of
 tf.data (Murray et al., VLDB 2021) and DALI's move-preprocessing-to-
 accelerator design, applied to this stack.
 
@@ -33,8 +31,8 @@ and prefill stages — keeping its historical surface:
     forward of N are in flight simultaneously with no extra copy or
     hand-off thread in between.
   * **Telemetry.**  Per-stage busy seconds and item counts accumulate
-    in `PIPELINE_TELEMETRY` (bench.py derives `decode_ms` /
-    `host_assemble_ms` and the `e2e_bound` attribution from deltas);
+    in `PIPELINE_TELEMETRY` (the benchmark's `decode_ms_per_kimg` and
+    `assemble_ms_per_kimg` are reduced from its deltas);
     each item observes `io.pipeline.stage.latency{stage=...}`, queue
     depths mirror to `io.pipeline.queue.depth.<stage>` gauges (the
     legacy names, kept alongside the runtime's unified
@@ -117,7 +115,8 @@ class PipelineTelemetry:
         return out
 
 
-# process-wide default sink: bench.py and tests read deltas off this
+# process-wide default sink: the benchmark's reducers and tests read
+# deltas off this
 PIPELINE_TELEMETRY = PipelineTelemetry()
 
 

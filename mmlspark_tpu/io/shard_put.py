@@ -1,10 +1,8 @@
-"""Sharded direct-to-chip transfers: the h2d wall attacked head-on.
+"""Sharded direct-to-chip transfers.
 
-PR 2/7/12 made the feed *overlap* perfectly — and BENCH_LASTGOOD still
-says `e2e_bound: "h2d"` at 0.058 GB/s against an 11.2k img/s forward,
-because a single monolithic `device_put` serializes the whole batch
-through one staging buffer and one transfer stream.  This module attacks
-the transfer itself (ROADMAP, first open item):
+A single monolithic `device_put` serializes the whole batch through one
+staging buffer and one transfer stream.  This module splits the transfer
+itself (no cell of the benchmark runs it yet: ROADMAP D6):
 
   * **Per-shard puts.**  A host batch bound for a `NamedSharding` is
     split along its shard boundaries (``sharding.
@@ -37,8 +35,8 @@ the transfer itself (ROADMAP, first open item):
 
 Telemetry rides the declared `io.feed.shard.*` series; per-shard
 bandwidth lands in `FeedTelemetry` (`shard_gbps`,
-`transfer_concurrency` in `tools/feed_bench.py --sharded`).
-See docs/performance.md ("Demolishing the h2d wall").
+`transfer_concurrency`).  See docs/performance.md ("The sharded and
+compressed feed paths").
 """
 from __future__ import annotations
 

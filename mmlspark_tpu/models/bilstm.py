@@ -1,8 +1,8 @@
 """BiLSTM sequence tagger with bucketed padding under jit.
 
 Reference capability: the "Medical Entity Extraction" BiLSTM notebook served
-through CNTK dynamic axes (SURVEY §5 long-context note: "BASELINE.json's
-BiLSTM config needs dynamic-shape padding/bucketing on XLA instead").
+through CNTK dynamic axes (SURVEY §5 long-context note: the BiLSTM
+needs dynamic-shape padding/bucketing on XLA instead).
 XLA has no dynamic axes, so variable-length token sequences are padded to a
 small set of bucket lengths — one compiled program per bucket — with masked
 loss/metrics.  `lax.scan` inside flax's nn.RNN keeps the recurrence

@@ -881,8 +881,7 @@ def fit_epochs_resumable(
                 batch_size / dt if dt > 0 else 0.0)
             # goodput plane: this step's timeline record (compute + the
             # h2d segment the feed telemetry measured) and one cadence-
-            # gated timeseries sweep — a few dict writes on the hot
-            # path (< 1% of step time, bench-gated in perf_gate)
+            # gated timeseries sweep — a few dict writes on the hot path
             core_telemetry.LEDGER.record_step(int(g), compute_s=dt,
                                               h2d=h2d_s)
             core_telemetry.STORE.tick()

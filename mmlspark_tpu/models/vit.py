@@ -2,11 +2,11 @@
 
 Beyond-reference model family (the reference's CNTK zoo stops at CNNs —
 SURVEY §2.9.6, downloader/ModelDownloader.scala:26-263): ViT is the
-MXU-native image backbone.  ResNet-50 inference is bandwidth-bound on a
-v5e (whole-model MFU ceiling ~0.47, docs/performance.md); a ViT is almost
-entirely large dense matmuls — patch embedding is a single [P²C, E]
-matmul, and every block is LN + QKV/proj/MLP matmuls at S=196 — so its
-roofline sits where the chip's FLOPs are, not its HBM.
+MXU-native image backbone.  ResNet-50's early stages are 1x1 convs over
+large activations, bound by bandwidth; a ViT is almost entirely large
+dense matmuls — patch embedding is a single [P²C, E] matmul, and every
+block is LN + QKV/proj/MLP matmuls at S=196 — so its roofline sits where
+the chip's FLOPs are, not its HBM.
 
 TPU-first choices: NHWC uint8/f32 in, one conv-as-matmul patchify, bf16
 compute with f32 params (flax default), static [B, 196, E] shapes, GAP

@@ -31,8 +31,7 @@ flash-attention backward as two more Pallas kernels (one accumulates
 dK/dV streaming Q blocks, one accumulates dQ streaming K blocks),
 recomputing each score block in VMEM from the forward's saved
 logsumexp — the dense-XLA backward materialized f32 [B, H, S, S]
-score tensors per layer and was measured to be 71% of the whole LM
-train step on a v5e (tools/lm_ablate.py).  Shapes `kernel_ok` declines
+score tensors per layer.  Shapes `kernel_ok` declines
 run the XLA composition forward and backward.  There is no other
 fallback: a shape `kernel_ok` admits and the compiler refuses raises
 (under an outer jit, when the outer program compiles).

@@ -69,8 +69,8 @@ def decode_cells(col: np.ndarray) -> list:
     short-circuited inline BEFORE the pool: only encoded bytes pay a
     codec, and a column of mostly-decoded rows with a few encoded
     stragglers no longer spins up 16 threads to re-wrap ndarrays.  Wall
-    time and item count land in the pipeline telemetry's "decode" stage
-    so bench.py's per-stage breakdown covers this path too."""
+    time and item count land in the pipeline telemetry's "decode" stage,
+    so a per-stage breakdown covers this path too."""
     import os
     import time
 

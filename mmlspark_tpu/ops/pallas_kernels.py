@@ -2,8 +2,7 @@
 
 Reference: the OpenCV Mat pipeline (opencv/.../ImageTransformer.scala:222-276)
 + UnrollImage (core/image/UnrollImage.scala:30-55) run per-row on JVM
-threads; BASELINE.json's north star is this preprocessing feeding the
-ImageFeaturizer.  Here the normalize + HWC->CHW unroll (the last host-side
+threads, feeding the ImageFeaturizer.  Here the normalize + HWC->CHW unroll (the last host-side
 step before the backbone) is ONE fused VMEM-resident Pallas kernel — a
 single HBM read and write per image instead of XLA's worst case of separate
 normalize/transpose materializations.

@@ -1,7 +1,7 @@
 """Compressed-wire codec: RLE-encoded pixels decoded ON DEVICE.
 
-The h2d wall is a bandwidth wall (BENCH_LASTGOOD: 0.058 GB/s), and the
-cheapest byte is the one never sent.  Classification pixels are highly
+Where the host-to-device link is the bound, the cheapest byte is the one
+never sent.  Classification pixels are highly
 runnable — letterboxed borders, flat backgrounds, uint8 quantization —
 so the feed's compressed path ships a byte-level run-length encoding of
 each chunk (values + a cumulative-length table) and expands it back into
@@ -33,8 +33,9 @@ the raw uint8 buffer on the chip:
     by `rle_kernel_ok()` (TPU backend, or forced via
     MMLSPARK_RLE_KERNEL=1 for interpret-mode tests on CPU).
 
-See docs/performance.md ("Demolishing the h2d wall") and the guide at
-/opt/skills/guides/pallas_guide.md for the scalar-prefetch idiom.
+See docs/performance.md ("The sharded and compressed feed paths") and
+the guide at /opt/skills/guides/pallas_guide.md for the scalar-prefetch
+idiom.
 """
 from __future__ import annotations
 

@@ -14,10 +14,9 @@ state machine::
 While in ``canary`` the gateway splits traffic by version weight (e.g.
 95/5).  Every :meth:`step` re-reads the gateway's per-version rolling
 stats (in-window request/error counts, latency percentiles over the
-forward window) and diffs canary vs baseline with the SAME
-direction+tolerance-band logic the repo's bench regression gate uses
-(tools/perf_gate.py ``compare``): a metric regresses only when it is
-worse by more than ``abs(base)*rel + floor``.  The verdict is
+forward window) and diffs canary vs baseline with a direction and a
+tolerance band per metric (``_band_compare``): a metric regresses only
+when it is worse by more than ``abs(base)*rel + floor``.  The verdict is
 hysteresis-free by design — one bad evaluation rolls back — because a
 canary sample is cheap to retake and a bad canary is expensive to keep.
 
@@ -53,8 +52,7 @@ from .fleet import FleetGateway, Replica
 
 __all__ = ["RolloutController", "ROLLOUT_METRICS", "drain_and_stop"]
 
-# metric -> (direction, relative tolerance, absolute floor) — the
-# perf_gate band shape (tools/perf_gate.py GATE_METRICS).
+# metric -> (direction, relative tolerance, absolute floor)
 ROLLOUT_METRICS: Dict[str, Tuple[str, float, float]] = {
     "latency_p50": ("lower", 0.50, 0.010),
     "latency_p95": ("lower", 0.50, 0.010),
@@ -65,27 +63,23 @@ ROLLOUT_METRICS: Dict[str, Tuple[str, float, float]] = {
 def _band_compare(fresh: Dict[str, Any], base: Dict[str, Any],
                   metrics: Dict[str, Tuple[str, float, float]],
                   ) -> List[Dict[str, Any]]:
-    """tools/perf_gate.compare with the rollout band table; falls back
-    to an inline copy of the band rule when tools/ is not importable
-    (installed-package layouts)."""
-    try:
-        from tools.perf_gate import compare
-        rows, _ = compare(fresh, base, metrics=metrics)
-        return rows
-    except ImportError:
-        rows = []
-        for name, (direction, rel, floor) in metrics.items():
-            f, b = fresh.get(name), base.get(name)
-            if not isinstance(f, (int, float)) or \
-                    not isinstance(b, (int, float)):
-                continue
-            band = abs(b) * rel + floor
-            worse_by = (b - f) if direction == "higher" else (f - b)
-            rows.append({"metric": name, "direction": direction,
-                         "base": b, "fresh": f, "band": band,
-                         "delta_pct": ((f - b) / b * 100.0) if b else None,
-                         "regressed": worse_by > band})
-        return rows
+    """One row for every metric of the band table that both records
+    carry as a number; ``regressed`` when fresh is worse than base by
+    more than ``abs(base)*rel + floor``.  A metric that is missing or not
+    numeric on either side is skipped."""
+    rows = []
+    for name, (direction, rel, floor) in metrics.items():
+        f, b = fresh.get(name), base.get(name)
+        if not all(isinstance(v, (int, float)) and not isinstance(v, bool)
+                   for v in (f, b)):
+            continue
+        band = abs(b) * rel + floor
+        worse_by = (b - f) if direction == "higher" else (f - b)
+        rows.append({"metric": name, "direction": direction,
+                     "base": b, "fresh": f, "band": band,
+                     "delta_pct": ((f - b) / b * 100.0) if b else None,
+                     "regressed": worse_by > band})
+    return rows
 
 
 def drain_and_stop(gateway: FleetGateway, rep: Replica,

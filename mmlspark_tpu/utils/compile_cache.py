@@ -4,7 +4,7 @@ The cache directory is part of every entry's key, so a directory that
 moves never hits.  The rule, in one place: `JAX_COMPILATION_CACHE_DIR`
 places the cache from outside (JAX reads the variable itself — nothing is
 set in code); otherwise it is ONE fixed, git-ignored directory inside the
-checkout.  Called first thing by `chip_smoke.py` and `bench.py`; the test
+checkout.  Called first thing by `chip_smoke.py` and `benchmarks/run.py`; the test
 suite leaves it off (tests/conftest.py).
 """
 from __future__ import annotations

@@ -898,8 +898,7 @@ def test_decode_mode_throughput_ratios_regression():
     recompile-per-tick or page-thrash bug tanks these ratios 5-10x).
     Absolute tokens/sec are meaningless on a 1-core host; the paged HBM
     ratio IS exact (pool sizing is deterministic: 10 pages x 64 rows vs
-    8 slots x 256 rows = 0.3125).  The chip-side analogue of these rows
-    rides `mfu_sweep --batcher`."""
+    8 slots x 256 rows = 0.3125)."""
     import time as _time
 
     from test_benchmarks import assert_benchmark, load_benchmarks

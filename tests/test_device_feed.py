@@ -211,8 +211,7 @@ def test_coalesced_feed_beats_naive_on_transfer_calls(rng):
     """256 images in 16 chunks: the naive per-chunk feed pays 16
     device_put round trips; the coalesced depth-2 engine must pay <= 4
     (>= 4x fewer) while producing identical results.  Structural — call
-    counts, not wall clock — so it cannot flake on a loaded host.
-    tools/feed_bench.py is the timing companion."""
+    counts, not wall clock — so it cannot flake on a loaded host."""
     import jax.numpy as jnp
 
     chunks = [(c, 16) for c in _chunks(rng, 16, (16, 32, 32, 3))]
@@ -236,7 +235,7 @@ def test_coalesced_feed_beats_naive_on_transfer_calls(rng):
 
 
 def test_process_telemetry_sink_is_shared():
-    """Consumers default to the process-wide sink bench.py reads."""
+    """Consumers default to the process-wide sink."""
     before = FEED_TELEMETRY.snapshot()
     DeviceFeed().put(np.zeros((2, 2), np.uint8))
     d = FEED_TELEMETRY.delta(before)

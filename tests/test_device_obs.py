@@ -1,7 +1,7 @@
 """Device-level observability suite: the XLA compile sentry (hot-path
 recompile detection with shape attribution), HBM/live-buffer memory
 gauges, Chrome/Perfetto trace export (unit + live serving round-trip),
-the perf regression gate, and the serving debug endpoints.  See
+and the serving debug endpoints.  See
 docs/observability.md "Device-level signals".
 """
 import json
@@ -16,8 +16,6 @@ import pytest
 from mmlspark_tpu.core import telemetry
 from mmlspark_tpu.core.telemetry import device as device_obs
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LASTGOOD = os.path.join(REPO, "BENCH_LASTGOOD.json")
 
 
 @pytest.fixture
@@ -99,8 +97,9 @@ def test_warmup_compiles_not_flagged(sentry):
 
 def test_watch_compiles_passes_through_jit_surface(sentry):
     """Call sites treat the wrapped value as a PjitFunction: .lower()
-    (bench.py does exactly this on make_lm_train_epoch's result) and
-    attribute access must pass through."""
+    (benchmarks/rehearse_aot.py does exactly this on
+    make_lm_train_epoch's result) and attribute access must pass
+    through."""
     import jax
     import jax.numpy as jnp
 
@@ -240,70 +239,6 @@ def test_obs_report_chrome_out(tmp_path):
     telemetry.clear_spans()
 
 
-# ----------------------------------------------------------------- perf gate
-def test_perf_gate_zero_on_self():
-    from tools import perf_gate
-
-    assert perf_gate.main([LASTGOOD, "--against", LASTGOOD]) == 0
-
-
-def test_perf_gate_nonzero_on_regression(tmp_path, capsys):
-    from tools import perf_gate
-
-    with open(LASTGOOD) as f:
-        rec = json.load(f)
-    bad = dict(rec)
-    bad["value"] = rec["value"] * 0.5  # 50% throughput loss
-    p = tmp_path / "regressed.json"
-    p.write_text(json.dumps({"record": bad}))  # --obs-out wrapper shape
-    assert perf_gate.main([str(p), "--against", LASTGOOD]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSED" in out and "value" in out
-
-
-def test_perf_gate_improvement_and_noise_pass(tmp_path):
-    from tools import perf_gate
-
-    with open(LASTGOOD) as f:
-        rec = json.load(f)
-    ok = dict(rec)
-    ok["value"] = rec["value"] * 1.3          # improvement
-    ok["mfu"] = rec["mfu"] * 0.95             # within the 10% band
-    p = tmp_path / "improved.json"
-    p.write_text(json.dumps(ok))
-    assert perf_gate.main([str(p), "--against", LASTGOOD]) == 0
-
-
-def test_perf_gate_steady_recompiles_zero_tolerance(tmp_path):
-    from tools import perf_gate
-
-    with open(LASTGOOD) as f:
-        rec = json.load(f)
-    base = dict(rec, steady_recompiles=0)
-    fresh = dict(rec, steady_recompiles=2)
-    pb = tmp_path / "base.json"
-    pf = tmp_path / "fresh.json"
-    pb.write_text(json.dumps(base))
-    pf.write_text(json.dumps(fresh))
-    assert perf_gate.main([str(pf), "--against", str(pb)]) == 1
-    fresh["steady_recompiles"] = 0
-    pf.write_text(json.dumps(fresh))
-    assert perf_gate.main([str(pf), "--against", str(pb)]) == 0
-
-
-def test_perf_gate_skips_stale(tmp_path, capsys):
-    from tools import perf_gate
-
-    with open(LASTGOOD) as f:
-        rec = json.load(f)
-    rec["stale"] = True
-    rec["value"] = 1.0  # would regress hard — but stale means unmeasured
-    p = tmp_path / "stale.json"
-    p.write_text(json.dumps(rec))
-    assert perf_gate.main([str(p), "--against", LASTGOOD]) == 0
-    assert "SKIP" in capsys.readouterr().out
-
-
 # ------------------------------------------- sanitize-collision metrics lint
 def test_metrics_lint_fails_on_sanitize_collision(monkeypatch, capsys):
     from tools import ci
@@ -417,24 +352,3 @@ def test_trace_json_live_roundtrip_nesting(live_server):
     assert any(e["name"].startswith(("serving.batcher", "feed."))
                for e in children)
     telemetry.clear_spans()
-
-
-# --------------------------------------------------- bench --obs-out plumbing
-def test_bench_obs_out_helpers(tmp_path, monkeypatch):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_obs_helpers", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    out = tmp_path / "obs.json"
-    monkeypatch.setattr(bench.sys, "argv",
-                        ["bench.py", "--obs-out", str(out)])
-    assert bench._obs_out_path() == str(out)
-    bench._write_obs_out(str(out), {"value": 1.0}, {"counters": {}})
-    doc = json.loads(out.read_text())
-    assert doc["record"] == {"value": 1.0}
-    assert doc["obs"] == {"counters": {}}
-    monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
-    assert bench._obs_out_path() is None
-    bench._write_obs_out(None, {}, None)  # no path: silent no-op

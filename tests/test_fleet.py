@@ -463,6 +463,31 @@ def test_begin_drain_under_concurrent_load():
 
 # ------------------------------------------------------------- canary
 
+_BANDS = {"qps": ("higher", 0.10, 0.0), "latency_p95": ("lower", 0.50, 0.010)}
+_BASE = {"qps": 100.0, "latency_p95": 0.020}
+
+
+@pytest.mark.parametrize("fresh,rows", [
+    # past the band on either direction: 100 -> 50 qps, 20 ms -> 45 ms
+    ({"qps": 50.0, "latency_p95": 0.045},
+     {"qps": True, "latency_p95": True}),
+    # an improvement, and noise inside the band (20 ms + 50% + 10 ms floor)
+    ({"qps": 130.0, "latency_p95": 0.039},
+     {"qps": False, "latency_p95": False}),
+    # not a number or not there: no row, whatever the value would say
+    ({"qps": "stale", "latency_p95": None}, {}),
+    ({}, {}),
+], ids=["regressed", "improvement_and_noise", "non_numeric", "missing"])
+def test_band_compare(fresh, rows):
+    from mmlspark_tpu.serving.rollout import _band_compare
+
+    got = _band_compare(fresh, _BASE, _BANDS)
+    assert {r["metric"]: r["regressed"] for r in got} == rows
+    for r in got:
+        assert r["band"] == pytest.approx(
+            abs(r["base"]) * _BANDS[r["metric"]][1] + _BANDS[r["metric"]][2])
+
+
 def test_slow_canary_auto_rolls_back():
     import random
 

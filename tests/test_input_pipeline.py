@@ -256,7 +256,7 @@ def test_stage_telemetry_and_metrics_accumulate():
     assert snap["work"]["items"] == 6
     assert snap["work"]["busy_s"] >= 0
     assert T.counters().get("io.pipeline.items.work", 0) == before + 6
-    # the delta shape bench.py consumes
+    # the delta shape the benchmark's reducers consume
     d = tel.delta({"work": {"busy_s": 0.0, "items": 1.0}})
     assert d["work"]["items"] == 5
 

@@ -233,32 +233,43 @@ def test_invalid_mode_rejected():
 
 # --------------------------------------------------------- latency evidence
 
-def _measure_concurrent_latency():
+def test_serving_concurrent_requests_each_answered_once():
+    """8 clients x 25 requests against one continuous-batching server:
+    every request gets its own body back and the model sees every row
+    exactly once, however the loop batched them.  The p50/p99/QPS this
+    CPU run saw are printed (`-s` shows them) and compared with nothing:
+    a CPU run yields counts and correctness, never a time."""
+    seen = []
+
+    def model(t):
+        x = np.asarray(t["x"], np.float64)
+        seen.extend(x.tolist())
+        return t.with_column("out", x)
+
     srv = ServingServer(
-        model=LambdaTransformer(
-            lambda t: t.with_column("out", np.asarray(t["x"], np.float64))),
-        reply_col="out", name="lat", path="/lat", batch_timeout_ms=2.0,
-        max_batch=128,
+        model=LambdaTransformer(model), reply_col="out", name="lat",
+        path="/lat", batch_timeout_ms=2.0, max_batch=128,
     )
     info = srv.start()
     n_clients, per_client = 8, 25
     lat = np.zeros((n_clients, per_client))
+    replies = [[] for _ in range(n_clients)]
     errors = []
 
     def client(ci):
         try:
             for i in range(per_client):
                 t0 = time.perf_counter()
-                r = send_request(to_http_request(info.url, {"x": ci}),
-                                 timeout=15)
+                r = send_request(
+                    to_http_request(info.url, {"x": ci * per_client + i}),
+                    timeout=15)
                 lat[ci, i] = time.perf_counter() - t0
                 assert r.ok, r.status_code
+                replies[ci].append(r.json())
         except Exception as e:  # noqa: BLE001 — surfaced in the main thread
             errors.append((ci, e))
 
     try:
-        # warm the pipeline before timing
-        send_request(to_http_request(info.url, {"x": 0}), timeout=15)
         t0 = time.perf_counter()
         threads = [threading.Thread(target=client, args=(ci,), daemon=True)
                    for ci in range(n_clients)]
@@ -270,38 +281,16 @@ def _measure_concurrent_latency():
     finally:
         srv.stop()
 
-    # a failed/hung client leaves 0.0 slots that would DEFLATE the
-    # percentiles — a broken server must fail here, not pass faster
     assert not errors, errors
-    assert np.all(lat > 0), "client thread hung past join timeout"
+    assert not any(t.is_alive() for t in threads), "a client hung"
+    total = n_clients * per_client
+    assert replies == [[{"out": float(ci * per_client + i)}
+                        for i in range(per_client)]
+                       for ci in range(n_clients)]
+    assert sorted(seen) == [float(v) for v in range(total)]
     flat = lat.reshape(-1) * 1000.0  # ms
-    return (float(np.percentile(flat, 50)), float(np.percentile(flat, 99)),
-            n_clients * per_client / wall)
-
-
-def test_serving_latency_qps_regression():
-    """Measured p50/p99/QPS under concurrent load vs the committed CSV —
-    the latency evidence the reference claims via latency_comparison.png
-    (docs/mmlspark-serving.md:142-145); absolute values here reflect this
-    CI container (1 CPU core), the regression guard is the point.  A
-    percentile measurement on a shared single core is load-sensitive, so
-    a violating first run re-measures once before failing (the committed
-    CSV stays the arbiter; this mirrors the reference CI's flaky-shard
-    retry, pipeline.yaml:408-410)."""
-    bench = load_benchmarks("benchmarks_serving.csv")
-    last = None
-    for _attempt in range(2):
-        p50, p99, qps = _measure_concurrent_latency()
-        try:
-            assert_benchmark(bench, "serving_p50_ms", p50)
-            assert_benchmark(bench, "serving_p99_ms", p99)
-            assert_benchmark(bench, "serving_qps", qps)
-            return
-        except AssertionError as e:
-            last = e
-            if _attempt == 0:
-                time.sleep(1.0)
-    raise last
+    print(f"[cpu, not compared] serving p50 {np.percentile(flat, 50):.2f} "
+          f"ms, p99 {np.percentile(flat, 99):.2f} ms, {total / wall:.0f} QPS")
 
 
 def test_serving_serial_latency_sub_ms():

@@ -1,7 +1,7 @@
 """Serving a REAL device model end-to-end: ImageFeaturizer behind
 ServingServer's continuous-batching loop — the SparkServing continuous-
-batched model endpoint configuration (BASELINE.json config 5;
-docs/mmlspark-serving.md pipeline-behind-HTTP examples)."""
+batched model endpoint configuration (docs/mmlspark-serving.md
+pipeline-behind-HTTP examples)."""
 import base64
 import io
 import json

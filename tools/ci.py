@@ -11,8 +11,6 @@ anywhere:
                                             # by default; --full scans
                                             # the whole tree)
     python tools/ci.py metrics-lint         # M001/M002 alias (graftlint G3)
-    python tools/ci.py perf-gate --fresh /tmp/bench_obs.json
-                                            # bench regression gate
     python tools/ci.py fleet-smoke          # gateway kill/revive soak
     python tools/ci.py obs-soak             # telemetry plane: kill ->
                                             # alert -> autoscale ->
@@ -21,7 +19,6 @@ anywhere:
     python tools/ci.py dist-soak            # elastic multi-host: kill a
                                             # pod host mid-epoch, shrink,
                                             # resume on survivors
-    python tools/ci.py feed-bench           # 3-path h2d transfer smoke
     python tools/ci.py parity-3d            # 3D-mesh trainer == single-
                                             # device losses (8-dev mesh)
     python tools/ci.py sanitize [--json]    # all soaks under GRAFTSAN=1
@@ -68,7 +65,7 @@ from tools.graftlint import core as _gl_core         # noqa: E402
 from tools.graftlint import g3_registry as _g3       # noqa: E402
 
 LINT_TARGETS = ("mmlspark_tpu", "tests", "tools", "examples",
-                "bench.py", "__graft_entry__.py")
+                "__graft_entry__.py")
 
 
 # ---------------------------------------------------------------- lint
@@ -291,17 +288,6 @@ def test(n_shards: int, shard: int, retries: int, timeout_s: int) -> int:
     return 0 if ok else 1
 
 
-def perf_gate(fresh: str, against: str = None, scale: float = 1.0) -> int:
-    """Delegate to tools/perf_gate.py (bench-record regression gate)."""
-    if ROOT not in sys.path:
-        sys.path.insert(0, ROOT)
-    from tools import perf_gate as gate
-    argv = [fresh, "--scale", str(scale)]
-    if against:
-        argv += ["--against", against]
-    return gate.main(argv)
-
-
 def fleet_smoke(timeout_s: int = 300) -> int:
     """Run the fleet kill/revive soak (tools/fleet_soak.py) as a smoke
     job: 2 replicas behind the gateway, a scripted mid-traffic kill, the
@@ -350,28 +336,6 @@ def train_smoke(timeout_s: int = 300) -> int:
         print(f"train-soak timed out after {timeout_s}s")
         return 1
     print("train-soak:", "OK" if rc == 0 else f"FAILED (rc={rc})")
-    return rc
-
-
-def feed_bench_smoke(timeout_s: int = 300) -> int:
-    """Run tools/feed_bench.py across all three transfer paths on a
-    small workload as a smoke job: the sharded, coalesced, and
-    compressed paths must all produce parity results (feed_bench
-    asserts byte equality against the naive baseline) on the virtual
-    8-device CPU mesh any CI machine can host."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
-                          " --xla_force_host_platform_device_count=8")
-               .strip())
-    cmd = [sys.executable, os.path.join("tools", "feed_bench.py"),
-           "--images", "64", "--chunks", "4", "--side", "64",
-           "--sharded", "--coalesced", "--compressed"]
-    try:
-        rc = subprocess.call(cmd, cwd=ROOT, env=env, timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        print(f"feed-bench timed out after {timeout_s}s")
-        return 1
-    print("feed-bench:", "OK" if rc == 0 else f"FAILED (rc={rc})")
     return rc
 
 
@@ -474,10 +438,9 @@ def sanitize(timeout_s: int = 300, json_out: bool = False) -> int:
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("command", choices=["lint", "metrics-lint", "test",
-                                        "perf-gate", "fleet-smoke",
+                                        "fleet-smoke",
                                         "obs-soak", "train-soak",
                                         "flow-soak", "dist-soak",
-                                        "feed-bench",
                                         "parity-3d", "sanitize", "all"])
     ap.add_argument("--shards", type=int, default=4)
     ap.add_argument("--shard", type=int, default=-1,
@@ -485,14 +448,6 @@ def main(argv=None):
     ap.add_argument("--retries", type=int, default=1)
     ap.add_argument("--timeout", type=int, default=1200,
                     help="per-shard budget, seconds (pipeline.yaml's 20min)")
-    ap.add_argument("--fresh", default=None,
-                    help="perf-gate: fresh bench snapshot "
-                         "(bench.py --obs-out file)")
-    ap.add_argument("--against", default=None,
-                    help="perf-gate: baseline record "
-                         "(default BENCH_LASTGOOD.json)")
-    ap.add_argument("--scale", type=float, default=1.0,
-                    help="perf-gate: widen tolerance bands")
     ap.add_argument("--json", action="store_true",
                     help="lint: machine-readable graftlint output")
     ap.add_argument("--full", action="store_true",
@@ -503,10 +458,6 @@ def main(argv=None):
         return lint(json_out=args.json, full=args.full)
     if args.command == "metrics-lint":
         return metrics_lint()
-    if args.command == "perf-gate":
-        if not args.fresh:
-            ap.error("perf-gate requires --fresh SNAPSHOT")
-        return perf_gate(args.fresh, args.against, args.scale)
     if args.command == "fleet-smoke":
         return fleet_smoke()
     if args.command == "obs-soak":
@@ -517,8 +468,6 @@ def main(argv=None):
         return flow_soak()
     if args.command == "dist-soak":
         return dist_soak()
-    if args.command == "feed-bench":
-        return feed_bench_smoke()
     if args.command == "parity-3d":
         return parity_3d()
     if args.command == "sanitize":

@@ -35,7 +35,7 @@ sys.path.insert(0, ROOT)
 
 
 def _make_chunks(images: int, chunk: int, side: int, rng):
-    """Flat gray-block pixels (see feed_bench): byte-runnable like real
+    """Flat gray-block pixels: byte-runnable like real
     decoded images — the compressed strategy needs representative run
     lengths, not pointwise noise."""
     bs = max(1, chunk)

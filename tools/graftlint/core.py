@@ -47,7 +47,7 @@ __all__ = ["Finding", "SourceFile", "ModuleGraph", "collect_files",
 # scope plus examples/ — tests/ are deliberately out (fixtures violate
 # rules on purpose)
 DEFAULT_TARGETS: Tuple[str, ...] = ("mmlspark_tpu", "tools", "examples",
-                                    "bench.py", "__graft_entry__.py")
+                                    "__graft_entry__.py")
 
 # one suppression grammar per tool: graftlint's static passes and
 # graftsan's runtime sanitizer (tools/graftsan) share the mechanics but
@@ -262,8 +262,8 @@ def collect_files(root: str,
 def module_name(rel: str) -> str:
     """Dotted module name for a repo-relative path:
     ``mmlspark_tpu/models/training.py`` -> ``mmlspark_tpu.models.training``,
-    a package ``__init__.py`` -> the package itself, ``bench.py`` ->
-    ``bench``."""
+    a package ``__init__.py`` -> the package itself, ``chip_smoke.py``
+    -> ``chip_smoke``."""
     rel = rel.replace(os.sep, "/")
     if rel.endswith(".py"):
         rel = rel[:-3]

@@ -25,8 +25,7 @@ Three analyses, all deterministic given a deterministic schedule:
 
 The disabled path costs nothing: uninstalled, production code builds
 plain `threading.Lock`s (utils/sync.py returns them directly) and the
-only residue is the `_SAN is None` branch at flow's credit hops,
-priced by bench.py's `sanitizer_overhead_frac` contract (< 1%).
+only residue is the `_SAN is None` branch at flow's credit hops.
 """
 from __future__ import annotations
 
