@@ -326,7 +326,7 @@ def phase_lm_train(size: dict, seed: int) -> dict:
 
     loss_diff = abs(float(losses[0]) - ref_loss)
     tol, grad_tol = 2e-2, 5e-2
-    expected = 3 * lm["num_layers"]   # forward + dK/dV + dQ per layer
+    expected = 2 * lm["num_layers"]   # forward + fused backward per layer
     ok = (bool(np.isfinite(losses).all()) and loss_diff <= tol
           and grad_diff <= grad_tol
           and custom_calls == (expected if _kernels_expected() else 0))

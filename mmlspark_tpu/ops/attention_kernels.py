@@ -14,27 +14,50 @@ Mosaic-friendly formulation (same playbook as pallas_kernels.py):
     holds it to that) — padding d=64 up to the lane would double the
     QK^T MACs with zeros and materialize 2x-size q/k/v/o copies around
     every call; other dims pad to the 128 lane.
-  - grid = (B*H, S/block_q, S/block_k), K innermost: K/V blocks STREAM
-    through VMEM while running max / normalizer / unnormalized output
-    live in VMEM scratch across the K steps (online softmax, the true
-    flash-attention recurrence) — so VMEM use is O(block_q * block_k),
-    independent of S; block_k adapts to the largest block tiling S, so
-    any 128-multiple sequence length takes the kernel.
-  - scores/softmax in f32; both matmuls via dot_general with f32
-    accumulation; causal mask from broadcasted_iota (2D iota is
-    Mosaic-legal, 1D is not); the m/l running statistics are stored
-    lane-broadcast as [block_q, 128] blocks (a bare [block_q] vector
-    is not a legal Mosaic tile).
+  - Score tiles are square, `_pick_blocks(s, d, causal)` a side (512
+    where it tiles S and d <= 128, else 256, 128, or S itself under
+    128), and a kernel LOOPS over tiles inside a grid step: a grid step
+    costs about 0.4 us on a v5e whatever it does, and at the LM cell's
+    [96, 1024, 64] the one-tile-a-step grids before PR 30 took 4,608
+    of them a layer (now 576).  The forward's
+    grid is (B*H, S / block_q, S / k_major): a step holds a query block
+    and up to 4,096 keys (`_kv_major`; all of a training sequence) and
+    walks their key tiles; longer sequences stream major blocks, the
+    online softmax's m / l / o carried in VMEM scratch, so VMEM use is
+    O(block_q * block_k + k_major * D), independent of S.
+  - The causal schedule (`_k_range`, `_q_range`, `causal_tiles`): tiles
+    above the diagonal are never started (nor copied: index maps park
+    on the last block that shows something), tiles wholly under it take
+    no mask, and only the tiles it crosses pay the iota / compare /
+    select of `_masked` — the same for the tiles `kv_valid` crosses
+    when S was padded.  The softmax scale is folded into the query
+    block once (`_scaled`), not multiplied into every score tile.
+  - Tiles are computed TRANSPOSED, keys down the sublanes and queries
+    along the lanes (s^T = K Q^T): per-query statistics (m, l, the
+    logsumexp, delta) are then [1, block_q] rows that broadcast down
+    the sublanes for nothing and reduce without crossing lanes, where a
+    [block_q, 1] column costs block_q / 8 registers an operation; the
+    logsumexp travels between forward and backward as [B*H, S] f32,
+    one lane-dense row a query block, never lane-broadcast.
+  - scores/softmax statistics and every accumulator in f32; all matmuls
+    via dot_general at the inputs' dtype with f32 accumulation, p and
+    ds cast at the consuming matmul; outputs (o, dq, dk, dv) leave at
+    q's dtype, which is what the model keeps (f32 stays f32).
 
 Training: fused_attention carries a custom VJP whose BACKWARD is the
-flash-attention backward as two more Pallas kernels (one accumulates
-dK/dV streaming Q blocks, one accumulates dQ streaming K blocks),
-recomputing each score block in VMEM from the forward's saved
-logsumexp — the dense-XLA backward materialized f32 [B, H, S, S]
-score tensors per layer.  Shapes `kernel_ok` declines
-run the XLA composition forward and backward.  There is no other
-fallback: a shape `kernel_ok` admits and the compiler refuses raises
-(under an outer jit, when the outer program compiles).
+flash-attention backward, recomputing each score tile in VMEM from the
+forward's saved logsumexp — the dense-XLA backward materialized f32
+[B, H, S, S] score tensors per layer.  Where a head's Q, dO and f32 dQ
+fit VMEM (`_fused_bwd_fits`: to S=2,048 at bf16) it is ONE kernel,
+`_attention_bwd_dkdv_dq`: key blocks on the grid, a loop over the query
+blocks that see them, each tile's p and ds computed once for dV, dK
+and dQ (5 matmuls and one vector pass a tile).  Longer sequences take
+the split pair (`_attention_bwd_dkdv` streaming Q blocks,
+`_attention_bwd_dq` streaming K blocks: 7 matmuls, two passes), O(block)
+in VMEM, on the same tiles, schedule and `_bwd_tile`.  Shapes
+`kernel_ok` declines run the XLA composition forward and backward.
+There is no other fallback: a shape `kernel_ok` admits and the compiler
+refuses raises (under an outer jit, when the outer program compiles).
 
 Off TPU the kernel runs interpret=True (tests/CI); on TPU it compiles to
 Mosaic.  tests/test_attention_kernels.py holds the parity suite,
@@ -56,310 +79,554 @@ _BLOCK_Q = 128
 _BLOCK_K = 512
 _LANE = 128
 _NEG_INF = -1e30  # finite stand-in: -inf arithmetic is fragile on Mosaic
+# what the one-kernel backward may keep resident: under the 16 MiB a v5e
+# kernel gets by default, with room for what the estimate leaves out
+_FUSED_BWD_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _pick_block_k(s: int) -> int:
-    """Largest K block that tiles s — any 128-multiple S gets a kernel."""
+    """Largest K block that tiles s (the prefill kernel's rule)."""
     for blk in (_BLOCK_K, 256, 128):
         if s >= blk and s % blk == 0:
             return blk
     return s  # s < 128: single block (s itself must divide by 8)
 
 
-def attention_fits_vmem(s: int, d: int, itemsize: int = 2,
-                        block_q: int = _BLOCK_Q,
-                        block_k: int = _BLOCK_K) -> bool:
-    """Per-grid-step VMEM estimate — O(block_q * block_k), NOT O(S):
-    K/V blocks stream while the accumulators persist.  Taking the kernel
-    path commits callers to the flash BACKWARD too (custom_vjp), whose
-    dK/dV kernel stages the most: both estimates must fit."""
-    d_p = _pad_up(d, _LANE)
-    block_k = _pick_block_k(s) if block_k == _BLOCK_K else min(block_k, s)
-    block_q = min(block_q, s)
-    fwd = (2 * block_k * d_p * itemsize       # K + V blocks
-           + block_q * d_p * itemsize         # Q block
+# ---- the tile schedule -----------------------------------------------------
+def _pick_blocks(s: int, d: int, causal: bool) -> tuple:
+    """(block_q, block_k) of the training kernels' score tiles, from the
+    shape alone: square, the largest of 512, 256, 128 that tiles s (512
+    only to d = 128: a wider head in f32 would push the forward past
+    its VMEM estimate), s itself under 128.  Measured on the chip at
+    [96, 1024, 64] bf16 causal (PERF.md section 6, PR 30): forward /
+    fused backward 0.44 / 0.88 ms a call at 512, 0.70 / 0.90 at 256,
+    1.44 / 1.80 at 128 and no better for an unequal pair — the D=64
+    matmuls fill half the MXU and each tile pays its own pipeline fill,
+    so fewer, larger tiles win although 512 computes 75% of S^2 under
+    the diagonal where 256 computes 62.5% and 128 56%.  `causal` is in
+    the signature because the choice is its to change; today both
+    schedules take the same tile."""
+    del causal
+    for blk in (512, 256, _LANE):
+        if s % blk == 0 and (blk <= 256 or d <= _LANE):
+            return blk, blk
+    return s, s  # s < 128: one tile (s itself must divide by 8)
+
+
+def _k_range(r0, block_q: int, block_k: int):
+    """Causal, query rows [r0, r0 + block_q): key blocks [0, n_full) lie
+    wholly at or under the diagonal (no mask), [n_full, n_vis) are
+    crossed by it (mask), the rest show nothing.  Ints or traced."""
+    return (r0 + 1) // block_k, (r0 + block_q - 1) // block_k + 1
+
+
+def _q_range(c0, block_q: int, block_k: int):
+    """Causal, key columns [c0, c0 + block_k): query blocks
+    [i_first, i_full) are crossed by the diagonal, [i_full, ...) lie
+    wholly under it, those before i_first see none of the keys."""
+    return c0 // block_q, (c0 + block_k + block_q - 2) // block_q
+
+
+def _tile_kind(r0, c0, block_q: int, block_k: int, causal: bool, kv_valid):
+    """(visible, masked) of the one tile at query row r0, key column c0:
+    the same schedule asked tile by tile (the split backward's grids)."""
+    visible, masked = True, False
+    if causal:
+        visible = r0 + block_q - 1 >= c0
+        masked = c0 + block_k - 1 > r0
+    if kv_valid is not None:
+        masked = masked | (c0 + block_k > kv_valid)
+    return visible, masked
+
+
+def causal_tiles(s: int, block_q: int, block_k: int) -> list:
+    """[(query block, key block, masked)] of every tile the causal
+    kernels compute at sequence length s, in the forward's order: the
+    schedule as a pure function, held by the tests to "every tile with
+    at least one unmasked element, masked where it has a masked one"."""
+    tiles = []
+    for qi in range(s // block_q):
+        n_full, n_vis = _k_range(qi * block_q, block_q, block_k)
+        tiles += [(qi, ki, ki >= n_full)
+                  for ki in range(min(n_vis, s // block_k))]
+    return tiles
+
+
+def _kv_major(s: int, d: int, itemsize: int, block_k: int) -> int:
+    """Keys a forward grid step holds in VMEM: the largest multiple of
+    block_k that tiles s and keeps K and V, double-buffered, inside half
+    the budget.  Up to 4,096 keys at d <= 128 in bf16, so a training
+    sequence is ONE grid step a query block and its key tiles a loop
+    inside that step; past that the major blocks stream."""
+    d_l = _pad_up(d, _LANE)
+    n = s // block_k
+    for m in range(n, 0, -1):
+        if n % m == 0 and (4 * m * block_k * d_l * itemsize
+                           <= PALLAS_IMAGE_VMEM_BUDGET // 2):
+            return m * block_k
+    return block_k
+
+
+def _fused_bwd_fits(s: int, d: int, itemsize: int = 2) -> bool:
+    """Does the one-kernel backward fit?  It keeps a head's Q and dO (as
+    given, and Q scaled), the f32 dQ accumulator and the dQ output
+    resident beside one key block's tiles: S=1024, D=64 in bf16 comes
+    to 7 MB, 3 of them the f32 tiles, and S=2,048 in bf16 (1,024 in
+    f32) is the last that fits (the split pair, O(block) in VMEM, takes
+    the sequences past it)."""
+    block_q, block_k = _pick_blocks(s, d, True)
+    d_l = _pad_up(d, _LANE)
+    resident = (s * d_l * (7 * itemsize + 4)     # q, dO x2; q scaled; dQ x2
+                + 4 * 8 * s * 4)                 # lse, delta rows x2
+    step = (8 * block_k * d_l * itemsize         # K, V in; dK, dV out, x2
+            + 2 * block_k * d_l * 4              # dK, dV accumulators
+            + 3 * block_q * block_k * 4)         # p / dp / ds (f32)
+    return resident + step <= _FUSED_BWD_VMEM_BUDGET
+
+
+def attention_fits_vmem(s: int, d: int, itemsize: int = 2) -> bool:
+    """Per-grid-step VMEM estimate of the forward and of the SPLIT
+    backward — O(block_q * block_k) and, in the forward, the K/V major
+    block `_kv_major` bounds; NOT O(S).  Taking the kernel path commits
+    callers to the flash backward too (custom_vjp): both must fit.  The
+    fused backward is taken where `_fused_bwd_fits` besides."""
+    d_l = _pad_up(d, _LANE)
+    block_q, block_k = _pick_blocks(s, d, True)
+    k_major = _kv_major(s, d, itemsize, block_k)
+    fwd = (4 * k_major * d_l * itemsize       # K + V major blocks, x2
+           + 4 * block_q * d_l * itemsize     # Q in, O out, x2
            + 2 * block_q * block_k * 4        # scores + probs (f32)
-           + block_q * d_p * 4                # O scratch
-           + 2 * block_q * _LANE * 4)         # m / l scratch
-    bwd = (2 * block_k * d_p * itemsize       # K + V blocks
-           + 2 * block_q * d_p * itemsize     # Q + dO blocks
-           + 2 * block_q * _LANE * 4          # lse + delta blocks
+           + block_q * d_l * 4                # o^T scratch
+           + 2 * 8 * block_q * 4)             # m / l rows
+    bwd = (4 * block_k * d_l * itemsize       # K + V blocks, x2
+           + 4 * block_q * d_l * itemsize     # Q + dO blocks, x2
+           + 4 * block_k * d_l * itemsize     # dK + dV (or dQ) out, x2
+           + 4 * 8 * block_q * 4              # lse + delta rows, x2
            + 3 * block_q * block_k * 4        # p / dp / ds (f32)
-           + 2 * block_k * d_p * 4)           # dK + dV accumulators
+           + 2 * block_k * d_l * 4)           # dK + dV accumulators
     return max(fwd, bwd) <= PALLAS_IMAGE_VMEM_BUDGET
 
 
-def _masked_scores(qb, kb, qi, ki, block_q, block_k, scale, causal,
-                   kv_valid=None):
-    """Score block sc = scale * Q K^T with the causal and/or KV-padding
-    mask applied — THE shared definition for the forward and both
-    backward kernels, so mask/scale/_NEG_INF semantics cannot
-    desynchronize between them.  `kv_valid` (static) masks key columns
-    >= the true sequence length when S was padded up to the block grid:
-    zero-padded K rows would otherwise score 0 and steal softmax mass
-    from every valid query."""
-    sc = jax.lax.dot_general(
-        qb, kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # [bq, bk]
-    if causal or kv_valid is not None:
-        cols = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        mask = None
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
-            mask = (qi * block_q + rows) >= (ki * block_k + cols)
-        if kv_valid is not None:
-            kv_mask = (ki * block_k + cols) < kv_valid
-            mask = kv_mask if mask is None else (mask & kv_mask)
-        sc = jnp.where(mask, sc, _NEG_INF)
-    return sc
+# ---- what every kernel does to a tile --------------------------------------
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
 
-def _dscores(p, dob, vb, dlt, scale):
-    """ds = p * (dO V^T - delta) * scale — shared by both backward
-    kernels (dp in f32, ds cast at the consuming matmul)."""
-    dp = jax.lax.dot_general(
-        dob, vb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)              # [bq, bk]
-    return p * (dp - dlt) * scale
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _scaled(qb, scale: float):
+    """The query block times the softmax scale, at its own dtype: [bq, D]
+    work once a block, where scaling the scores was [bq, bk] work a tile
+    (exact at D=64, a power of two)."""
+    return (qb.astype(jnp.float32) * scale).astype(qb.dtype)
+
+
+def _masked(sc, q0, k0, q_axis: int, causal: bool, kv_valid):
+    """The causal and/or KV-padding mask on one score tile whose axis
+    `q_axis` runs over queries from q0 and whose other axis over keys
+    from k0 — THE shared definition for the forward and the backward
+    kernels, so mask and _NEG_INF semantics cannot desynchronize.  Only
+    tiles the diagonal (or `kv_valid`) crosses come here.  `kv_valid`
+    (static) masks key columns >= the true sequence length when S was
+    padded up to the block grid: zero-padded K rows would otherwise
+    score 0 and steal softmax mass from every valid query."""
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1 - q_axis)
+    mask = None
+    if causal:
+        mask = (q0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape,
+                                              q_axis)) >= kpos
+    if kv_valid is not None:
+        kv_mask = kpos < kv_valid
+        mask = kv_mask if mask is None else (mask & kv_mask)
+    return jnp.where(mask, sc, _NEG_INF)
+
+
+def _bwd_tile(qs, kb, vb, dob, lse, dlt, masked, r0, c0, causal, kv_valid):
+    """One backward tile, TRANSPOSED (keys down the sublanes, queries
+    along the lanes, so the per-query lse and delta are [1, bq] rows that
+    broadcast down for nothing) — shared by the fused kernel and the
+    split pair: p^T = exp(K Qs^T - lse), recomputed from the forward's
+    logsumexp (exact, no renormalization pass), and
+    ds^T = p^T * (V dO^T - delta) WITHOUT the scale: Qs carries it into
+    dK = ds^T Qs, and dQ takes it once at the end.  All f32; -> (p^T,
+    ds^T) cast to the consuming matmuls' operand dtype."""
+    s_t = _dot(kb, qs, _NT)                              # [bk, bq] f32
+    if masked:
+        s_t = _masked(s_t, r0, c0, 1, causal, kv_valid)
+    p_t = jnp.exp(s_t - lse)
+    ds_t = p_t * (_dot(vb, dob, _NT) - dlt)
+    return p_t.astype(dob.dtype), ds_t.astype(qs.dtype)
+
+
+def _loop(lo, hi, body, carry=None):
+    """fori_loop, not emitted for a range that is statically empty."""
+    if isinstance(lo, int) and isinstance(hi, int) and hi <= lo:
+        return carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _stat_rows(x, block_q: int):
+    """[BH, S] per-query statistics -> [BH, S / block_q, 1, block_q]: one
+    lane-dense row a query block, which a kernel indexes by the block."""
+    bh, s = x.shape
+    return x.reshape(bh, s // block_q, 1, block_q)
 
 
 @partial(jax.jit, static_argnames=("causal", "scale", "kv_valid"))
 def _attention_pallas(q, k, v, causal: bool, scale: float,
                       kv_valid=None):
-    """q,k,v: [BH, S, D_padded] (D padded to a lane multiple) -> [BH, S,
-    D_padded] f32.  `scale` is 1/sqrt(TRUE head dim) — the padded D must
-    not leak into the softmax temperature."""
+    """q,k,v: [BH, S, D] (D as `_kernel_d` has it) -> (o [BH, S, D] at
+    q's dtype, logsumexp [BH, S] f32).  `scale` is 1/sqrt(TRUE head
+    dim) — a padded D must not leak into the softmax temperature.
+
+    grid (BH, S / block_q, S / k_major), the last axis sequential: a step
+    holds one query block and `k_major` keys and LOOPS over their
+    block_k-wide tiles, first those wholly under the diagonal (no mask),
+    then those it crosses; the tiles above it are never started, and a
+    major block wholly above it is neither computed nor copied (its
+    index map parks on the last one the query block sees)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = q.shape
-    block_q = min(_BLOCK_Q, s)
-    block_k = _pick_block_k(s)
-    n_k = s // block_k
+    block_q, block_k = _pick_blocks(s, d, causal)
+    k_major = _kv_major(s, d, q.dtype.itemsize, block_k)
+    n_q, n_kb, n_major, n_sub = (s // block_q, s // block_k, s // k_major,
+                                 k_major // block_k)
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc,
-               *, scale):
-        ki = pl.program_id(2)
+    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc):
         qi = pl.program_id(1)
+        kj = pl.program_id(2)
+        r0 = qi * block_q
 
-        @pl.when(ki == 0)
+        @pl.when(kj == 0)
         def _init():
             o_acc[...] = jnp.zeros_like(o_acc)
             m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
             l_acc[...] = jnp.zeros_like(l_acc)
 
-        # causal: K blocks entirely above the diagonal are pure no-op work
-        # (up to ~half the grid at long S) — skip both matmuls for them
-        visible = ((qi * block_q + block_q - 1 >= ki * block_k)
-                   if causal else (ki >= 0))
+        if causal:
+            n_full, n_vis = _k_range(r0, block_q, block_k)
+        elif kv_valid is not None:
+            n_full, n_vis = kv_valid // block_k, -(-kv_valid // block_k)
+        else:
+            n_full = n_vis = n_kb
+        if causal or n_major > 1:     # this major block's share of them
+            n_full = jnp.clip(n_full - kj * n_sub, 0, n_sub)
+            n_vis = jnp.clip(n_vis - kj * n_sub, 0, n_sub)
+        qs = _scaled(q_ref[0], scale)
 
-        @pl.when(visible)
-        def _update():
-            qb = q_ref[0]                    # [block_q, D]
-            kb = k_ref[0]                    # [block_k, D]
-            vb = v_ref[0]
-            sc = _masked_scores(qb, kb, qi, ki, block_q, block_k,
-                                scale, causal, kv_valid)
-            # online softmax: m/l live lane-broadcast in [bq, LANE]
-            # scratch.  Read via full-tile load + lane reduction (all
-            # lanes hold the same value) — a narrow [:, :1] ref slice is
-            # not a safe Mosaic tile access
-            m_prev = jnp.max(m_acc[...], axis=-1, keepdims=True)
-            l_prev = jnp.max(l_acc[...], axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        def tile(t, carry, masked):
+            m_prev, l_prev, acc = carry                  # [1, bq] x2, [D, bq]
+            c0 = pl.multiple_of(t * block_k, block_k)
+            kb = k_ref[0, pl.ds(c0, block_k), :]
+            vb = v_ref[0, pl.ds(c0, block_k), :]
+            s_t = _dot(kb, qs, _NT)                      # [bk, bq] f32
+            if masked:
+                s_t = _masked(s_t, r0, kj * k_major + c0, 1, causal,
+                              kv_valid)
+            m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
-            p = jnp.exp(sc - m_new)                        # [bq, bk] f32
-            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-            o_acc[...] = o_acc[...] * corr + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_acc[...] = jnp.broadcast_to(m_new, m_acc.shape)
-            l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape)
+            # a query this tile hides wholly keeps the m an earlier tile
+            # gave it (every query's FIRST tile shows it key 0), so its
+            # p is exp(-1e30 - m) = 0
+            p_t = jnp.exp(s_t - m_new)
+            l_new = l_prev * corr + jnp.sum(p_t, axis=0, keepdims=True)
+            return m_new, l_new, acc * corr + _dot(vb, p_t.astype(vb.dtype),
+                                                   _TN)
 
-        @pl.when(ki == n_k - 1)
+        # TRANSPOSED like the backward: keys down the sublanes, queries
+        # along the lanes.  The online softmax's m and l are then [1, bq]
+        # ROWS (two registers, where a [bq, 1] column is bq / 8 and its
+        # row maximum a cross-lane reduction), the output accumulates as
+        # o^T [D, bq], and the logsumexp leaves in the backward's layout
+        carry = (m_acc[...], l_acc[...], o_acc[...])
+        carry = _loop(0, n_full, partial(tile, masked=False), carry)
+        if causal or kv_valid is not None:
+            carry = _loop(n_full, n_vis, partial(tile, masked=True), carry)
+        m_acc[...], l_acc[...], o_acc[...] = carry
+
+        @pl.when(kj == n_major - 1)
         def _finish():
-            # fully-masked rows (possible only with non-causal all-pad
-            # inputs) keep l=0; guard the divide.  Full-tile read + lane
-            # reduction again (lanes are equal by construction).
-            l_fin = jnp.max(l_acc[...], axis=-1, keepdims=True)
-            o_ref[0] = o_acc[...] / jnp.maximum(l_fin, 1e-20)
-            # logsumexp residual for the flash backward: rows the causal
-            # mask fully hides never update m (=-inf stand-in) — their
-            # lse is meaningless and the backward masks them anyway
-            m_fin = jnp.max(m_acc[...], axis=-1, keepdims=True)
-            lse = m_fin + jnp.log(jnp.maximum(l_fin, 1e-20))
-            lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+            m_fin, l_fin, acc = carry
+            # fully-masked queries (possible only with non-causal all-pad
+            # inputs) keep l=0; guard the divide
+            l_fin = jnp.maximum(l_fin, 1e-20)
+            o_ref[0] = (acc / l_fin).T.astype(o_ref.dtype)
+            lse_ref[0, 0] = m_fin + jnp.log(l_fin)
 
-    return pl.pallas_call(
-        partial(kernel, scale=scale),
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh, s, _LANE), jnp.float32)),
-        grid=(bh, s // block_q, n_k),
+    def kv_block(b, i, j):
+        if causal and n_major > 1:
+            # major blocks above the diagonal park on its own: no copy
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // k_major)
+        return (b, j, 0)
+
+    o, lse = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+                   jax.ShapeDtypeStruct((bh, n_q, 1, block_q),
+                                        jnp.float32)),
+        grid=(bh, n_q, n_major),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, k_major, d), kv_block),
+            pl.BlockSpec((1, k_major, d), kv_block),
         ],
         out_specs=(
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, i, j: (b, i, 0, 0)),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
+            pltpu.VMEM((d, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
         ],
         interpret=_interpret(),
     )(q, k, v)
+    return o, lse.reshape(bh, s)
 
 
 def _xla_attention(q, k, v, causal: bool):
     from ..parallel.ring_attention import full_attention
 
-    return full_attention(q, k, v, causal=causal)
+    return full_attention(q, k, v, causal=causal).astype(q.dtype)
+
+
+@partial(jax.jit, static_argnames=("causal", "scale", "kv_valid"))
+def _attention_bwd_dkdv_dq(q, k, v, do, lse, delta, causal: bool,
+                           scale: float, kv_valid=None):
+    """The whole flash backward in ONE kernel, where a head's dQ fits
+    VMEM (`_fused_bwd_fits`): -> (dq, dk, dv) [BH, S, D] at q's dtype.
+    q, k, v, do: [BH, S, D]; lse, delta: [BH, S] f32.
+
+    grid (BH, S / block_k): a step holds one key block and the head's
+    whole Q, dO, lse and delta (copied once a head: their block index
+    does not move), and LOOPS over the query blocks that see the keys —
+    first those the diagonal crosses, then those wholly under it —
+    recomputing each tile's p and ds ONCE (`_bwd_tile`) for all three
+    gradients: dV += p^T dO and dK += ds^T Qs in f32 scratch, written a
+    step, and dQ^T[block] += K^T ds^T into a head-resident f32
+    accumulator ([S / block_q, D, block_q]: the small K block is the
+    operand transposed, not the tile), turned and written once a head.
+    Five matmuls and one vector pass a tile, as FlashAttention's
+    backward has them.  The name starts with
+    `_attention_bwd_dkdv`: the benchmark's trace metrics find the kernel
+    by that."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, s, d = q.shape
+    block_q, block_k = _pick_blocks(s, d, causal)
+    n_q, n_kb = s // block_q, s // block_k
+
+    def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+               dq_ref, dk_ref, dv_ref, qs_ref, dq_acc, dk_acc, dv_acc):
+        kj = pl.program_id(1)
+        c0 = kj * block_k
+
+        @pl.when(kj == 0)
+        def _head():
+            qs_ref[...] = _scaled(q_ref[0], scale)
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+        kb, vb = k_ref[0], v_ref[0]
+
+        def tile(i, _, masked):
+            r0 = pl.multiple_of(i * block_q, block_q)
+            qs = qs_ref[pl.ds(r0, block_q), :]
+            dob = do_ref[0, pl.ds(r0, block_q), :]
+            p_t, ds_t = _bwd_tile(qs, kb, vb, dob, lse_ref[0, i],
+                                  dl_ref[0, i], masked, r0, c0, causal,
+                                  kv_valid)
+            dv_acc[...] += _dot(p_t, dob, _NN)               # [bk, D]
+            dk_acc[...] += _dot(ds_t, qs, _NN)               # [bk, D]
+            dq_acc[i] += _dot(kb, ds_t, _TN)                 # [D, bq]
+
+        if causal:
+            i_first, i_full = _q_range(c0, block_q, block_k)
+            i_full = jnp.minimum(i_full, n_q)
+        elif kv_valid is not None:   # a key block with padding in it
+            i_first, i_full = 0, jnp.where(c0 + block_k > kv_valid, n_q, 0)
+        else:
+            i_first = i_full = 0
+        _loop(i_first, i_full, partial(tile, masked=True))
+        _loop(i_full, n_q, partial(tile, masked=False))
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+        @pl.when(kj == n_kb - 1)
+        def _finish():
+            for i in range(n_q):
+                dq_ref[0, i * block_q:(i + 1) * block_q, :] = (
+                    dq_acc[i] * scale).T.astype(dq_ref.dtype)
+
+    head = pl.BlockSpec((1, s, d), lambda b, j: (b, 0, 0))
+    stat = pl.BlockSpec((1, n_q, 1, block_q), lambda b, j: (b, 0, 0, 0))
+    keys = pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0))
+    return pl.pallas_call(
+        kernel,
+        out_shape=tuple(jax.ShapeDtypeStruct((bh, s, d), q.dtype)
+                        for _ in range(3)),
+        grid=(bh, n_kb),
+        in_specs=[head, keys, keys, head, stat, stat],
+        out_specs=(head, keys, keys),
+        scratch_shapes=[
+            pltpu.VMEM((s, d), q.dtype),
+            pltpu.VMEM((n_q, d, block_q), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        interpret=_interpret(),
+    )(q, k, v, do, _stat_rows(lse, block_q), _stat_rows(delta, block_q))
+
+
+def _split_bwd_specs(pl, s, d, block_q, block_k, causal, q_inner: bool):
+    """Block specs of the split pair's grids (BH, outer, inner), one tile
+    a step: Q blocks innermost for dK/dV, K blocks innermost for dQ.
+    Under `causal` the inner axis' index maps park on the first (last)
+    block the outer one sees, so a tile above the diagonal is not copied
+    either.  -> (rows, keys, stat) for [BH, S, D] by query block, by key
+    block, and the statistics' rows."""
+    def qi_kj(outer, inner):
+        qi, kj = (inner, outer) if q_inner else (outer, inner)
+        if causal and q_inner:
+            qi = jnp.maximum(qi, _q_range(kj * block_k, block_q,
+                                          block_k)[0])
+        if causal and not q_inner:
+            kj = jnp.minimum(kj, _k_range(qi * block_q, block_q,
+                                          block_k)[1] - 1)
+        return qi, kj
+
+    rows = pl.BlockSpec((1, block_q, d),
+                        lambda b, o, n: (b, qi_kj(o, n)[0], 0))
+    keys = pl.BlockSpec((1, block_k, d),
+                        lambda b, o, n: (b, qi_kj(o, n)[1], 0))
+    stat = pl.BlockSpec((1, 1, 1, block_q),
+                        lambda b, o, n: (b, qi_kj(o, n)[0], 0, 0))
+    return rows, keys, stat
+
+
+def _split_bwd_step(update, r0, c0, block_q, block_k, causal, kv_valid):
+    """Run `update(masked)` for the step's one tile: not at all above the
+    diagonal, with the mask only where the diagonal or `kv_valid` crosses
+    it."""
+    from jax.experimental import pallas as pl
+
+    visible, masked = _tile_kind(r0, c0, block_q, block_k, causal, kv_valid)
+    if masked is False:     # static (not causal, not padded): every tile
+        update(False)       # shows everything
+        return
+    pl.when(visible & masked)(partial(update, True))
+    pl.when(visible & jnp.logical_not(masked))(partial(update, False))
 
 
 @partial(jax.jit, static_argnames=("causal", "scale", "kv_valid"))
 def _attention_bwd_dkdv(q, k, v, do, lse, delta, causal: bool, scale: float,
                         kv_valid=None):
-    """dK/dV: grid (BH, n_k, n_q) with Q innermost — each (b, k-block)
-    streams every visible Q/dO block, recomputing its score block from
-    the saved lse (p = exp(s - lse), exact, no renormalization pass),
-    accumulating dV += p^T dO and dK += ds^T Q in VMEM.  All inputs are
-    [BH, S, D_pad] except lse/delta [BH, S, LANE] lane-broadcast."""
+    """dK/dV of the SPLIT backward (sequences past `_fused_bwd_fits`):
+    grid (BH, n_k, n_q) with Q innermost — each (b, k-block) streams
+    every visible Q/dO block, accumulating dV += p^T dO and
+    dK += ds^T Qs in VMEM.  Shapes as the fused kernel's; -> (dk, dv)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = q.shape
-    block_q = min(_BLOCK_Q, s)
-    block_k = _pick_block_k(s)
+    block_q, block_k = _pick_blocks(s, d, causal)
     n_q = s // block_q
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-               dk_ref, dv_ref, dk_acc, dv_acc, *, scale):
-        kj = pl.program_id(1)
-        qi = pl.program_id(2)
+               dk_ref, dv_ref, dk_acc, dv_acc):
+        kj, qi = pl.program_id(1), pl.program_id(2)
+        r0, c0 = qi * block_q, kj * block_k
 
         @pl.when(qi == 0)
         def _init():
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
-        visible = ((qi * block_q + block_q - 1 >= kj * block_k)
-                   if causal else (qi >= 0))
+        def update(masked):
+            qs, dob = _scaled(q_ref[0], scale), do_ref[0]
+            p_t, ds_t = _bwd_tile(qs, k_ref[0], v_ref[0], dob,
+                                  lse_ref[0, 0], dl_ref[0, 0], masked,
+                                  r0, c0, causal, kv_valid)
+            dv_acc[...] += _dot(p_t, dob, _NN)
+            dk_acc[...] += _dot(ds_t, qs, _NN)
 
-        @pl.when(visible)
-        def _update():
-            qb = q_ref[0]
-            kb = k_ref[0]
-            vb = v_ref[0]
-            dob = do_ref[0]
-            lse = jnp.max(lse_ref[0], axis=-1, keepdims=True)   # [bq, 1]
-            dlt = jnp.max(dl_ref[0], axis=-1, keepdims=True)    # [bq, 1]
-            sc = _masked_scores(qb, kb, qi, kj, block_q, block_k,
-                                scale, causal, kv_valid)
-            p = jnp.exp(sc - lse)                                # [bq, bk]
-            dv_acc[...] += jax.lax.dot_general(
-                p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)              # [bk, D]
-            ds = _dscores(p, dob, vb, dlt, scale)
-            dk_acc[...] += jax.lax.dot_general(
-                ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)              # [bk, D]
+        _split_bwd_step(update, r0, c0, block_q, block_k, causal, kv_valid)
 
         @pl.when(qi == n_q - 1)
         def _finish():
-            dk_ref[0] = dk_acc[...]
-            dv_ref[0] = dv_acc[...]
+            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
+    rows, keys, stat = _split_bwd_specs(pl, s, d, block_q, block_k, causal,
+                                        q_inner=True)
+    out = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     return pl.pallas_call(
-        partial(kernel, scale=scale),
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh, s, d), jnp.float32)),
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+                   jax.ShapeDtypeStruct((bh, s, d), q.dtype)),
         grid=(bh, s // block_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANE), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANE), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+        in_specs=[rows, keys, keys, rows, stat, stat],
+        out_specs=(out, out),
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, _stat_rows(lse, block_q), _stat_rows(delta, block_q))
 
 
 @partial(jax.jit, static_argnames=("causal", "scale", "kv_valid"))
 def _attention_bwd_dq(q, k, v, do, lse, delta, causal: bool, scale: float,
                       kv_valid=None):
-    """dQ: grid (BH, n_q, n_k) with K innermost — the forward's layout,
-    accumulating dQ += ds @ K across the streamed K/V blocks."""
+    """dQ of the split backward: grid (BH, n_q, n_k) with K innermost —
+    the forward's order, accumulating dQ += ds K across the streamed K/V
+    blocks and taking the scale at the end."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = q.shape
-    block_q = min(_BLOCK_Q, s)
-    block_k = _pick_block_k(s)
-    n_k = s // block_k
+    block_q, block_k = _pick_blocks(s, d, causal)
+    n_kb = s // block_k
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-               dq_ref, dq_acc, *, scale):
-        qi = pl.program_id(1)
-        ki = pl.program_id(2)
+               dq_ref, dq_acc):
+        qi, kj = pl.program_id(1), pl.program_id(2)
+        r0, c0 = qi * block_q, kj * block_k
 
-        @pl.when(ki == 0)
+        @pl.when(kj == 0)
         def _init():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
-        visible = ((qi * block_q + block_q - 1 >= ki * block_k)
-                   if causal else (ki >= 0))
-
-        @pl.when(visible)
-        def _update():
-            qb = q_ref[0]
+        def update(masked):
             kb = k_ref[0]
-            vb = v_ref[0]
-            dob = do_ref[0]
-            lse = jnp.max(lse_ref[0], axis=-1, keepdims=True)
-            dlt = jnp.max(dl_ref[0], axis=-1, keepdims=True)
-            sc = _masked_scores(qb, kb, qi, ki, block_q, block_k,
-                                scale, causal, kv_valid)
-            p = jnp.exp(sc - lse)
-            ds = _dscores(p, dob, vb, dlt, scale)
-            dq_acc[...] += jax.lax.dot_general(
-                ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)              # [bq, D]
+            _p_t, ds_t = _bwd_tile(_scaled(q_ref[0], scale), kb, v_ref[0],
+                                   do_ref[0], lse_ref[0, 0], dl_ref[0, 0],
+                                   masked, r0, c0, causal, kv_valid)
+            dq_acc[...] += _dot(kb, ds_t, _TN)               # [D, bq]
 
-        @pl.when(ki == n_k - 1)
+        _split_bwd_step(update, r0, c0, block_q, block_k, causal, kv_valid)
+
+        @pl.when(kj == n_kb - 1)
         def _finish():
-            dq_ref[0] = dq_acc[...]
+            dq_ref[0] = (dq_acc[...] * scale).T.astype(dq_ref.dtype)
 
+    rows, keys, stat = _split_bwd_specs(pl, s, d, block_q, block_k, causal,
+                                        q_inner=False)
     return pl.pallas_call(
-        partial(kernel, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
-        grid=(bh, s // block_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
-        ],
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+        grid=(bh, s // block_q, n_kb),
+        in_specs=[rows, keys, keys, rows, stat, stat],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, _stat_rows(lse, block_q), _stat_rows(delta, block_q))
 
 
 def _padded_len(s: int):
@@ -392,7 +659,9 @@ def kernel_ok(q) -> bool:
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
 def fused_attention(q, k, v, causal: bool = True):
-    """Drop-in for full_attention: (B, S, H, D) -> (B, S, H, D) f32.
+    """Drop-in for full_attention: (B, S, H, D) -> (B, S, H, D) at q's
+    dtype, on the kernel arm and the XLA arm alike (full_attention
+    itself hands back f32; bf16 callers cast on their next line anyway).
 
     VMEM-resident scores on TPU via Pallas (interpret mode elsewhere).
     Non-block-multiple S (ViT's 196, ragged text) pads up to the 128
@@ -401,8 +670,10 @@ def fused_attention(q, k, v, causal: bool = True):
     < 64 (lane padding wastes the MXU), the XLA composition runs
     instead — `kernel_ok(q)` is the public predicate.  Scale uses the
     TRUE head dim even when D pads to the 128 lane.  Differentiable:
-    kernel-path shapes take the flash backward kernels (blockwise
-    recompute from the saved logsumexp — matches the XLA gradients to
+    kernel-path shapes take the flash backward (one fused kernel where a
+    head's dQ fits VMEM, the dK/dV + dQ pair past that; blockwise
+    recompute from the saved logsumexp, with the forward's output kept
+    at q's dtype and delta summed in f32 — matches the XLA gradients to
     MXU precision, ~1e-3 on bf16 passes); shapes `kernel_ok` declines
     keep the exact XLA recompute.
     """
@@ -426,18 +697,19 @@ def _pad_seq(x, s_p):
     s = x.shape[1]
     if s_p == s:
         return x
-    return jnp.pad(x, ((0, 0), (0, s_p - s), (0, 0)))
+    return jnp.pad(x, ((0, 0), (0, s_p - s)) + ((0, 0),) * (x.ndim - 2))
 
 
 def _kernel_d(d: int) -> int:
     """Head-dim the kernels run at: 64-multiples are native (64, 128,
-    192, ... — all three kernels compile at the 64-minor tiles for a
+    192, ... — every kernel compiles at the 64-minor tiles for a
     v5e in bf16 and f32, tests/test_aot_tpu_compile.py); everything else
     pads up to the 128 lane."""
     return d if d % 64 == 0 else _pad_up(d, _LANE)
 
 
 def _run_kernel(q, k, v, causal: bool):
+    """-> (out [B, S, H, D] at q's dtype, logsumexp [B*H, S] f32)."""
     b, s, h, d = q.shape
     d_p = _kernel_d(d)
     s_p = _padded_len(s)
@@ -446,8 +718,7 @@ def _run_kernel(q, k, v, causal: bool):
     o, lse = _attention_pallas(
         _pad_seq(_to_bhsd(q, d_p), s_p), _pad_seq(_to_bhsd(k, d_p), s_p),
         _pad_seq(_to_bhsd(v, d_p), s_p), causal, scale, kv_valid)
-    # keep one lane of the broadcast lse as the backward residual
-    return _from_bhsd(o[:, :s], b, s, h, d), lse[:, :s, 0]
+    return _from_bhsd(o[:, :s], b, s, h, d), lse[:, :s]
 
 
 def _fused_attention_fwd(q, k, v, causal):
@@ -455,7 +726,7 @@ def _fused_attention_fwd(q, k, v, causal):
         out, lse = _run_kernel(q, k, v, causal)
         return out, (q, k, v, out, lse)
     # the XLA backward recomputes from q/k/v alone — saving `out` here
-    # would keep a dead [B, S, H, D] f32 alive until the backward
+    # would keep a dead [B, S, H, D] array alive until the backward
     return _xla_attention(q, k, v, causal), (q, k, v, None, None)
 
 
@@ -473,25 +744,25 @@ def _flash_bwd(q, k, v, out, lse, g, causal):
     d_p, s_p = _kernel_d(d), _padded_len(s)  # the forward's decisions
     kv_valid = s if s_p != s else None
     scale = 1.0 / float(d) ** 0.5
-    # delta = rowsum(dO * O) on the TRUE head dim (pad columns are zero).
-    # Padded Q rows are inert by construction: their dO rows pad to zero,
-    # so every dv/dk contribution they touch is zero; lse/delta pad 0.
-    delta = jnp.einsum("bshd,bshd->bhs", g.astype(jnp.float32), out)
-    delta = _pad_seq(delta.reshape(b * h, s)[..., None], s_p)
-    delta = jnp.broadcast_to(delta, (b * h, s_p, _LANE))
-    lse = jnp.broadcast_to(_pad_seq(lse[..., None], s_p),
-                           (b * h, s_p, _LANE))
+    # delta = rowsum(dO * O), summed in f32 on the TRUE head dim (`out`
+    # is the residual at q's dtype).  Padded Q rows are inert by
+    # construction: their dO rows pad to zero, so every dv/dk
+    # contribution they touch is zero; lse/delta pad 0.
+    delta = jnp.einsum("bshd,bshd->bhs", g, out,
+                       preferred_element_type=jnp.float32)
+    delta = _pad_seq(delta.reshape(b * h, s), s_p)
+    lse = _pad_seq(lse, s_p)
     # matmul-heavy backward runs at the inputs' dtype (bf16 on the MXU)
     # with f32 accumulation, like the forward
     qp, kp, vp = (_pad_seq(_to_bhsd(x, d_p), s_p) for x in (q, k, v))
     dop = _pad_seq(_to_bhsd(g.astype(q.dtype), d_p), s_p)
-    dk, dv = _attention_bwd_dkdv(qp, kp, vp, dop, lse, delta, causal,
-                                 scale, kv_valid)
-    dq = _attention_bwd_dq(qp, kp, vp, dop, lse, delta, causal,
-                           scale, kv_valid)
-    return (_from_bhsd(dq[:, :s], b, s, h, d).astype(q.dtype),
-            _from_bhsd(dk[:, :s], b, s, h, d).astype(k.dtype),
-            _from_bhsd(dv[:, :s], b, s, h, d).astype(v.dtype))
+    args = (qp, kp, vp, dop, lse, delta, causal, scale, kv_valid)
+    if _fused_bwd_fits(s_p, d_p, q.dtype.itemsize):
+        dq, dk, dv = _attention_bwd_dkdv_dq(*args)
+    else:
+        dk, dv = _attention_bwd_dkdv(*args)
+        dq = _attention_bwd_dq(*args)
+    return tuple(_from_bhsd(x[:, :s], b, s, h, d) for x in (dq, dk, dv))
 
 
 fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)

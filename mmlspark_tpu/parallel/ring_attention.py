@@ -180,7 +180,8 @@ def _ring_flash_fwd(q, k, v, mesh: Mesh, axis: str, causal: bool):
 
         def run(blk_causal):
             o_b, lse = _run_kernel(q_loc, k_blk, v_blk, blk_causal)
-            return o_b, lse.reshape(b, h, s_loc)
+            # the kernel writes q's dtype; blocks merge in f32
+            return o_b.astype(jnp.float32), lse.reshape(b, h, s_loc)
 
         def skipped():
             return (jnp.zeros(q_loc.shape, jnp.float32),

@@ -77,7 +77,7 @@ def _assert_one_kernel(compiled):
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
-# ---- flash attention: forward, dK/dV, dQ ----------------------------------
+# ---- flash attention: forward, the fused backward, the split pair ---------
 def _vit_seq():
     s = (VIT["side"] // 16) ** 2          # ViT-B/16: 196 patches
     return s, ak._padded_len(s)           # ... on the 256 grid
@@ -92,14 +92,27 @@ ATTN_SHAPES = {
     "s1024_d128": (96, 1024, 128, jnp.bfloat16, None, True),
     # the rule in ak._kernel_d: 64-multiples run native, unpadded
     "s256_d192_native": (8, 256, 192, jnp.bfloat16, None, True),
+    # f32 callers (the parity tests' dtype) at the LM cell's length
+    "s1024_d64_f32": (16, 1024, 64, jnp.float32, None, True),
+    # past the fused backward's limit: K/V stream in two major blocks
+    # forward, and the backward is the dK/dV + dQ pair
+    "s8192_d64_split": (4, 8192, 64, jnp.bfloat16, None, True),
 }
+SPLIT_ONLY = ["s8192_d64_split"]
+FUSED_SHAPES = [t for t in ATTN_SHAPES if t not in SPLIT_ONLY]
 
 
 def _attn_args(mesh, tag):
     bh, s, d, dtype, kv_valid, causal = ATTN_SHAPES[tag]
     q = _shape(mesh, (bh, s, d), dtype)
-    stat = _shape(mesh, (bh, s, 128), jnp.float32)
+    stat = _shape(mesh, (bh, s), jnp.float32)
     return q, stat, causal, 1.0 / float(d) ** 0.5, kv_valid
+
+
+def test_fused_backward_limit_splits_the_shapes():
+    for tag, (_bh, s, d, dtype, _kv, _c) in ATTN_SHAPES.items():
+        assert ak._fused_bwd_fits(s, d, jnp.dtype(dtype).itemsize) == \
+            (tag not in SPLIT_ONLY), tag
 
 
 @pytest.mark.parametrize("tag", list(ATTN_SHAPES))
@@ -107,6 +120,14 @@ def test_attention_forward_compiles(v5e, tag):
     q, _stat, causal, scale, kv_valid = _attn_args(v5e, tag)
     _assert_one_kernel(_compile(ak._attention_pallas, q, q, q,
                                 causal, scale, kv_valid))
+
+
+@pytest.mark.parametrize("tag", FUSED_SHAPES)
+def test_attention_fused_backward_compiles(v5e, tag):
+    """dQ, dK and dV in one kernel, the head's Q, dO and dQ resident."""
+    q, stat, causal, scale, kv_valid = _attn_args(v5e, tag)
+    _assert_one_kernel(_compile(ak._attention_bwd_dkdv_dq, q, q, q, q, stat,
+                                stat, causal, scale, kv_valid))
 
 
 @pytest.mark.parametrize("tag", list(ATTN_SHAPES))
@@ -319,20 +340,42 @@ def pool_programs(v5e):
 
 
 @pytest.fixture(scope="module")
+def split_backward_program(v5e):
+    """Forward and backward of one attention call past the fused
+    backward's limit, through `fused_attention`'s own two halves: the
+    dK/dV + dQ pair under their names."""
+    bh, s, d, dtype, _kv_valid, causal = ATTN_SHAPES[SPLIT_ONLY[0]]
+    q = _shape(v5e, (1, s, bh, d), dtype)
+
+    def both(q, k, v, g):
+        out, lse = ak._run_kernel(q, k, v, causal)
+        return ak._flash_bwd(q, k, v, out, lse, g, causal)
+
+    return _compile(jax.jit(both), q, q, q, q)
+
+
+@pytest.fixture(scope="module")
 def decode_program(pool_programs):
     """The batcher's slot-decode program (TransformerLM.decode_step over
     page pools) at chip_smoke's full sizes."""
     return pool_programs("smoke")[0]
 
 
-def test_lm_train_epoch_compiles_with_36_kernels(epoch_program):
-    """12 layers x (forward, dK/dV, dQ) custom calls, and it fits one
-    chip."""
+# the same program's temp_size_in_bytes at the parent of PR 30 (f32 `out`
+# residuals and f32 dq/dk/dv; a compile fact, PR 30)
+EPOCH_TEMP_BYTES_BEFORE_PR30 = 7_352_914_432
+
+
+def test_lm_train_epoch_compiles_with_24_kernels(epoch_program):
+    """12 layers x (forward, fused backward) custom calls, it fits one
+    chip, and it keeps no more than it did with three kernels a layer
+    writing f32."""
     assert epoch_program.as_text().count("tpu_custom_call") == \
-        3 * LM["num_layers"] == 36
+        2 * LM["num_layers"] == 24
     mem = epoch_program.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < V5E_HBM_BYTES
+    assert mem.temp_size_in_bytes <= EPOCH_TEMP_BYTES_BEFORE_PR30
 
 
 def test_paged_decode_step_compiles_with_12_kernels(decode_program):
@@ -526,12 +569,21 @@ def _metric_pattern(name):
 
 # (program fixture, metric file, kernel, custom calls the pattern must find)
 KERNEL_NAMES = [
+    # every training kernel of the epoch program: the forward and the
+    # fused backward, whose name the one pattern finds by its prefix
     ("epoch_program", "flash_attn_ms", "_attention_pallas",
      LM["num_layers"]),
-    ("epoch_program", "flash_attn_ms", "_attention_bwd_dkdv",
+    ("epoch_program", "flash_attn_ms", "_attention_bwd_dkdv_dq",
      LM["num_layers"]),
-    ("epoch_program", "flash_attn_roofline", "_attention_bwd_dq",
+    ("epoch_program", "flash_attn_roofline", "_attention_pallas",
      LM["num_layers"]),
+    ("epoch_program", "flash_attn_roofline", "_attention_bwd_dkdv_dq",
+     LM["num_layers"]),
+    # ... and the split pair a sequence past the fused limit takes
+    ("split_backward_program", "flash_attn_ms", "_attention_pallas", 1),
+    ("split_backward_program", "flash_attn_ms", "_attention_bwd_dkdv", 1),
+    ("split_backward_program", "flash_attn_roofline", "_attention_bwd_dq",
+     1),
     ("decode_program", "paged_attn_ms", "_paged_pallas", LM["num_layers"]),
     # Laguna's two layers: one full and one window page walk, and the gate/up
     # and down calls of the one sparse layer's grouped matmul
@@ -583,6 +635,9 @@ def test_metric_patterns_find_the_kernels(request, bench_trace_lib, program,
     mine = [n for n in found if n.split(".")[0] == kernel]
     assert len(mine) == calls, (kernel, sorted({n.split(".")[0]
                                                 for n, *_ in events}))
+    if metric.startswith("flash_attn"):
+        # ... and the pattern misses no attention kernel of the program
+        assert len(found) == len(events)
 
 
 def test_described_context_does_not_leak(v5e):
