@@ -88,15 +88,19 @@ DECLARED_METRICS: Dict[str, str] = {
     # models with two kinds of KV state and routed experts (models/moe_lm.py)
     "serving.batcher.pages.full": "counter",      # pages in use, summed a tick
     "serving.batcher.pages.window": "counter",
+    "serving.batcher.pages.latent": "counter",    # a one-pool latent kind
     "serving.batcher.pages.window_recycled": "counter",  # ring entries reused
     "serving.batcher.attended.full": "counter",   # K/V rows a layer attends
     "serving.batcher.attended.window": "counter",
+    "serving.batcher.attended.latent": "counter",
     "serving.batcher.prefill.attended.full": "counter",   # admissions' part
     "serving.batcher.prefill.attended.window": "counter",
+    "serving.batcher.prefill.attended.latent": "counter",
     "serving.moe.assignments": "counter",         # (token, expert) on experts held
     "serving.moe.experts_touched": "counter",     # (layer, expert) pairs read
     "serving.moe.live_assignments": "counter",    # the same, live rows only
     "serving.moe.load_max": "counter",            # busiest held expert, live rows
+    "serving.moe.zero_assignments": "counter",    # live rows' identity experts
     # -- counters: fleet gateway event ledger (serving/fleet.py, PR 9)
     "serving.fleet.retry": "counter",
     "serving.fleet.eject": "counter",

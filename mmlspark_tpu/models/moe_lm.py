@@ -48,12 +48,20 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import grouped_matmul as gm
+from ..ops.quant import rounded_to
 from .transformer import _rope, _single_tpu
 
 __all__ = ["MoELM", "STAT_NAMES", "yarn_inv_freq"]
 
 STAT_NAMES = ("moe_assignments", "moe_experts_touched",
               "moe_live_assignments", "moe_load_max")
+ZERO_STAT = "moe_zero_assignments"      # a layer with zero-compute experts
+
+
+def counters_of(names):
+    """((stat, the counter it feeds), ...): `moe_x` -> `serving.moe.x`."""
+    return tuple((name, "serving.moe." + name[len("moe_"):])
+                 for name in names)
 
 
 def _router_logits(y, wr):
@@ -106,8 +114,12 @@ def _normal(std):
 
 
 class _RMSNorm(nn.Module):
+    """`gain`: a constant the normed row is multiplied by in float32,
+    before its one rounding to the model's dtype."""
+
     eps: float
     dtype: Any
+    gain: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -116,7 +128,11 @@ class _RMSNorm(nn.Module):
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True)
                                 + self.eps)
-        return (y * w.astype(jnp.float32)).astype(self.dtype)
+        y = y * w.astype(jnp.float32)
+        if self.gain != 1.0:
+            y = y * self.gain
+        # one rounding, and the same for every consumer
+        return rounded_to(y, self.dtype).astype(self.dtype)
 
 
 class _DenseMLP(nn.Module):
@@ -136,9 +152,26 @@ class _DenseMLP(nn.Module):
                        preferred_element_type=jnp.float32)
 
 
+def _zero_experts_term(y, weights, top_e, num_experts: int):
+    """What the chosen zero-compute (identity) experts add: ids at or
+    past `num_experts` hand their input back, so their part of the sum is
+    the token's own row times the weights that fell on them.  No matmul,
+    no dispatch: outside the grouped matmul.  -> [T, E] float32."""
+    w_zero = jnp.sum(jnp.where(top_e >= num_experts, weights, 0.0), -1)
+    return w_zero[:, None] * y.astype(jnp.float32)
+
+
 class _SparseMLP(nn.Module):
-    """Router over all `num_experts`, top-k renormalised and scaled, the
-    experts in [lo, hi) held here, one shared expert."""
+    """The routed layer of both MoE models.  A router over all
+    `num_experts` FFN experts and then `zero_experts` identity experts,
+    softmax scores, the top-k chosen by score (plus a selection `bias`
+    that weighs nothing, if `choice_bias`), weights the chosen scores
+    times `scaling`, renormalised over the top-k if `renormalise`; the
+    FFN experts in [lo, hi) held here, the identity experts computed for
+    every token, one shared expert if `shared_width`.  `token_chunk`: a
+    call of more tokens sends them through the experts that many at a
+    time, the last chunk padded with rows that fall on no expert (0: all
+    at once)."""
 
     num_experts: int
     top_k: int
@@ -147,6 +180,10 @@ class _SparseMLP(nn.Module):
     scaling: float
     held: Tuple[int, int]
     dtype: Any
+    renormalise: bool = True
+    choice_bias: bool = False
+    zero_experts: int = 0
+    token_chunk: int = 0
 
     @nn.compact
     def __call__(self, y, live=None):
@@ -155,9 +192,9 @@ class _SparseMLP(nn.Module):
         lead, e = y.shape[:-1], y.shape[-1]
         lo, hi = self.held
         n_held = hi - lo
+        n_out = self.num_experts + self.zero_experts
         y = y.reshape(-1, e)
-        wr = self.param("router", _normal(e ** -0.5),
-                        (e, self.num_experts), self.dtype)
+        wr = self.param("router", _normal(e ** -0.5), (e, n_out), self.dtype)
         w1 = self.param("w1", _normal(e ** -0.5),
                         (n_held, e, self.width), self.dtype)
         w3 = self.param("w3", _normal(e ** -0.5),
@@ -167,32 +204,81 @@ class _SparseMLP(nn.Module):
         with jax.named_scope("moe.route"):
             r = _router_logits(y, wr)
             p = jax.nn.softmax(r, axis=-1)
-            top_p, top_e = jax.lax.top_k(p, self.top_k)
-            weights = self.scaling * top_p / jnp.sum(top_p, -1,
-                                                     keepdims=True)
+            if self.choice_bias:
+                bias = self.param("bias", nn.initializers.zeros, (n_out,),
+                                  jnp.float32)
+                _biased, top_e = jax.lax.top_k(p + bias, self.top_k)
+                top_p = jnp.take_along_axis(p, top_e, -1)
+            else:
+                top_p, top_e = jax.lax.top_k(p, self.top_k)
+            weights = self.scaling * top_p
+            if self.renormalise:
+                weights = weights / jnp.sum(top_p, -1, keepdims=True)
+        alive = (jnp.ones(top_e.shape[:1], bool) if live is None
+                 else live.reshape(-1))
+
+        def experts(y, top_e, weights, alive):
+            """The held experts' weighted sum for these rows, and the
+            rows' statistics."""
             tm = gm.row_tile(y.shape[0])
             plan = gm.dispatch(top_e.astype(jnp.int32), lo, hi, tm)
-        with jax.named_scope("moe.experts"):
             rows = gm.expert_mlp(y, plan, w1, w3, w2, tm,
                                  kernel=_single_tpu())
-            out = gm.combine(rows, plan, weights)
+            if live is None:
+                load = plan.counts
+            else:                   # the same count over the live rows
+                e_live = jnp.where(plan.held & alive[:, None], top_e - lo,
+                                   n_held)
+                load = jnp.zeros(n_held + 1, jnp.int32).at[e_live].add(
+                    1)[:n_held]
+            return gm.combine(rows, plan, weights), (
+                jnp.sum(plan.counts), jnp.sum(plan.counts > 0), load)
+
+        with jax.named_scope("moe.experts"):
+            t, chunk = y.shape[0], self.token_chunk
+            if chunk and t > chunk:
+                # the dispatch buffers hold a row for EVERY assignment
+                # of the call (dropless, whatever falls on the experts
+                # held): a long admission goes through in chunks of
+                # tokens, so that they stay the size of one chunk's
+                rows = (y, top_e, weights, alive)
+                pad = -t % chunk
+                if pad:             # the last chunk's rows past the call:
+                    # nobody's, and on an expert no chip holds
+                    rows = tuple(
+                        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
+                                constant_values=fill)
+                        for a, fill in zip(rows, (0, n_out, 0, False)))
+                out, (assigned, touched, load) = jax.lax.map(
+                    lambda a: experts(*a), jax.tree.map(
+                        lambda a: a.reshape(-1, chunk, *a.shape[1:]), rows))
+                out = out.reshape(-1, e)
+                if pad:
+                    out = out[:t]
+                assigned, touched, load = (jnp.sum(assigned), jnp.sum(touched),
+                                           jnp.sum(load, 0))
+            else:
+                out, (assigned, touched, load) = experts(y, top_e, weights,
+                                                         alive)
+        if self.zero_experts:
+            with jax.named_scope("moe.zero"):
+                out = out + _zero_experts_term(y, weights, top_e,
+                                               self.num_experts)
         taps = dict(input=y, router_input=y.astype(jnp.float32), logits=r,
                     experts=top_e, routed=out)    # for whoever asks
         for name, value in taps.items():
             self.sow("routing", name, value.reshape(*lead, -1))
-        with jax.named_scope("moe.shared"):
-            out = out + _DenseMLP(self.shared_width, self.dtype,
-                                  name="shared")(y)
-        if live is None:
-            load = plan.counts
-        else:                       # the same count over the live rows
-            e_live = jnp.where(plan.held & live.reshape(-1, 1),
-                               top_e - lo, n_held)
-            load = jnp.zeros(n_held + 1, jnp.int32).at[e_live].add(
-                1)[:n_held]
-        for name, value in zip(STAT_NAMES, (
-                jnp.sum(plan.counts), jnp.sum(plan.counts > 0),
-                jnp.sum(load), jnp.max(load))):
+        if self.shared_width:
+            with jax.named_scope("moe.shared"):
+                out = out + _DenseMLP(self.shared_width, self.dtype,
+                                      name="shared")(y)
+        stats = [assigned, touched, jnp.sum(load), jnp.max(load)]
+        names = STAT_NAMES
+        if self.zero_experts:       # live assignments that cost nothing
+            names = names + (ZERO_STAT,)
+            stats.append(jnp.sum((top_e >= self.num_experts)
+                                 & alive[:, None]))
+        for name, value in zip(names, stats):
             self.sow("stats", name, value.astype(jnp.int32),
                      reduce_fn=lambda a, b: a + b,
                      init_fn=lambda: jnp.zeros((), jnp.int32))
@@ -329,8 +415,7 @@ class MoELM(nn.Module):
     input_dtype = jnp.int32
     # the `stats` a program sums, and the counter each feeds when a
     # server hands them back
-    stat_counters = tuple(
-        (name, "serving.moe." + name[len("moe_"):]) for name in STAT_NAMES)
+    stat_counters = counters_of(STAT_NAMES)
 
     @classmethod
     def from_config(cls, cfg: dict, max_len: int, dtype=jnp.bfloat16):
@@ -380,6 +465,12 @@ class MoELM(nn.Module):
         if "window" in self.layer_types:
             kinds.append(("window", self.window))
         return tuple(kinds)
+
+    @property
+    def cache_rows(self):
+        """Per cache kind, the row width of each pool a layer of the
+        kind keeps: a K and a V pool."""
+        return ((self.kv_width, self.kv_width),) * len(self.cache_kinds)
 
     @property
     def layer_kinds(self):

@@ -468,6 +468,23 @@ class TransformerLM(nn.Module):
         """K/V head count — the KV-cache head dimension every cache
         allocator (generation, batcher) must use."""
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def head_dim(self) -> int:
+        return self.embed_dim // self.num_heads
+
+    # what a paged server sizes its pools from (serving/batcher.py, KINDS
+    # OF CACHE): every layer keeps the whole context, as a K and a V pool
+    # of the KV heads side by side
+    cache_kinds = (("full", None),)
+
+    @property
+    def layer_kinds(self):
+        return (0,) * self.num_layers
+
+    @property
+    def cache_rows(self):
+        return ((self.kv_heads * self.head_dim,) * 2,)
     input_dtype = jnp.int32  # token ids (FlaxBundle auto-init dummy dtype)
 
     @property

@@ -771,8 +771,10 @@ fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 # ---- prefill: causal, grouped heads, optional window (forward only) -------
 @partial(jax.jit, static_argnames=("group", "window", "scale"))
 def _prefill_attention_pallas(q, k, v, group: int, window, scale: float):
-    """q [B*H, S, D], k/v [B*Hkv, S, D] (H = Hkv * group; query head
-    b*H + h reads KV head (b*H + h) // group) -> [B*H, S, D] f32.
+    """q [B*H, S, D], k [B*Hkv, S, D], v [B*Hkv, S, Dv] (H = Hkv *
+    group; query head b*H + h reads KV head (b*H + h) // group; Dv = D
+    but for latent attention's expanded heads, 192 against 128) ->
+    [B*H, S, Dv] f32.
 
     The flash forward again, for serving's admission prefill: causal,
     and with `window` (static) only keys in (query - window, query].  The
@@ -786,6 +788,7 @@ def _prefill_attention_pallas(q, k, v, group: int, window, scale: float):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = q.shape
+    dv = v.shape[-1]
     block_q = min(_BLOCK_Q, s)
     block_k = min(256, s) if window is not None else _pick_block_k(s)
     n_kb = s // block_k
@@ -849,16 +852,16 @@ def _prefill_attention_pallas(q, k, v, group: int, window, scale: float):
 
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bh, s, dv), jnp.float32),
         grid=(bh, s // block_q, n_rel),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_block),
-            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, dv), kv_block),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
@@ -867,8 +870,8 @@ def _prefill_attention_pallas(q, k, v, group: int, window, scale: float):
 
 
 def _xla_prefill_attention(q, k, v, window):
-    """Dense composition of the same: [B, S, H|Hkv, D] -> [B, S, H, D]
-    f32."""
+    """Dense composition of the same: [B, S, H|Hkv, D|Dv] -> [B, S, H,
+    Dv] f32."""
     b, s, h, d = q.shape
     group = h // k.shape[2]
     k = jnp.repeat(k, group, axis=2)
@@ -885,22 +888,27 @@ def _xla_prefill_attention(q, k, v, window):
                       preferred_element_type=jnp.float32)
 
 
-def prefill_attention_ok(q) -> bool:
-    """[B, S, H, D]: lane-wide heads and a sequence the blocks tile."""
+def prefill_attention_ok(q, v=None) -> bool:
+    """q [B, S, H, D], v [B, S, Hkv, Dv] (None: Dv = D): a q/k head of
+    whole or one and a half lane tiles (128, 192, 256: the contraction
+    compiles at the 64-minor tile), a lane-wide v head, and a sequence
+    the blocks tile."""
     _b, s, _h, d = q.shape
-    return d % _LANE == 0 and s % 8 == 0 and (s <= _BLOCK_Q or s % 256 == 0)
+    dv = d if v is None else v.shape[-1]
+    return (d >= _LANE and d % 64 == 0 and dv % _LANE == 0 and s % 8 == 0
+            and (s <= _BLOCK_Q or s % 256 == 0))
 
 
 def prefill_attention(q, k, v, window=None, kernel: bool = True):
     """Causal attention of a whole prompt with grouped heads and an
-    optional window: q [B, S, H, D], k/v [B, S, Hkv, D] -> [B, S, H, D]
-    f32.  `kernel` False (or a shape the kernel declines) takes the XLA
-    composition."""
+    optional window: q [B, S, H, D], k [B, S, Hkv, D], v [B, S, Hkv, Dv]
+    -> [B, S, H, Dv] f32.  `kernel` False (or a shape the kernel
+    declines) takes the XLA composition."""
     b, s, h, d = q.shape
-    hkv = k.shape[2]
-    if not (kernel and prefill_attention_ok(q)):
+    hkv, dv = k.shape[2], v.shape[-1]
+    if not (kernel and prefill_attention_ok(q, v)):
         return _xla_prefill_attention(q, k, v, window)
     o = _prefill_attention_pallas(
-        _to_bhsd(q, d), _to_bhsd(k, d), _to_bhsd(v, d), group=h // hkv,
+        _to_bhsd(q, d), _to_bhsd(k, d), _to_bhsd(v, dv), group=h // hkv,
         window=window, scale=1.0 / float(d) ** 0.5)
-    return _from_bhsd(o, b, s, h, d)
+    return _from_bhsd(o, b, s, h, dv)

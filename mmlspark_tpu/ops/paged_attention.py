@@ -62,7 +62,7 @@ import jax.numpy as jnp
 from .pallas_kernels import PALLAS_IMAGE_VMEM_BUDGET, _interpret
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_int8",
-           "paged_kernel_ok"]
+           "paged_kernel_ok", "paged_mla_attention"]
 
 _NEG_INF = -1e30
 _LANE = 128
@@ -475,3 +475,172 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos,
     if window is not None:
         return _xla_paged_window(q, k_pool, v_pool, tbl, pos, window)
     return _xla_paged(q, k_pool, v_pool, page_table, pos)
+
+
+# ---- latent attention (MLA): one pool of [c, kr] rows ----------------------
+_MLA_CHUNK = 8          # pages a loop step copies and multiplies at once
+
+
+def _mla_query_parts(q_abs, dtype):
+    """The absorbed query q_abs [B, H, W] float32 as the operands of a
+    product at the pool's `dtype`: hi + lo, hi the rounding to `dtype`
+    and lo the rounding of what that lost.  Against bf16 rows the two
+    products' sum carries q_abs to 16 bits of mantissa, which is what
+    float32 accumulation of the unabsorbed product would keep (a float32
+    pool takes hi alone: lo is zero).  The roundings are ones the
+    compiler keeps (`rounded_to`): with a bare `astype` pair lo comes
+    out zero and the walk reads a bf16 query."""
+    from .quant import rounded_to
+
+    hi = rounded_to(q_abs, dtype)
+    return hi.astype(dtype), rounded_to(q_abs - hi, dtype).astype(dtype)
+
+
+def _mla_page_walk(hi, lo, pool, page_table, pos, rank: int):
+    """hi, lo [B, H, W] (`_mla_query_parts`); pool [NP, page, W], a row
+    the latent (first `rank`) and the rope key; table [B, MP] i32; pos
+    [B] i32 -> softmax((hi + lo) . row) . row[:rank], [B, H, rank] f32.
+
+    One grid step a slot.  Inside it a loop over the slot's LIVE pages,
+    `_MLA_CHUNK` at a time (its trip count is the slot's own: pages past
+    the write position are neither copied nor multiplied, and a parked
+    slot takes one chunk of the trash page): each page is copied from
+    the pool in HBM into one of two VMEM buffers by its own DMA, the next
+    chunk's copies running under this chunk's products.  All heads read
+    the one row, so a chunk is two MXU products: [2H, W] x [W, chunk
+    rows] for the scores of hi and lo at once, [H, chunk rows] x [chunk
+    rows, rank] for the values; online softmax in float32 scratch."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, w = hi.shape
+    _, page, _ = pool.shape
+    mp = page_table.shape[1]
+    g = min(_MLA_CHUNK, mp)
+    span = g * page
+    split = pool.dtype != jnp.float32
+    q2 = jnp.concatenate([hi, lo], 1) if split else hi
+    hq = q2.shape[1]
+
+    def kernel(tbl_ref, pos_ref, q_ref, pool_ref, o_ref, buf, sem,
+               o_acc, m_acc, l_acc):
+        bi = pl.program_id(0)
+        p_b = pos_ref[bi]
+        n_chunks = (p_b // page + g) // g
+
+        def copies(chunk, slot):
+            return [pltpu.make_async_copy(
+                pool_ref.at[tbl_ref[bi * mp + jnp.minimum(chunk * g + i,
+                                                          mp - 1)]],
+                buf.at[slot, pl.ds(i * page, page)], sem.at[slot, i])
+                for i in range(g)]
+
+        o_acc[...] = jnp.zeros_like(o_acc)
+        m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
+        l_acc[...] = jnp.zeros_like(l_acc)
+        for cp in copies(0, 0):
+            cp.start()
+
+        def body(c, _carry):
+            slot = c % 2
+
+            @pl.when(c + 1 < n_chunks)
+            def _ahead():
+                for cp in copies(c + 1, 1 - slot):
+                    cp.start()
+
+            for cp in copies(c, slot):
+                cp.wait()
+            rows = buf[slot]                              # [span, W]
+            sc = jax.lax.dot_general(
+                q_ref[0], rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [2H, span]
+            if split:
+                sc = sc[:h] + sc[h:]
+            cols = c * span + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            seen = cols <= p_b
+            sc = jnp.where(seen, sc, _NEG_INF)
+            m_prev = jnp.max(m_acc[...], axis=-1, keepdims=True)
+            l_prev = jnp.max(l_acc[...], axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
+            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            o_acc[...] = o_acc[...] * corr + jax.lax.dot_general(
+                p.astype(rows.dtype), rows[:, :rank],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_acc[...] = jnp.broadcast_to(m_new, m_acc.shape)
+            l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape)
+            return _carry
+
+        jax.lax.fori_loop(0, n_chunks, body, 0)
+        l_fin = jnp.max(l_acc[...], axis=-1, keepdims=True)
+        o_ref[0] = o_acc[...] / jnp.maximum(l_fin, 1e-20)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[pl.BlockSpec((1, hq, w), lambda bi, tbl, pos:
+                                   (bi, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, h, rank), lambda bi, tbl, pos:
+                                   (bi, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, span, w), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2, g)),
+                            pltpu.VMEM((h, rank), jnp.float32),
+                            pltpu.VMEM((h, _LANE), jnp.float32),
+                            pltpu.VMEM((h, _LANE), jnp.float32)]),
+        interpret=_interpret(),
+    )(page_table.reshape(-1), pos, q2, pool)
+
+
+# `mla_decode_ms` finds the walk by `^_paged_mla`.
+@partial(jax.jit, static_argnames=("rank",))
+def _paged_mla(hi, lo, pool, page_table, pos, rank: int):
+    return _mla_page_walk(hi, lo, pool, page_table, pos, rank)
+
+
+def _xla_paged_mla(hi, lo, pool, page_table, pos, rank: int):
+    """Gather semantics of the same: every slot's pages gathered,
+    positions <= pos attended, in float32."""
+    q = hi.astype(jnp.float32) + lo.astype(jnp.float32)
+    rows = _gather_pages(pool, page_table, None).astype(jnp.float32)
+    sc = jnp.einsum("bhw,bkw->bhk", q, rows,
+                    precision=jax.lax.Precision.HIGHEST)
+    valid = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
+    p = jax.nn.softmax(jnp.where(valid[:, None, :], sc, -jnp.inf), axis=-1)
+    return jnp.einsum("bhk,bkr->bhr", p, rows[..., :rank],
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def mla_kernel_ok(pool, rank: int) -> bool:
+    """A lane-wide latent in lane-wide rows, a page of whole sublane
+    tiles of the pool's dtype (a page is one DMA into a slice of the
+    chunk's buffer), and two chunks of rows inside the VMEM budget."""
+    import os
+
+    _, page, w = pool.shape
+    item = pool.dtype.itemsize
+    return (not os.environ.get("MMLSPARK_NO_PAGED_KERNEL")
+            and rank % _LANE == 0 and w % _LANE == 0
+            and page % (_SUBLANE * max(1, 4 // item)) == 0
+            and 2 * _MLA_CHUNK * page * w * item <= PALLAS_IMAGE_VMEM_BUDGET)
+
+
+def paged_mla_attention(q_abs, pool, page_table, pos, rank: int,
+                        kernel: bool = True):
+    """Single-token decode attention in the latent space: the absorbed,
+    scaled query q_abs [B, H, rank + rope] float32 against ONE pool of
+    cached rows [NP, page, rank + rope] (latent `rank`, then rope key) under
+    table [B, MP] at per-slot positions `pos` [B] -> (softmax(q_abs .
+    row) . row[:rank] [B, H, rank] float32, the query as it was
+    multiplied, float32).  The page walk where `kernel` and the shape
+    allow, the gather composition otherwise."""
+    hi, lo = _mla_query_parts(q_abs, pool.dtype)
+    tbl, pos = page_table.astype(jnp.int32), pos.astype(jnp.int32)
+    walk = _paged_mla if kernel and mla_kernel_ok(pool, rank) \
+        else _xla_paged_mla
+    return (walk(hi, lo, pool, tbl, pos, rank=rank),
+            hi.astype(jnp.float32) + lo.astype(jnp.float32))

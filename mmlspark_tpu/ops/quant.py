@@ -116,6 +116,19 @@ class QuantDense(nn.Module):
         return y.astype(self.dtype)
 
 
+def rounded_to(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """float32 x with the values it would have at `dtype`, still float32,
+    by an operation the compiler keeps (`reduce_precision`).  Of a bare
+    `astype` pair (f32 -> bf16 -> f32) it may drop the rounding for one
+    consumer and keep it for another (excess precision is allowed on the
+    TPU): a router and a tap of its input then read different numbers,
+    and a hi + lo split of a query loses its lo."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return x
+    info = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(x, info.nexp, info.nmant)
+
+
 def quantize_kv_row(x: jnp.ndarray):
     """[..., H, D] K/V rows -> (int8 rows, f32 per-row-per-head scales
     [..., H]).  Symmetric per-(position, head) scaling: each attention

@@ -64,10 +64,13 @@ mirrors and tables agree.
 Compose with serving: `stream_reply(lambda row: batcher.stream_text(...))`
 gives token-by-token HTTP with cross-request batching on the device.
 
-KINDS OF CACHE.  A model may say what state each layer keeps
-(`cache_kinds`, `layer_kinds`; models/moe_lm.py): the whole context
-("full") or only the last `window` positions ("window").  The batcher
-then keeps a pool set, a page table and a free list PER KIND, and an
+KINDS OF CACHE.  A model says what state each cached layer keeps
+(`cache_kinds`, `layer_kinds`, `cache_rows`; models/moe_lm.py,
+models/longcat_lm.py): the whole context ("full": a K and a V pool;
+"latent": ONE pool of latent rows, two cached sublayers a block) or only
+the last `window` positions ("window"); and, per kind, the row width of
+every pool a layer of the kind keeps.  The batcher sizes its pools from
+that, keeps a pool set, a page table and a free list PER KIND, and an
 admission reserves in both.  The full kind is the state described above;
 a window slot's table is a RING of window / page + 1 pages
 (`_WindowPages`): logical page lp lives at entry lp % ring, so the page
@@ -77,7 +80,7 @@ held.  Such a model also brings its own admission forward (`prefill`:
 the last position's logits only, cache rows of the bucket's length, at
 most `max_len` prompt tokens a program) and may name statistics
 (`stat_counters`), which ride back with the tick's token fetch.
-A model without the description (TransformerLM) is the case of one kind.
+`TransformerLM` is the case of one kind, "full".
 Each arm below asks for the one thing it needs: `_own_prefill` (the
 model's admission forward; its programs hand back the model's
 statistics behind their tokens), `_win` (a second kind of page).  The
@@ -126,6 +129,9 @@ IDLE = "serving.batcher.idle"               # nothing live: wait on intake
 # counters of the one-step-ahead loop (module doc)
 TICK_OVERLAPPED = TICK + ".overlapped"      # dispatched before that fetch
 TICK_LATE_DISCARDS = TICK + ".late_discards"   # dead rows' tokens dropped
+# positions whose taps one replayed admission program hands back
+# (`teacher_force`): a longer prompt is replayed once a piece
+TAP_ROWS = 1024
 
 
 class PrefillStage(Stage):
@@ -389,12 +395,14 @@ class ContinuousBatcher:
         # split, so its temporaries are those of one full-length prompt
         self._prefill_cap = model.max_len if self._own_prefill else None
         s, L = self.max_slots, model.max_len
-        h = model.kv_heads
-        d = getattr(model, "head_dim", None) or (model.embed_dim
-                                                 // model.num_heads)
-        kinds = getattr(model, "cache_kinds", (("full", None),))
-        self._layer_kinds = tuple(getattr(model, "layer_kinds",
-                                          (0,) * model.num_layers))
+        # what state a layer keeps is the model's to say (module doc,
+        # KINDS OF CACHE): the kinds, each cached layer's kind, and the
+        # row width of every pool a layer of the kind keeps
+        kinds = model.cache_kinds
+        self._layer_kinds = tuple(model.layer_kinds)
+        # the whole-context kind's counters, by its name
+        self._count = {what: f"serving.batcher.{what}.{kinds[0][0]}"
+                       for what in ("pages", "attended", "prefill.attended")}
         self._win: Optional[_WindowPages] = None
         dt = jnp.float32 if model.dtype == jnp.float32 else model.dtype
         if self.paged:
@@ -418,9 +426,6 @@ class ContinuousBatcher:
                         else s * self._mp + 1)      # default: dense parity
             if self._np < 2:
                 raise ValueError("num_pages must be >= 2 (page 0 is trash)")
-            # FLAT pools: heads folded into the minor axis (module doc)
-            shape_kv = (self._np, self.page_size, h * d)
-            shape_sc = (self._np, self.page_size, h)
             self._free: List[int] = list(range(1, self._np))
             self._avail = len(self._free)           # unreserved budget
             self._slot_pages: List[List[int]] = [[] for _ in range(s)]
@@ -431,23 +436,37 @@ class ContinuousBatcher:
             self._next_prefix = 1
             if len(kinds) > 1:
                 self._win = _WindowPages(kinds[1][1], self.page_size, s)
-        else:
-            shape_kv, shape_sc = (s, L, h, d), (s, L, h)
-        if kv_cache_dtype == "int8":
-            # 4x the co-tenant density per HBM byte: int8 rows + f32
-            # per-(pos, head) scales (ops/quant.quantize_kv_row)
+        if self.paged and kv_cache_dtype is None:
+            # FLAT pools (module doc): per cached layer, one pool a row
+            # width of its kind: K and V with the heads folded into the
+            # minor axis, or a latent kind's single row
+            pages = [self._np] + ([self._win.np] if self._win else [])
             self._cache = tuple(
-                (jnp.zeros(shape_kv, jnp.int8),
-                 jnp.zeros(shape_sc, jnp.float32),
-                 jnp.zeros(shape_kv, jnp.int8),
-                 jnp.zeros(shape_sc, jnp.float32))
-                for _ in range(model.num_layers))
-        else:
-            shapes = [shape_kv] + ([(self._win.np, *shape_kv[1:])]
-                                   if self._win is not None else [])
-            self._cache = tuple(
-                (jnp.zeros(shapes[kind], dt), jnp.zeros(shapes[kind], dt))
+                tuple(jnp.zeros((pages[kind], self.page_size, w), dt)
+                      for w in model.cache_rows[kind])
                 for kind in self._layer_kinds)
+        else:
+            # the dense slot cache and int8 pools are (K, V) of separate
+            # heads: the models that take them say both
+            h, d = model.kv_heads, model.head_dim
+            if self.paged:
+                shape_kv = (self._np, self.page_size, h * d)
+                shape_sc = (self._np, self.page_size, h)
+            else:
+                shape_kv, shape_sc = (s, L, h, d), (s, L, h)
+            if kv_cache_dtype == "int8":
+                # 4x the co-tenant density per HBM byte: int8 rows + f32
+                # per-(pos, head) scales (ops/quant.quantize_kv_row)
+                self._cache = tuple(
+                    (jnp.zeros(shape_kv, jnp.int8),
+                     jnp.zeros(shape_sc, jnp.float32),
+                     jnp.zeros(shape_kv, jnp.int8),
+                     jnp.zeros(shape_sc, jnp.float32))
+                    for _ in self._layer_kinds)
+            else:
+                self._cache = tuple(
+                    (jnp.zeros(shape_kv, dt), jnp.zeros(shape_kv, dt))
+                    for _ in self._layer_kinds)
         # loop-thread state of the decode pipeline (module doc, ONE STEP
         # AHEAD).  Host mirrors: `_pos` the position the NEXT dispatch
         # writes, `_tok` the last token fetched, `_give` a token the host
@@ -567,7 +586,7 @@ class ContinuousBatcher:
                                     if c != "kvcache"}
             dL = draft_model.max_len
             dh = draft_model.kv_heads
-            dd = draft_model.embed_dim // draft_model.num_heads
+            dd = draft_model.head_dim
             ddt = (jnp.float32 if draft_model.dtype == jnp.float32
                    else draft_model.dtype)
             self._d_cache = tuple(
@@ -600,20 +619,39 @@ class ContinuousBatcher:
 
         self._load_kinds = jax.jit(load, donate_argnums=(0,))
 
-    def _own_programs(self, taps: bool):
+    def _own_programs(self, taps):
         """(decode step, admission forward) over the model's own methods.
         `taps`: the same functions also hand back the logits and the
-        model's `routing` collection (what `teacher_force` reads)."""
+        model's `routing` collection (what `teacher_force` reads): all of
+        it (True), or the taps named.  The admission forward then takes
+        one more argument, a position `r0`, and hands back the taps of
+        positions [r0, r0 + TAP_ROWS) only, each layer's cut before the
+        layers are stacked: a whole context's taps of every layer are
+        gigabytes beside the weights and the pools, and the replayed
+        program needs what the served one needs and one piece."""
         model = self.model
         names = tuple(name for name, _counter in self._stat_counters)
         asked = ["stats", "routing"] if taps else ["stats"]
+        tap_rows = TAP_ROWS
 
-        def out(logits, kept):
+        def out(logits, kept, r0=None):
             packed = jnp.concatenate([
                 jnp.argmax(logits, axis=-1).astype(jnp.int32),
                 _sum_stats(kept.get("stats", {}), names)])
             if taps:
-                return packed, logits, _by_tap(kept.get("routing", {}))
+                routing = kept.get("routing", {})
+                if r0 is not None:
+                    # each layer's tap cut where it was sown, before the
+                    # layers are stacked: no whole context's tap outlives
+                    # its layer
+                    routing = jax.tree.map(
+                        lambda x: jax.lax.dynamic_slice_in_dim(
+                            x, r0, min(tap_rows, x.shape[1]), axis=1),
+                        routing)
+                routing = _by_tap(routing)
+                if taps is not True:
+                    routing = {k: v for k, v in routing.items() if k in taps}
+                return packed, logits, routing
             return packed
 
         def forward(v, t, c, p, tables):
@@ -621,10 +659,10 @@ class ContinuousBatcher:
                 v, t, c, p, tables, method=model.decode_step, mutable=asked)
             return out(lg[:, 0], kept), cache
 
-        def prefill(v, toks, last, slots, adm):
+        def prefill(v, toks, last, slots, adm, r0=None):
             (lg, rows), kept = model.apply(
                 v, toks, last, method=model.prefill, mutable=asked)
-            made = out(lg, kept)
+            made = out(lg, kept, r0)
             firsts = (made[0] if taps else made)[:toks.shape[0]]
             return made, adm.at[slots].set(firsts, mode="drop"), rows
 
@@ -635,7 +673,7 @@ class ContinuousBatcher:
         for (_name, counter), value in zip(self._stat_counters, values):
             telemetry.incr(counter, int(value))
 
-    def teacher_force(self, pairs) -> list:
+    def teacher_force(self, pairs, taps=True) -> list:
         """Replay (prompt ids, reply ids) pairs as they would be served:
         the same host path (reservation, page tables of every kind,
         just-in-time growth, the ring), the same program functions at
@@ -647,8 +685,11 @@ class ContinuousBatcher:
         -> per pair {"logits": [len(reply), V] (row j is what chose reply
         token j), "routing": {tap: [routed layers, P, ...]}} over the
         P = len(prompt) + len(reply) - 1 positions fed, the taps being
-        the model's `routing` collection.  For a stopped (or never
-        started) batcher whose model brings its own programs."""
+        the model's `routing` collection (`taps`: all of them, or the
+        ones named: a tap not asked for is not computed); a tap that only
+        the decode step sows covers the len(reply) - 1 positions it fed.
+        For a stopped (or never started) batcher whose model brings its
+        own programs."""
         if not self._own_prefill:
             raise ValueError("teacher_force needs a model with its own "
                              "prefill and decode programs")
@@ -658,21 +699,38 @@ class ContinuousBatcher:
             raise ValueError(f"{len(pairs)} pairs on {self.max_slots} slots")
         served = self._step, self._prefill_last
         seen: list = []
+        replay_step, replay_admission = self._own_programs(taps)
 
-        def handing_back(program):
-            def call(*args):
-                (packed, logits, routing), *rest = program(*args)
-                seen.append((logits, routing))
-                return (packed, *rest)
-            return call
+        def step(*args):
+            (packed, logits, routing), *rest = replay_step(*args)
+            seen.append((logits, routing))
+            return (packed, *rest)
 
-        self._step, self._prefill_last = map(handing_back,
-                                             self._own_programs(taps=True))
+        def admission(*args):
+            # the same program once for every TAP_ROWS positions of the
+            # prompt: all it computes is the same each time, the taps it
+            # hands back are the next piece's
+            pieces = []
+            for r0 in range(0, len(admitting.prompt), TAP_ROWS):
+                (packed, logits, routing), *rest = replay_admission(
+                    *args, np.int32(r0))
+                pieces.append(jax.tree.map(np.asarray, routing))
+                # the next piece's program finds this piece's taps gone
+                # from the device
+                del routing
+            seen.append((logits, {
+                k: np.concatenate([p[k] for p in pieces], axis=2)
+                for k in pieces[0]}))
+            return (packed, *rest)
+
+        self._step, self._prefill_last = step, admission
+        admitting = None              # the request `admission` replays
         try:
             live, out = {}, []
             for prompt, reply in pairs:
-                req = _Request(np.asarray(prompt, np.int32).reshape(-1),
-                               len(reply), None)
+                req = admitting = _Request(
+                    np.asarray(prompt, np.int32).reshape(-1), len(reply),
+                    None)
                 self._buffer.append(req)
                 batch = self._plan_admit()
                 if len(batch) != 1:
@@ -702,8 +760,9 @@ class ContinuousBatcher:
                 for slot in active:
                     req, _reply, rec = live[slot]
                     rec["logits"].append(logits[slot])
-                    for tap, v in routing.items():
-                        rec["routing"][tap].append(v[:, slot])
+                    for tap, v in routing.items():    # a tap of the
+                        # decode step alone has no rows of the prompt
+                        rec["routing"].setdefault(tap, []).append(v[:, slot])
                     if self._live[slot] is not req:
                         del live[slot]
         finally:
@@ -1184,8 +1243,8 @@ class ContinuousBatcher:
                 # (query, key) pairs a layer of each kind attends: in
                 # all (with the decode ticks'), and the admissions' own
                 pairs = n * (n + 1) // 2
-                telemetry.incr("serving.batcher.attended.full", pairs)
-                telemetry.incr("serving.batcher.prefill.attended.full", pairs)
+                telemetry.incr(self._count["attended"], pairs)
+                telemetry.incr(self._count["prefill.attended"], pairs)
                 if win is not None:
                     for lp, pg in win.admit(slot, n):
                         ids[1][i, lp] = pg
@@ -1536,9 +1595,9 @@ class ContinuousBatcher:
                             + len(self._slot_pages[sl])] = pg
                 self._slot_pages[sl].append(pg)
         pos = self._pos[active].astype(np.int64) + 1
-        telemetry.incr("serving.batcher.pages.full",
+        telemetry.incr(self._count["pages"],
                        sum(len(self._slot_pages[sl]) for sl in active))
-        telemetry.incr("serving.batcher.attended.full", int(pos.sum()))
+        telemetry.incr(self._count["attended"], int(pos.sum()))
         win = self._win
         if win is None:
             return

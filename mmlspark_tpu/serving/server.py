@@ -854,9 +854,13 @@ class ServingServer:
             if self._running.is_set() and not self._worker.is_alive():
                 self.stats["recoveries"] += 1
                 self.stats["replayed"] += self.server.recover(self.max_attempts)
-                self._worker = threading.Thread(
+                # started before it is published: `stop()` joins whatever
+                # `_worker` names, and a thread not yet started cannot be
+                # joined
+                worker = threading.Thread(
                     target=self._loop, daemon=True, name="serving-batch-loop")
-                self._worker.start()
+                worker.start()
+                self._worker = worker
 
     def start(self) -> ServiceInfo:
         self.server.start()

@@ -492,7 +492,8 @@ def laguna_programs(v5e):
                                 tables),
         "replay_admission": _compile(
             replay_admission, variables, _shape(v5e, (1, ctx), jnp.int32),
-            _shape(v5e, (1,), jnp.int32), _shape(v5e, (1,), jnp.int32), adm),
+            _shape(v5e, (1,), jnp.int32), _shape(v5e, (1,), jnp.int32), adm,
+            _shape(v5e, (), jnp.int32)),
         "decode_step": _compile(batcher._step, variables, cache, *given,
                                 tables),
         "admission": _compile(
@@ -560,6 +561,128 @@ def test_laguna_decode_program_picks_and_feeds_its_own_tokens(
         len(laguna_programs["stats"]), 50176)
 
 
+# ---- LongCat-Flash-Chat: latent page walk, 192/128 flash forward ------------
+LONGCAT_SERVE = {"max_slots": 32, "page_size": 64, "context": 8192}
+LONGCAT_ADMISSION = (1, 8192)          # the largest: a whole context alone
+V5E_BYTES_LIMIT = 16.9e9               # what the runtime leaves a program
+
+
+@pytest.fixture(scope="module")
+def longcat_programs(v5e):
+    """-> {"decode_step", "admission", "load"} compiled for the described
+    chip at 2 blocks of the published widths (4 cached sublayers), with
+    the pools' bytes and shape."""
+    import json
+
+    from mmlspark_tpu.models.longcat_lm import LongCatLM
+    from mmlspark_tpu.serving.batcher import ContinuousBatcher
+
+    with open(os.path.join(BENCH, "configs", "longcat-flash-chat.json")) as f:
+        cfg = dict(json.load(f), num_layers=2)
+    b, page, ctx = (LONGCAT_SERVE[k] for k in ("max_slots", "page_size",
+                                               "context"))
+    model = LongCatLM.from_config(cfg, ctx, jnp.bfloat16)
+    variables = {"params": _lm_variables(v5e, model, (1, 8))}
+    batcher = ContinuousBatcher(model, variables, max_slots=b, paged=True,
+                                page_size=page, num_pages=2)
+    assert batcher._layer_kinds == (0,) * 4 and batcher._win is None
+    mp, (width,) = ctx // page, model.cache_rows[0]
+    assert width == 640                 # 576 values in whole lane tiles
+    n_pages = b * mp + 1
+    cache = tuple((_shape(v5e, (n_pages, page, width), jnp.bfloat16),)
+                  for _ in batcher._layer_kinds)
+    k, bucket = LONGCAT_ADMISSION
+    rows = tuple((_shape(v5e, (k, bucket, width), jnp.bfloat16),)
+                 for _ in batcher._layer_kinds)
+    given = _step_inputs(v5e, b, len(batcher._stat_counters))
+    pools = jax.tree.leaves(cache)
+    params = jax.tree.leaves(variables)
+    admitted = (variables, _shape(v5e, (k, bucket), jnp.int32),
+                _shape(v5e, (k,), jnp.int32), _shape(v5e, (k,), jnp.int32),
+                _shape(v5e, (b,), jnp.int32))
+    # what `teacher_force` runs for the cell's `verify`: the taps it asks
+    # for, a piece of positions a program
+    _step, replay_admission = batcher._own_programs(
+        ("router_input", "logits", "experts", "routed", "mla_query",
+         "mla_q_nope"))
+    return {
+        "decode_step": _compile(batcher._step, variables, cache, *given,
+                                (_shape(v5e, (b, mp), jnp.int32),)),
+        "admission": _compile(batcher._prefill_last, *admitted),
+        "replay_admission": _compile(replay_admission, *admitted,
+                                     _shape(v5e, (), jnp.int32)),
+        "load": _compile(batcher._load_kinds, cache, rows,
+                         (_shape(v5e, (k * bucket // page,), jnp.int32),)),
+        "pool_bytes": sum(a.size * a.dtype.itemsize for a in pools),
+        "param_bytes": sum(a.size * a.dtype.itemsize for a in params),
+        "pool_shape": f"bf16[{n_pages},{page},{width}]",
+        "stats": batcher._stat_counters,
+    }
+
+
+@pytest.fixture(scope="module")
+def longcat_decode_program(longcat_programs):
+    return longcat_programs["decode_step"]
+
+
+@pytest.fixture(scope="module")
+def longcat_admission_program(longcat_programs):
+    return longcat_programs["admission"]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "load"])
+def test_longcat_pool_programs_update_the_pools_in_place(longcat_programs,
+                                                         program):
+    """The one-pool kind is donated and keeps its layout: every byte
+    aliased from argument to result, no `copy` of the pool's shape (a
+    row of 576 would be padded to 640 by the layout and relaid for the
+    page walk's DMAs: the pool is 640 wide to begin with)."""
+    import re
+
+    compiled = longcat_programs[program]
+    assert [line.strip()[:160] for line in compiled.as_text().splitlines()
+            if re.search(r"= " + re.escape(longcat_programs["pool_shape"])
+                         + r"\S* copy(-start)?\(", line)] == []
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        longcat_programs["pool_bytes"]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "admission",
+                                     "replay_admission"])
+def test_longcat_programs_carry_their_kernels(longcat_programs, program):
+    """A block is two latent attentions (page walks, or the flash forward
+    at 192/128) and the routed layer's two grouped matmuls: 8 custom
+    calls at 2 blocks.  And the largest admission fits the chip beside
+    the 4-block share's weights and 8 sublayers of pools."""
+    compiled = longcat_programs[program]
+    assert compiled.as_text().count("tpu_custom_call") == 8
+    mem = compiled.memory_analysis()
+    embed_head = 2 * 16384 * 6144 * 2
+    share = 2 * (longcat_programs["param_bytes"] - embed_head) + embed_head
+    assert round(share / 1e9, 2) == 10.35
+    # at 2 blocks; the 4-block programs' temporaries are in PERF.md
+    handed_back = (2 * mem.output_size_in_bytes
+                   if program == "replay_admission" else 0)
+    assert (share + 2 * longcat_programs["pool_bytes"]
+            + mem.temp_size_in_bytes + handed_back) < V5E_BYTES_LIMIT - 1e9
+    if program != "decode_step":
+        assert "f32[1,16384]" in compiled.as_text()
+    if program == "replay_admission":
+        # each layer's taps are cut before they are stacked: the replay
+        # needs the served program's temporaries and no whole context's
+        # taps beside them (they were a quarter more, and `verify` did
+        # not fit beside pools at dense parity)
+        served = longcat_programs["admission"].memory_analysis()
+        assert mem.temp_size_in_bytes < 1.06 * served.temp_size_in_bytes
+
+
+def test_longcat_decode_program_picks_and_feeds_its_own_tokens(
+        longcat_programs):
+    _assert_runs_one_step_ahead(
+        longcat_programs["decode_step"], LONGCAT_SERVE["max_slots"],
+        len(longcat_programs["stats"]), 16384)
+
+
 def _metric_pattern(name):
     import json
 
@@ -604,6 +727,21 @@ KERNEL_NAMES = [
      "_prefill_attention_pallas", 2),
     ("laguna_admission_program", "prefill_attn_roofline",
      "_prefill_attention_pallas", 2),
+    # LongCat's two blocks: two latent page walks and the routed layer's
+    # gate/up and down calls a block; the admission's flash forward at
+    # q/k 192 and v 128 keeps the kernel's name
+    ("longcat_decode_program", "mla_decode_ms", "_paged_mla", 4),
+    ("longcat_decode_program", "mla_decode_roofline", "_paged_mla", 4),
+    ("longcat_decode_program", "moe_roofline_family", "_moe_gmm_decode", 4),
+    ("longcat_decode_program", "moe_expert_decode_ms", "_moe_gmm_decode", 4),
+    ("longcat_admission_program", "mla_prefill_roofline",
+     "_prefill_attention_pallas", 4),
+    ("longcat_admission_program", "prefill_attn_ms",
+     "_prefill_attention_pallas", 4),
+    ("longcat_admission_program", "moe_roofline_family", "_moe_gmm_prefill",
+     4),
+    ("longcat_admission_program", "moe_expert_prefill_ms", "_moe_gmm_prefill",
+     4),
 ]
 
 

@@ -308,9 +308,13 @@ def test_get_breaker_is_shared_per_name():
 def _fake_lm():
     import jax.numpy as jnp
 
-    return SimpleNamespace(max_len=16, kv_heads=1, embed_dim=4,
+    # what the batcher reads of a model: sizes, and the state a layer
+    # keeps (one whole-context kind, a K and a V row of 4)
+    return SimpleNamespace(max_len=16, kv_heads=1, head_dim=4, embed_dim=4,
                            num_heads=1, num_layers=1, dtype=jnp.float32,
-                           vocab_size=8, moe_experts=0, moe_capacity=0)
+                           vocab_size=8, moe_experts=0, moe_capacity=0,
+                           cache_kinds=(("full", None),), layer_kinds=(0,),
+                           cache_rows=((4, 4),))
 
 
 @pytest.mark.chaos
