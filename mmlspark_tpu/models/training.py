@@ -289,13 +289,25 @@ def make_lm_train_step_3d(model, optimizer, plan, remat: bool = True,
     metrics)`` on a :class:`~mmlspark_tpu.parallel.mesh.MeshPlan`'s 3D
     mesh: data-parallel microbatches x megatron tensor rules x the GPipe
     schedule (`parallel.pipeline.gpipe_spmd_apply`), in ONE jitted
-    program whose collectives XLA places from shardings.
+    program whose collectives XLA places from shardings — all but one,
+    which the step's form decides: where the gradients meet over 'data'.
 
     ``tokens [A, M, mb, S]`` int32: A gradient-accumulation chunks of M
-    pipeline microbatches of mb sequences (mb sharded over 'data') —
-    global batch A*M*mb.  Accumulation is an outer `lax.scan` summing
-    grads across chunks before ONE optimizer update, so the HBM freed
-    by sharding + remat converts directly into batch size.  ``remat``
+    pipeline microbatches of mb sequences (mb sharded over 'data', so
+    divisible by it) — global batch A*M*mb.  Accumulation is an outer
+    `lax.scan` summing grads across chunks before ONE optimizer update,
+    so the HBM freed by sharding + remat converts directly into batch
+    size.  The accumulation is LOCAL to a data replica: mb splits into
+    ``[data, mb / data]``, the gradient is taken per replica (a `vmap`
+    whose mapped dim is the 'data' axis), and the accumulators carry
+    that leading ``[data]`` dim sharded over 'data' — one model shard's
+    worth of f32 a device, as replicated accumulators would be.  The
+    replicas' sums meet in ONE all-reduce after the scan, on the f32
+    accumulators.  (`value_and_grad` of replicated parameters inside
+    the scan would hand back replicated gradients, and GSPMD resolves
+    that partial sum where it arises: every layer of every chunk.
+    `parallel.mesh.collectives_by_loop` reads which of the two a
+    compiled step is.)  ``remat``
     wraps each transformer block in `jax.checkpoint` with the
     dots-saveable policy: matmul outputs are kept, everything else
     (gelu, layernorm, attention softmax) recomputes in the backward —
@@ -366,10 +378,12 @@ def make_lm_train_step_3d(model, optimizer, plan, remat: bool = True,
         return x
 
     def loss_of(p3, toks):
-        # toks [M, mb, S] -> mean next-token CE over all microbatches
+        # ONE data replica's share: toks [M, mb / data, S] -> mean
+        # next-token CE over its microbatches.  No batch axis for the
+        # pipeline buffer: the vmap below already is the `data` axis
         xs = jax.vmap(lambda t: embed_one(p3, t))(toks)
         hs = gpipe_spmd_apply(stage_fn, p3["blocks"], xs, mesh=mesh,
-                              axis="pipe", batch_axis="data")
+                              axis="pipe", batch_axis=None)
 
         def mb_loss(h, t):
             h = ln_f.apply({"params": p3["out"]["ln_f"]}, h)
@@ -379,22 +393,39 @@ def make_lm_train_step_3d(model, optimizer, plan, remat: bool = True,
 
         return jnp.mean(jax.vmap(mb_loss)(hs, toks))
 
+    n_data = mesh.shape["data"]
+    # per replica: the gradients come back with a leading [data] dim that
+    # lives on the `data` axis, so no sum over `data` exists to resolve
+    grad_of = jax.vmap(jax.value_and_grad(loss_of), in_axes=(None, 0),
+                       spmd_axis_name="data")
+
     def step(params3d, opt_state, tokens):
-        def acc(carry, toks):
+        a, m, mb, s = tokens.shape
+        # [A, M, mb, S] -> [A, data, M, mb / data, S]: replica d takes the
+        # d-th contiguous slice of every microbatch, which is the slice
+        # the tokens' sharding already put on its devices
+        toks = tokens.reshape(a, m, n_data, mb // n_data, s)
+        toks = jax.lax.with_sharding_constraint(
+            toks.transpose(0, 2, 1, 3, 4),
+            NamedSharding(mesh, P(None, "data")))
+
+        def acc(carry, chunk):
             gsum, lsum = carry
-            loss, grads = jax.value_and_grad(loss_of)(params3d, toks)
+            loss, grads = grad_of(params3d, chunk)
             return (jax.tree.map(jnp.add, gsum, grads),
                     lsum + loss), None
 
-        zeros = jax.tree.map(jnp.zeros_like, params3d)
+        zeros = jax.tree.map(
+            lambda p: jnp.zeros((n_data,) + p.shape, p.dtype), params3d)
         (gsum, lsum), _ = jax.lax.scan(
-            acc, (zeros, jnp.zeros((), jnp.float32)), tokens)
-        a = jnp.float32(tokens.shape[0])
-        grads = jax.tree.map(lambda g: g / a, gsum)
+            acc, (zeros, jnp.zeros((n_data,), jnp.float32)), toks)
+        # the step's one all-reduce over `data`: the replicas' f32 sums
+        n = jnp.float32(a * n_data)
+        grads = jax.tree.map(lambda g: g.sum(0) / n, gsum)
         updates, new_opt = optimizer.update(grads, opt_state, params3d)
         new_params = optax.apply_updates(params3d, updates)
         return new_params, new_opt, {
-            "loss": lsum / a, "grad_norm": optax.global_norm(grads)}
+            "loss": lsum.sum() / n, "grad_norm": optax.global_norm(grads)}
 
     tok_sh = NamedSharding(mesh, P(None, None, "data", None))
     jitted = core_telemetry.watch_compiles(jax.jit(

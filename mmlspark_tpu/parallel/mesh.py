@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import math
+import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -36,6 +37,7 @@ __all__ = [
     "shard_batch",
     "shard_map",
     "pad_to_multiple",
+    "collectives_by_loop",
 ]
 
 # Every axis name a mesh in this codebase may declare.  graftlint G501
@@ -255,3 +257,122 @@ def shard_batch(arr: np.ndarray, mesh: Optional[Mesh] = None) -> Tuple[jax.Array
     padded, n = pad_to_multiple(np.asarray(arr), dp, axis=0)
     out = jax.device_put(padded, batch_sharding(mesh, padded.ndim))
     return out, n
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_COLLECTIVE = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) "
+    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(-start)?\(")
+_HLO_CALLEES = re.compile(
+    r"\b(condition|body|to_apply|calls|true_computation|false_computation"
+    r"|branch_computations)=(%?[\w.\-]+|\{[^}]*\})")
+_HLO_ARRAY = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
+_HLO_GROUPS = re.compile(
+    r"(?:replica_groups|source_target_pairs)="
+    r"(\{(?:\{[\d,]*\},?)*\}|\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?)")
+
+
+def _hlo_result_bytes(result: str, kind: str, start: bool) -> int:
+    arrays = _HLO_ARRAY.findall(result)
+    if start and kind in ("all-gather", "collective-permute"):
+        # (operands, results, u32 contexts): the results are what moves
+        arrays = [a for a in arrays if a != ("u32", "")]
+        arrays = arrays[len(arrays) // 2:]
+    total = 0
+    for dtype, dims in arrays:
+        width = 8 if dtype == "pred" else int(re.sub(r"\D", "", dtype) or 0)
+        total += math.prod(int(d) for d in dims.split(",") if d) * width // 8
+    return total
+
+
+def _hlo_groups(text: str) -> List[List[int]]:
+    """Replica groups (or a permute's pairs) as lists of device ids, from
+    either spelling: `{{0,1},{2,3}}` or the iota form `[2,2]<=[2,2]T(1,0)`
+    (arange over the second shape, transposed, reshaped to the first)."""
+    if text.startswith("{"):
+        return [[int(i) for i in g.split(",") if i]
+                for g in re.findall(r"\{([\d,]*)\}", text[1:-1])]
+    shape, dims, perm = re.match(
+        r"\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?", text).groups()
+    dims = [int(d) for d in dims.split(",")]
+    ids = np.arange(math.prod(dims)).reshape(dims)
+    if perm:
+        ids = ids.transpose([int(d) for d in perm.split(",")])
+    return ids.reshape([int(d) for d in shape.split(",")]).tolist()
+
+
+def collectives_by_loop(compiled, mesh: Optional[Mesh] = None) -> List[dict]:
+    """What a compiled program sends between devices, and how often: one
+    record for every collective in the optimized HLO of `compiled` (a
+    `jax.stages.Compiled`, or its `as_text()`), in program order:
+
+        {"name", "kind", "bytes", "groups", "axes", "loops"}
+
+    `kind` is all-reduce / all-gather / reduce-scatter / all-to-all /
+    collective-permute (an async `-start` counts, its `-done` does not);
+    `bytes` is the size of the result on one device; `groups` the replica
+    groups (a permute's source-target pairs) as lists of device ids;
+    `loops` the number of `while` loops the instruction sits in (0 = once
+    a call, 1 = once an iteration of an outer scan, ...).  With the
+    program's `mesh`, `axes` names the mesh axes a group spans (ids index
+    `mesh.devices.flat`, jit's device assignment); without one it is None.
+
+    A static property of the program, read from text: the same answer on
+    the CPU test mesh and from a described-TPU compile.  It is how
+    tests/test_trainer3d.py holds `make_lm_train_step_3d` to ONE
+    `data`-axis all-reduce of the gradients a step, outside every loop."""
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    found, homes, calls, entry, here = [], [], {}, None, None
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            here = head.group(1)
+            calls[here] = []
+            if line.startswith("ENTRY"):
+                entry = here
+            continue
+        if here is None or " = " not in line:
+            continue
+        in_while = re.search(r"\bwhile\(", line) is not None
+        for role, names in _HLO_CALLEES.findall(line):
+            for name in names.strip("{}").split(", "):
+                calls[here].append((name.lstrip("%"), int(
+                    in_while and role in ("body", "condition"))))
+        op = _HLO_COLLECTIVE.match(line)
+        if not op:
+            continue
+        name, result, kind, start = op.groups()
+        groups = _HLO_GROUPS.search(line)
+        found.append({"name": name, "kind": kind,
+                      "bytes": _hlo_result_bytes(result, kind, bool(start)),
+                      "groups": _hlo_groups(groups.group(1)) if groups
+                      else []})
+        homes.append(here)
+    # a computation's depth is the deepest chain of while bodies that
+    # reaches it from the entry (fusions, calls and branches add none)
+    loops = {entry: 0}
+    todo = [entry]
+    while todo:
+        comp = todo.pop()
+        for callee, step in calls.get(comp, ()):
+            if loops.get(callee, -1) < loops[comp] + step:
+                loops[callee] = loops[comp] + step
+                todo.append(callee)
+    for rec, home in zip(found, homes):
+        rec["loops"] = loops.get(home, 0)
+        rec["axes"] = None if mesh is None else _axes_spanned(
+            rec["groups"], mesh)
+    return found
+
+
+def _axes_spanned(groups: List[List[int]], mesh: Mesh) -> Tuple[str, ...]:
+    """The mesh axes along which the members of a group differ; no groups
+    at all is HLO's spelling of one group of every device."""
+    shape = mesh.devices.shape
+    spans = set()
+    for g in groups or [list(range(math.prod(shape)))]:
+        coords = np.unravel_index(np.asarray(g, int), shape)
+        spans.update(name for name, c in zip(mesh.axis_names, coords)
+                     if len(set(c.tolist())) > 1)
+    return tuple(a for a in mesh.axis_names if a in spans)

@@ -18,7 +18,7 @@ mesh/collective design as the rest of `parallel/`.
 """
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -89,7 +89,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x: jnp.ndarray,
 
 def gpipe_spmd_apply(stage_fn: Callable, stacked_params, x: jnp.ndarray,
                      mesh: Mesh = None, axis: str = "pipe",
-                     batch_axis: str = "data") -> jnp.ndarray:
+                     batch_axis: Optional[str] = "data") -> jnp.ndarray:
     """The SAME M + P - 1 GPipe schedule as :func:`pipeline_apply`,
     lowered through GSPMD sharding annotations instead of shard_map —
     which is what lets it COMPOSE with data-parallel batch sharding and
@@ -97,7 +97,10 @@ def gpipe_spmd_apply(stage_fn: Callable, stacked_params, x: jnp.ndarray,
     arrays; tensor-parallel collectives inside them would have to be
     hand-written).
 
-    ``x [M, mb, ...]`` microbatches; ``stacked_params`` leaves carry a
+    ``x [M, mb, ...]`` microbatches, mb pinned to ``batch_axis`` at every
+    tick (None where the caller shards the batch itself: the 3D step
+    vmaps this whole schedule over 'data', and an axis may appear in a
+    spec once); ``stacked_params`` leaves carry a
     leading stage dim P (any further leading dims — e.g. the
     [P, K_blocks] layout of ``lm_params_to_3d`` — are stage-private).
     The schedule is a `lax.scan` whose donated carry is the [P, mb, ...]
@@ -123,7 +126,7 @@ def gpipe_spmd_apply(stage_fn: Callable, stacked_params, x: jnp.ndarray,
 
     def pin(buf):
         # keep the buffer stage-dim on the pipe axis and the microbatch
-        # dim data-sharded at every tick, so the roll stays a pure
+        # dim on batch_axis at every tick, so the roll stays a pure
         # neighbor hop instead of a resharding
         if mesh is None:
             return buf
