@@ -101,6 +101,14 @@ DECLARED_METRICS: Dict[str, str] = {
     "serving.moe.live_assignments": "counter",    # the same, live rows only
     "serving.moe.load_max": "counter",            # busiest held expert, live rows
     "serving.moe.zero_assignments": "counter",    # live rows' identity experts
+    # -- counters: what a trained step of a routed model did
+    # (models/training.py record_lm_stats, from models/glm_moe_lm.py's parts)
+    "training.moe.assignments": "counter",     # live (token, expert) on experts held
+    "training.moe.experts_touched": "counter", # (layer, expert) pairs with a row
+    "training.moe.load_max": "counter",        # busiest held expert, a layer a step
+    "training.moe.load_max_all": "counter",    # busiest of ALL experts, the same
+    "training.attn.pairs": "counter",          # causal (query, key) pairs, all sublayers
+    "training.mtp.tokens": "counter",          # targets of the MTP head
     # -- counters: fleet gateway event ledger (serving/fleet.py, PR 9)
     "serving.fleet.retry": "counter",
     "serving.fleet.eject": "counter",

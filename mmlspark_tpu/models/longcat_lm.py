@@ -62,7 +62,7 @@ import jax.numpy as jnp
 
 from .moe_lm import (STAT_NAMES, ZERO_STAT, _DenseMLP, _normal, _RMSNorm,
                      _SparseMLP, counters_of)
-from .transformer import _rope, _single_tpu
+from .transformer import _rope, _single_tpu, default_attn
 
 __all__ = ["LongCatLM", "TAP_HEADS"]
 
@@ -85,6 +85,11 @@ def _kv_gain(embed_dim: int, kv_rank: int) -> float:
 
 
 class _LatentAttention(nn.Module):
+    """Latent attention (MLA).  `lora_scales`: LongCat's `mla_scale_*`
+    gains on the two norms (a family without them gives False).
+    `param_dtype`: what the weights are kept in (None: `dtype`); they are
+    read in `dtype`."""
+
     heads: int
     nope: int
     rope: int
@@ -94,12 +99,16 @@ class _LatentAttention(nn.Module):
     theta: float
     eps: float
     dtype: Any
+    lora_scales: bool = True
+    param_dtype: Any = None
 
     @nn.compact
-    def __call__(self, y, cache=None, pos=None, page_table=None):
+    def __call__(self, y, cache=None, pos=None, page_table=None,
+                 train: bool = False):
         """cache None: causal attention over y [B, S, E]; returns (out
-        [B, S, E], (rows [B, S, row = `latent_row_width`],)).  Otherwise y is
-        [B, 1, E] at per-slot `pos` [B] and cache this sublayer's pool
+        [B, S, E], (rows [B, S, row = `latent_row_width`],)); `train`: by
+        the attention that carries a backward (`_expanded`).  Otherwise y
+        is [B, 1, E] at per-slot `pos` [B] and cache this sublayer's pool
         ([NP, page, row],) under `page_table` [B, MP]; returns
         (out, (pool,))."""
         b, s, e = y.shape
@@ -108,16 +117,20 @@ class _LatentAttention(nn.Module):
         decode = cache is not None
 
         def proj(name, n_in, n_out):
-            return self.param(name, _normal(n_in ** -0.5), (n_in, n_out), dt)
+            return self.param(name, _normal(n_in ** -0.5), (n_in, n_out),
+                              self.param_dtype or dt).astype(dt)
+
+        def norm(name, gain):
+            return _RMSNorm(self.eps, dt, gain if self.lora_scales else 1.0,
+                            self.param_dtype, name=name)
 
         # mla_scale_q_lora rides the query norm (it commutes with Wqb)
-        q_lat = _RMSNorm(self.eps, dt, math.sqrt(e / rq), name="q_norm")(
+        q_lat = norm("q_norm", math.sqrt(e / rq))(
             jnp.dot(y, proj("wqa", e, rq)))
         q = jnp.dot(q_lat, proj("wqb", rq, h * (dn + dr))).reshape(
             b, s, h, dn + dr)
         ckr = jnp.dot(y, proj("wkva", e, rk + dr))
-        c = _RMSNorm(self.eps, dt, _kv_gain(e, rk),
-                     name="kv_norm")(ckr[..., :rk])
+        c = norm("kv_norm", _kv_gain(e, rk))(ckr[..., :rk])
         positions = (jnp.arange(s) if not decode
                      else pos[:, None] + jnp.arange(s)[None])
         q_nope, q_rope = q[..., :dn], _rope(q[..., dn:], positions,
@@ -137,24 +150,36 @@ class _LatentAttention(nn.Module):
                                               cache, pos, page_table)
             else:
                 with jax.named_scope("mla.expand"):
-                    a = self._expanded(q_nope, q_rope, c, kr, wkvb)
+                    a = self._expanded(q_nope, q_rope, c, kr, wkvb, train)
                 cache = (rows,)
         a = a.astype(dt).reshape(b, s, h * dv)
         return jnp.dot(a, proj("wo", h * dv, e)), cache
 
-    def _expanded(self, q_nope, q_rope, c, kr, wkvb):
+    def _expanded(self, q_nope, q_rope, c, kr, wkvb, train: bool = False):
         """K and V of every head from the latent rows, then the causal
-        flash forward at q/k heads of nope + rope and v heads of v."""
-        from ..ops.attention_kernels import prefill_attention
-
+        flash forward at q/k heads of nope + rope and v heads of v.
+        `train`: through `fused_attention`, the kernels that carry a
+        backward (`default_attn`: on one TPU, the XLA composition
+        elsewhere), which take q, k and v of ONE width: a v narrower than
+        q/k (LongCat's 128 under 192) is padded with zero columns up to
+        it and the output cut back."""
         b, s, h, dn = q_nope.shape
         kv = jnp.einsum("bsr,rhd->bshd", c, wkvb)
         k = jnp.concatenate(
             [kv[..., :dn],
              jnp.broadcast_to(kr[:, :, None], (b, s, h, self.rope))], -1)
         q = jnp.concatenate([q_nope, q_rope], -1)
-        return prefill_attention(q, k, kv[..., dn:], None,
-                                 kernel=_single_tpu())
+        v = kv[..., dn:]
+        if not train:
+            from ..ops.attention_kernels import prefill_attention
+
+            return prefill_attention(q, k, v, None, kernel=_single_tpu())
+        if self.v_dim > q.shape[-1]:
+            raise NotImplementedError(
+                "training attends at one head width, q/k's: a v wider "
+                f"than it ({self.v_dim} > {q.shape[-1]}) is not built")
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - self.v_dim),))
+        return default_attn(True)(q, k, v)[..., :self.v_dim]
 
     def _absorbed(self, q_nope, q_rope, rows, wkvb, cache, pos, page_table):
         """Write this token's row into its page, then attend in the

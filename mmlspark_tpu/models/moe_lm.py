@@ -113,6 +113,11 @@ def _normal(std):
     return nn.initializers.normal(stddev=std)
 
 
+# router logits [T, X] float32 -> the scores the top-k is taken of
+_SCORES = {"softmax": lambda r: jax.nn.softmax(r, axis=-1),
+           "sigmoid": jax.nn.sigmoid}
+
+
 class _RMSNorm(nn.Module):
     """`gain`: a constant the normed row is multiplied by in float32,
     before its one rounding to the model's dtype."""
@@ -120,11 +125,12 @@ class _RMSNorm(nn.Module):
     eps: float
     dtype: Any
     gain: float = 1.0
+    param_dtype: Any = None     # what the scale is KEPT in (None: dtype)
 
     @nn.compact
     def __call__(self, x):
         w = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                       self.dtype)
+                       self.param_dtype or self.dtype)
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True)
                                 + self.eps)
@@ -136,16 +142,23 @@ class _RMSNorm(nn.Module):
 
 
 class _DenseMLP(nn.Module):
+    """`param_dtype`: what the weights are KEPT in (a trainer's float32
+    under a bfloat16 `dtype`; None: `dtype`); they are read in `dtype`."""
+
     width: int
     dtype: Any
+    param_dtype: Any = None
 
     @nn.compact
     def __call__(self, y):
         e = y.shape[-1]
-        w1 = self.param("w1", _normal(e ** -0.5), (e, self.width), self.dtype)
-        w3 = self.param("w3", _normal(e ** -0.5), (e, self.width), self.dtype)
-        w2 = self.param("w2", _normal(self.width ** -0.5), (self.width, e),
-                        self.dtype)
+        kept = self.param_dtype or self.dtype
+        w1, w3, w2 = (
+            self.param(name, _normal(shape[0] ** -0.5), shape, kept).astype(
+                self.dtype)
+            for name, shape in (("w1", (e, self.width)),
+                                ("w3", (e, self.width)),
+                                ("w2", (self.width, e))))
         h = jax.nn.silu(jnp.dot(y, w1, preferred_element_type=jnp.float32))
         h = h * jnp.dot(y, w3, preferred_element_type=jnp.float32)
         return jnp.dot(h.astype(y.dtype), w2,
@@ -162,16 +175,27 @@ def _zero_experts_term(y, weights, top_e, num_experts: int):
 
 
 class _SparseMLP(nn.Module):
-    """The routed layer of both MoE models.  A router over all
+    """The routed layer of every MoE model here.  A router over all
     `num_experts` FFN experts and then `zero_experts` identity experts,
-    softmax scores, the top-k chosen by score (plus a selection `bias`
-    that weighs nothing, if `choice_bias`), weights the chosen scores
-    times `scaling`, renormalised over the top-k if `renormalise`; the
-    FFN experts in [lo, hi) held here, the identity experts computed for
-    every token, one shared expert if `shared_width`.  `token_chunk`: a
-    call of more tokens sends them through the experts that many at a
-    time, the last chunk padded with rows that fall on no expert (0: all
-    at once)."""
+    `scores` a softmax over them or a sigmoid of each, the top-k chosen
+    by score (plus a selection `bias` that weighs nothing, if
+    `choice_bias`), weights the chosen scores times `scaling`,
+    renormalised over the top-k if `renormalise`; the FFN experts in
+    [lo, hi) held here, the identity experts computed for every token,
+    one shared expert if `shared_width`.  `token_chunk`: a call of more
+    tokens sends them through the experts that many at a time, the last
+    chunk padded with rows that fall on no expert (0: all at once).
+
+    The selection bias is a parameter (drawn by whoever makes the
+    weights), or with `bias_collection` a variable of that collection,
+    starting at 0: STATE that a controller moves between optimizer steps
+    (`models/glm_moe_lm.py`) and that no optimizer sees.  Either way no
+    gradient reaches it.  For that controller the layer sows into `load`
+    the live rows' assignments on each of ALL `num_experts +
+    zero_experts` outputs, held here or not.  `param_dtype`: what the
+    weights are kept in (None: `dtype`); the experts read them in
+    `dtype`, the router as kept.  `train`: the experts' kernel arm that
+    carries a backward."""
 
     num_experts: int
     top_k: int
@@ -184,36 +208,50 @@ class _SparseMLP(nn.Module):
     choice_bias: bool = False
     zero_experts: int = 0
     token_chunk: int = 0
+    scores: str = "softmax"                 # | "sigmoid"
+    bias_collection: Optional[str] = None
+    param_dtype: Any = None
 
     @nn.compact
-    def __call__(self, y, live=None):
+    def __call__(self, y, live=None, train: bool = False):
         """y [..., E]; live [...] bool: the rows that are somebody's
         tokens (None: all), for the load statistics only."""
         lead, e = y.shape[:-1], y.shape[-1]
         lo, hi = self.held
         n_held = hi - lo
         n_out = self.num_experts + self.zero_experts
+        kept = self.param_dtype or self.dtype
         y = y.reshape(-1, e)
-        wr = self.param("router", _normal(e ** -0.5), (e, n_out), self.dtype)
+        wr = self.param("router", _normal(e ** -0.5), (e, n_out), kept)
         w1 = self.param("w1", _normal(e ** -0.5),
-                        (n_held, e, self.width), self.dtype)
+                        (n_held, e, self.width), kept).astype(self.dtype)
         w3 = self.param("w3", _normal(e ** -0.5),
-                        (n_held, e, self.width), self.dtype)
+                        (n_held, e, self.width), kept).astype(self.dtype)
         w2 = self.param("w2", _normal(self.width ** -0.5),
-                        (n_held, self.width, e), self.dtype)
+                        (n_held, self.width, e), kept).astype(self.dtype)
         with jax.named_scope("moe.route"):
             r = _router_logits(y, wr)
-            p = jax.nn.softmax(r, axis=-1)
+            p = _SCORES[self.scores](r)
             if self.choice_bias:
-                bias = self.param("bias", nn.initializers.zeros, (n_out,),
-                                  jnp.float32)
-                _biased, top_e = jax.lax.top_k(p + bias, self.top_k)
+                if self.bias_collection:
+                    bias = self.variable(
+                        self.bias_collection, "bias",
+                        lambda: jnp.zeros((n_out,), jnp.float32)).value
+                else:
+                    bias = self.param("bias", nn.initializers.zeros,
+                                      (n_out,), jnp.float32)
+                # the biased scores choose and weigh nothing
+                _biased, top_e = jax.lax.top_k(
+                    p + jax.lax.stop_gradient(bias), self.top_k)
                 top_p = jnp.take_along_axis(p, top_e, -1)
             else:
                 top_p, top_e = jax.lax.top_k(p, self.top_k)
             weights = self.scaling * top_p
             if self.renormalise:
-                weights = weights / jnp.sum(top_p, -1, keepdims=True)
+                total = jnp.sum(top_p, -1, keepdims=True)
+                if self.scores == "sigmoid":    # scores that can all be 0
+                    total = total + 1e-20
+                weights = weights / total
         alive = (jnp.ones(top_e.shape[:1], bool) if live is None
                  else live.reshape(-1))
 
@@ -223,7 +261,7 @@ class _SparseMLP(nn.Module):
             tm = gm.row_tile(y.shape[0])
             plan = gm.dispatch(top_e.astype(jnp.int32), lo, hi, tm)
             rows = gm.expert_mlp(y, plan, w1, w3, w2, tm,
-                                 kernel=_single_tpu())
+                                 kernel=_single_tpu(), train=train)
             if live is None:
                 load = plan.counts
             else:                   # the same count over the live rows
@@ -271,7 +309,12 @@ class _SparseMLP(nn.Module):
         if self.shared_width:
             with jax.named_scope("moe.shared"):
                 out = out + _DenseMLP(self.shared_width, self.dtype,
-                                      name="shared")(y)
+                                      self.param_dtype, name="shared")(y)
+        # the controller's reading: live assignments on every output
+        chosen = (top_e[..., None] == jnp.arange(n_out)) & alive[:, None, None]
+        self.sow("load", "counts", jnp.sum(chosen, (0, 1), dtype=jnp.int32),
+                 reduce_fn=lambda a, b: a + b,
+                 init_fn=lambda: jnp.zeros((n_out,), jnp.int32))
         stats = [assigned, touched, jnp.sum(load), jnp.max(load)]
         names = STAT_NAMES
         if self.zero_experts:       # live assignments that cost nothing

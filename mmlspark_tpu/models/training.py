@@ -26,7 +26,8 @@ from ..parallel.sharding_rules import (make_shard_and_gather_fns,
                                        match_partition_rules)
 
 __all__ = ["TrainState", "make_train_step", "make_train_epoch",
-           "make_lm_train_epoch", "make_distill_epoch", "make_eval_step",
+           "make_lm_train_epoch", "record_lm_stats", "make_distill_epoch",
+           "make_eval_step",
            "make_lm_train_step_3d", "lm_params_to_3d", "lm_params_from_3d",
            "make_lm_resumable_step_3d",
            "fit_epochs", "fit_epochs_resumable", "shard_params",
@@ -205,47 +206,79 @@ def make_lm_train_epoch(
     jitted `lax.scan` — the TransformerLM counterpart of make_train_epoch
     (same reason: one dispatch per epoch keeps per-call host latency
     out of the loop; params/optimizer stay in HBM).
-    Loss is mean next-token cross-entropy in f32, PLUS 0.01x any
-    module-sown 'losses' terms (the MoE load-balance aux) — MoE loss
-    curves are not pure cross-entropy."""
+
+    The objective is the MODEL's: `model.lm_objective(variables, tokens)
+    -> (loss, parts)`.  `TransformerLM`'s is mean next-token
+    cross-entropy in f32 PLUS 0.01x any module-sown 'losses' terms (the
+    MoE load-balance aux: MoE loss curves are not pure cross-entropy)
+    and no parts; models/glm_moe_lm.py's has two loss terms, a head whose
+    logits are never whole and the step's routing statistics.  The
+    gradient and the optimizer see `variables["params"]` alone
+    (`opt_state` is `optimizer.init` of that); whatever else the model
+    keeps, the state no gradient owns, is moved after
+    `optax.apply_updates` by `model.lm_controller(variables, parts)`.
+
+    `params` is the bare parameter tree, and then what comes back is bare
+    too and `losses` the per-step loss [S]; or the model's VARIABLES
+    (`{"params": ..., ...}`), and then variables come back and `losses`
+    is a dict of per-step stacks: `loss` and every scalar part
+    (`record_lm_stats` turns the fetched ones into counters).
+    `epoch.loss_and_grads(variables, tokens [B, S]) -> ((loss, parts),
+    gradients)` is the function the scan body differentiates, for
+    whoever wants to check the timed program's own gradients."""
     mesh = mesh or default_mesh()
 
-    def lm_step(params, opt_state, toks):
-        def loss_fn(p):
-            # 'losses' collects auxiliary objectives sown by modules (the
-            # MoE load-balance term); dense models sow nothing and the
-            # sum is 0
-            (logits, _), mut = model.apply({"params": p}, toks,
-                                           mutable=["losses"])
-            # optax's integer-label form is logsumexp minus the gathered
-            # logit — unlike an explicit log_softmax it materializes no
-            # f32 [B, S, V] tensor (0.5GB at the bench config)
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :-1].astype(jnp.float32), toks[:, 1:])
-            aux = sum(jnp.sum(v) for v in
-                      jax.tree.leaves(mut.get("losses", {})))
-            return jnp.mean(ce) + 0.01 * aux
+    def loss_and_grads(variables, toks):
+        return jax.value_and_grad(
+            lambda p: model.lm_objective({**variables, "params": p}, toks),
+            has_aux=True)(variables["params"])
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+    def step(variables, opt_state, toks):
+        (loss, parts), grads = loss_and_grads(variables, toks)
+        updates, opt_state = optimizer.update(grads, opt_state,
+                                              variables["params"])
+        variables = model.lm_controller(
+            {**variables,
+             "params": optax.apply_updates(variables["params"], updates)},
+            parts)
+        scalars = {k: v for k, v in parts.items()
+                   if not isinstance(v, dict) and jnp.ndim(v) == 0}
+        return variables, opt_state, {"loss": loss, **scalars}
 
     def epoch(params, opt_state, tokens):
-        def body(carry, toks):
-            params, opt_state = carry
-            params, opt_state, loss = lm_step(params, opt_state, toks)
-            return (params, opt_state), loss
+        bare = "params" not in params
+        variables = {"params": params} if bare else params
 
-        (params, opt_state), losses = jax.lax.scan(
-            body, (params, opt_state), tokens)
-        return params, opt_state, losses
+        def body(carry, toks):
+            variables, opt_state, losses = step(*carry, toks)
+            return (variables, opt_state), losses
+
+        (variables, opt_state), losses = jax.lax.scan(
+            body, (variables, opt_state), tokens)
+        if bare:
+            return variables["params"], opt_state, losses["loss"]
+        return variables, opt_state, losses
 
     tok_sh = NamedSharding(mesh, P(None, "data"))
-    return core_telemetry.watch_compiles(jax.jit(
+    jitted = jax.jit(
         epoch,
         in_shardings=(None, None, tok_sh),
         donate_argnums=(0, 1) if donate else (),
-    ), name="training.lm_train_epoch")
+    )
+    # the watched proxy passes attribute reads through to the jitted one
+    jitted.loss_and_grads = loss_and_grads
+    return core_telemetry.watch_compiles(jitted,
+                                         name="training.lm_train_epoch")
+
+
+def record_lm_stats(model, losses) -> None:
+    """Count what an epoch of `make_lm_train_epoch` handed back into the
+    counters the model names (`model.train_counters`: (part, counter)
+    pairs).  `losses`: the epoch's third output AFTER the caller fetched
+    it (numpy: this reads no device)."""
+    for part, counter in model.train_counters:
+        if part in losses:
+            core_telemetry.incr(counter, int(np.sum(losses[part])))
 
 
 def lm_params_to_3d(params, num_layers: int, pipe: int):

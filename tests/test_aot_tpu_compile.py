@@ -683,11 +683,127 @@ def test_longcat_decode_program_picks_and_feeds_its_own_tokens(
         len(longcat_programs["stats"]), 16384)
 
 
-def _metric_pattern(name):
+# ---- glm-train-moe: the cell's own epoch program ---------------------------
+def _bench_json(*parts):
     import json
 
-    with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
-        return json.load(f)["args"]["pattern"]
+    with open(os.path.join(BENCH, *parts)) as f:
+        return json.load(f)
+
+
+GLM_LAYERS = 5 + 1        # attention sublayers: the kept layers and the MTP block
+GLM_SPARSE = 4 + 1        # routed layers among them
+
+
+@pytest.fixture(scope="module")
+def glm_epoch_program(v5e):
+    """make_lm_train_epoch over GlmMoeLM at benchmarks/configs/
+    glm-4.7-flash.json, the batch and steps of workloads/glm-train-moe.json
+    and the sequence of its traffic file: what the cell times, compiled
+    once for the tests that read it (about a minute)."""
+    import optax
+
+    from mmlspark_tpu.models.glm_moe_lm import GlmMoeLM
+    from mmlspark_tpu.models.training import make_lm_train_epoch
+
+    cfg = _bench_json("configs", "glm-4.7-flash.json")
+    cell = _bench_json("workloads", "glm-train-moe.json")["params"]
+    seq = _bench_json("traffic", "uniform-tokens-s4096.json")["seq_len"]
+    model = GlmMoeLM.from_config(cfg, seq)
+    opt = optax.adam(cell["learning_rate"])
+    variables = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    variables = jax.tree.map(
+        lambda a: _shape(v5e, a.shape, a.dtype),
+        {k: variables[k] for k in ("params", "controller")})
+    opt_state = jax.tree.map(lambda a: _shape(v5e, a.shape, a.dtype),
+                             jax.eval_shape(opt.init, variables["params"]))
+    tokens = jax.ShapeDtypeStruct(
+        (cell["steps_per_epoch"], cell["batch"], seq), jnp.int32,
+        sharding=NamedSharding(v5e, P(None, "data")))
+    return _compile(make_lm_train_epoch(model, opt, mesh=v5e), variables,
+                    opt_state, tokens)
+
+
+def test_glm_epoch_program_fits_with_its_kernels(glm_epoch_program):
+    """The cell's batch is the largest of 1, 2, 4 that fits 14.5 GB by
+    this number (`batch_found` in its workload file: 13.08 GB); every
+    attention sublayer runs the flash forward twice (a checkpointed
+    block) and the SPLIT backward, every routed layer the grouped
+    matmul's forward twice (two calls each) and its dX (two) and dW
+    (three) programs."""
+    import collections
+    import re
+
+    mem = glm_epoch_program.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < 14.5e9
+    assert mem.argument_size_in_bytes > 8.4e9      # weights and Adam state
+    names = collections.Counter(
+        re.sub(r"[.\d]+$", "", m.group(1)) for m in re.finditer(
+            r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
+            glm_epoch_program.as_text()))
+    assert names == {"_attention_pallas": 2 * GLM_LAYERS,
+                     "_attention_bwd_dkdv": GLM_LAYERS,
+                     "_attention_bwd_dq": GLM_LAYERS,
+                     "_moe_gmm_train_fwd": 4 * GLM_SPARSE,
+                     "_moe_gmm_bwd_dx": 2 * GLM_SPARSE,
+                     "_moe_gmm_bwd_dw": 3 * GLM_SPARSE}
+
+
+# what `moe_rows_ms` counts in this cell, a routed layer a step: the jitted
+# program an operation came from (the compiled text says; the trace does
+# not) and how many.  Six gathers (rows in: forward and again under the
+# checkpoint; rows out: forward; the three transposes, written as gathers),
+# the weight's multiply in `combine`'s transpose, and the pass that zeroes
+# the unused rows' gradient inside `_moe_gmm_bwd_dx`.
+GLM_ROW_OPS = {"_moe_rows_gather": 2, "_moe_rows_combine": 1,
+               "_moe_rows_combine_bwd": 3, "_moe_rows_gather_bwd": 1,
+               "_moe_gmm_bwd_dx": 1}
+
+
+def test_row_buffer_pattern_finds_the_gathers(glm_epoch_program,
+                                              bench_trace_lib):
+    """`moe_rows_ms` in this cell: XLA names the row buffers' fusions by
+    kind, so the cell's pattern goes by their shapes.  The shapes are
+    the cell's batch's, and what the pattern finds among the operations
+    a trace would show (the instructions outside fused computations) is
+    exactly `GLM_ROW_OPS`: nothing else of the step has those shapes."""
+    import collections
+    import re
+
+    tr = bench_trace_lib
+    cfg = _bench_json("configs", "glm-4.7-flash.json")
+    cell = _bench_json("workloads", "glm-train-moe.json")
+    seq = _bench_json("traffic", "uniform-tokens-s4096.json")["seq_len"]
+    over = cell["per_layer"]["moe_rows_ms"]
+    assigned = cell["params"]["batch"] * seq * cfg["num_experts_per_tok"]
+    buffer = (-(-assigned // 128) + cfg["n_routed_experts"]) * 128
+    assert over["pattern"] == (
+        rf"\[({buffer}|{assigned}),{cfg['hidden_size']}\]")
+    text = glm_epoch_program.as_text()
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
+    events, inside = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):       # a computation opens
+            name = line.split(" ")[1 if line.startswith("ENTRY") else 0]
+            inside = name.lstrip("%") in fused
+        elif " = " in line and not inside:
+            op, detail = tr.op_name(line.strip().removeprefix("ROOT "))
+            source = re.findall(r"jit\((_moe_\w+)\)", line)
+            events.append((op, 0.0, 1.0, detail, source[-1] if source
+                           else line.strip()[:120]))
+    found = {op for op, _s, _e in tr.ops(
+        [e[:4] for e in events], over["pattern"], over["exclude"])}
+    assert len(found) == sum(GLM_ROW_OPS.values()) * GLM_SPARSE
+    sources = collections.Counter(e[4] for e in events if e[0] in found)
+    assert sources == {k: n * GLM_SPARSE for k, n in GLM_ROW_OPS.items()}
+    assert _metric_pattern("moe_rows_ms") == "^_moe_rows_"
+
+
+def _metric_pattern(name):
+    return _bench_json("metrics", name + ".json")["args"]["pattern"]
 
 
 # (program fixture, metric file, kernel, custom calls the pattern must find)
@@ -742,6 +858,19 @@ KERNEL_NAMES = [
      4),
     ("longcat_admission_program", "moe_expert_prefill_ms", "_moe_gmm_prefill",
      4),
+    # the trained routed model: the flash kernels at 256-wide heads keep
+    # their names (forward twice a sublayer, the split pair), and `^_moe_gmm`
+    # finds the grouped matmul's three training programs
+    ("glm_epoch_program", "flash_attn_ms", "_attention_pallas",
+     2 * GLM_LAYERS),
+    ("glm_epoch_program", "flash_attn_ms", "_attention_bwd_dkdv", GLM_LAYERS),
+    ("glm_epoch_program", "flash_attn_roofline_family", "_attention_bwd_dq",
+     GLM_LAYERS),
+    ("glm_epoch_program", "moe_train_ms", "_moe_gmm_train_fwd",
+     4 * GLM_SPARSE),
+    ("glm_epoch_program", "moe_train_ms", "_moe_gmm_bwd_dx", 2 * GLM_SPARSE),
+    ("glm_epoch_program", "moe_train_roofline", "_moe_gmm_bwd_dw",
+     3 * GLM_SPARSE),
 ]
 
 
@@ -775,7 +904,8 @@ def test_metric_patterns_find_the_kernels(request, bench_trace_lib, program,
                                                 for n, *_ in events}))
     if metric.startswith("flash_attn"):
         # ... and the pattern misses no attention kernel of the program
-        assert len(found) == len(events)
+        assert len(found) == sum(n.startswith("_attention")
+                                 for n, *_ in events)
 
 
 def test_described_context_does_not_leak(v5e):

@@ -368,3 +368,24 @@ def test_kernel_head_dim_rule(d, want):
     """64-multiples run native (what the described-v5e compiles in
     tests/test_aot_tpu_compile.py admit); the rest pad to the lane."""
     assert ak._kernel_d(d) == want
+
+
+def test_grad_at_256_wide_heads_takes_the_split_backward(monkeypatch):
+    """The latent-attention training shape: q, k and v heads of 256 (the
+    kernels run it native, 256 x 256 tiles).  At the cell's S = 4,096 the
+    repo's own predicates give the kernel path, 2,048 keys a forward step
+    and the SPLIT backward; here the same kernels at a length the
+    interpreter can afford, the split pair forced as the long sequence
+    takes it."""
+    assert ak.attention_fits_vmem(4096, 256)
+    assert ak._pick_blocks(4096, 256, True) == (256, 256)
+    assert ak._kv_major(4096, 256, 2, 256) == 2048
+    assert not ak._fused_bwd_fits(4096, 256)
+    assert ak._kernel_d(256) == 256
+    # lm-train's shape keeps its tiles and its fused backward
+    assert ak._pick_blocks(1024, 64, True) == (512, 512)
+    assert ak._fused_bwd_fits(1024, 64)
+    q, k, v = _normal(13, (1, 512, 2, 256))
+    assert ak.kernel_ok(q)
+    monkeypatch.setattr(ak, "_fused_bwd_fits", lambda *a, **kw: False)
+    _assert_fwd_and_grads_match(q, k, v, True)

@@ -524,6 +524,59 @@ def test_rows_cap_splits_a_bucket(params):
 
 
 # ---- kernels in interpret mode against their XLA paths --------------------
+def _draws(kind, rng, rows, top_k, n_exp):
+    if kind == "all_on_one":        # expert 3 draws everything, the rest none
+        return np.full((rows, top_k), 3)
+    if kind == "none_held":         # nothing falls on the share
+        return np.zeros((rows, top_k), np.int64)
+    return np.stack([rng.permutation(n_exp)[:top_k] for _ in range(rows)])
+
+
+@pytest.mark.parametrize("kind,rows,top_k,held,dtype", [
+    ("random", 300, 2, (0, 8), jnp.float32),
+    ("random", 300, 3, (2, 6), jnp.float32),
+    ("all_on_one", 300, 1, (0, 8), jnp.float32),
+    ("none_held", 200, 2, (6, 8), jnp.float32),
+    ("random", 300, 2, (0, 8), jnp.bfloat16),
+])
+def test_grouped_matmul_backward_matches_ragged_dot_autodiff(kind, rows, top_k,
+                                                             held, dtype):
+    """`expert_mlp(train=True)`: the kernel arm's hand-written backward
+    (interpret mode) against `jax.grad` through `ragged_dot`, for the
+    rows, the three weights and the combine weights; an expert that drew
+    no row gets a zero gradient, one that drew every row all of it."""
+    rng = np.random.default_rng(8)
+    e, f, n_exp = 128, 256, 8
+    lo, hi = held
+    x = jnp.asarray(rng.normal(size=(rows, e)), dtype)
+    w1, w3 = (jnp.asarray(rng.normal(size=(hi - lo, e, f)) * e ** -0.5, dtype)
+              for _ in range(2))
+    w2 = jnp.asarray(rng.normal(size=(hi - lo, f, e)) * f ** -0.5, dtype)
+    ids = jnp.asarray(_draws(kind, rng, rows, top_k, n_exp), jnp.int32)
+    weights = jnp.asarray(rng.uniform(size=(rows, top_k)), jnp.float32)
+    probe = jnp.asarray(rng.normal(size=(rows, e)), jnp.float32)
+
+    def loss(x, w1, w3, w2, weights, kernel):
+        plan = gm.dispatch(ids, lo, hi, 128)
+        y = gm.expert_mlp(x, plan, w1, w3, w2, 128, kernel=kernel, train=True)
+        return jnp.sum(gm.combine(y, plan, weights) * probe)
+
+    grads = [jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                     static_argnums=5)(x, w1, w3, w2, weights, kernel)
+             for kernel in (True, False)]
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    for got, want, name in zip(*grads, ("x", "w1", "w3", "w2", "weights")):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.isfinite(got).all(), name
+        assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), 1.0), name
+    dw1 = np.asarray(grads[0][1], np.float32)
+    if kind == "all_on_one":
+        assert np.abs(dw1[3]).max() > 0
+        assert not np.delete(dw1, 3, 0).any()
+    if kind == "none_held":
+        assert not dw1.any() and not np.asarray(grads[0][0], np.float32).any()
+
+
 @pytest.mark.parametrize("rows,top_k,held", [(32, 2, (0, 8)), (32, 3, (2, 6)),
                                              (300, 2, (0, 4))])
 def test_grouped_matmul_kernel_matches_ragged_dot(rows, top_k, held):
