@@ -13,6 +13,9 @@ published widths, each phase compared with a plain reference:
              vs the same weights on full_attention
   lm_train   transformer_lm d768/L12 through make_lm_train_epoch (flash
              forward + backward kernels) vs full_attention
+  admit_attn the serving cells' admission flash forward (grouped heads with
+             and without a window, and q/k 192 over v 128) at ragged
+             prompt lengths vs its XLA composition
   lm_serve   the README's one-call LM endpoint (paged continuous batching
              over loopback HTTP) vs models.generation.generate
 
@@ -60,6 +63,12 @@ SIZES = {
         "vit": dict(builder="vit_base", batch=128, side=224),
         "lm": _LM_FULL,
         "lm_train": dict(batch=16, seq=1024, steps=4),
+        # (query heads, KV heads, q/k width, v width, window): Laguna's
+        # full and window layers, LongCat's expanded latent heads
+        "admit_attn": dict(bucket=2048, lengths=(2048, 1100),
+                           shapes=((48, 8, 128, 128, None),
+                                   (72, 8, 128, 128, 512),
+                                   (64, 64, 192, 128, None))),
         "lm_serve": dict(prompt_lens=(5, 12, 20, 40), copies=2, max_new=16,
                          max_slots=8, page_size=64),
         "lm3d": dict(accum=2, micro=2, mb=4, seq=1024, steps=2),
@@ -70,6 +79,10 @@ SIZES = {
         "vit": dict(builder="vit_tiny", batch=1, side=32),
         "lm": _LM_TINY,
         "lm_train": dict(batch=8, seq=32, steps=2),
+        "admit_attn": dict(bucket=32, lengths=(32, 13),
+                           shapes=((4, 2, 128, 128, None),
+                                   (6, 2, 128, 128, 8),
+                                   (2, 2, 192, 128, None))),
         "lm_serve": dict(prompt_lens=(3, 18), copies=2, max_new=3,
                          max_slots=2, page_size=8),
         "lm3d": dict(accum=1, micro=2, mb=2, seq=32, steps=2),
@@ -345,6 +358,48 @@ def phase_lm_train(size: dict, seed: int) -> dict:
     }
 
 
+def phase_admit_attn(size: dict, seed: int) -> dict:
+    """The admission flash forward at the serving cells' head shapes,
+    rows of ragged length in one bucket, against its XLA composition;
+    the bucket's padding must come back exactly zero."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mmlspark_tpu.models.transformer import _single_tpu
+    from mmlspark_tpu.ops import attention_kernels as ak
+
+    cfg = size["admit_attn"]
+    s, lengths = cfg["bucket"], jnp.asarray(cfg["lengths"], jnp.int32)
+    b = len(cfg["lengths"])
+    diffs, calls, padding_zero = [], 0, True
+    for i, (h, hkv, d, dv, window) in enumerate(cfg["shapes"]):
+        q, k, v = (jax.random.normal(kk, shape, jnp.bfloat16)
+                   for kk, shape in zip(
+                       jax.random.split(jax.random.PRNGKey(seed + i), 3),
+                       ((b, s, h, d), (b, s, hkv, d), (b, s, hkv, dv))))
+        kernel = jax.jit(lambda q, k, v, w=window: ak.prefill_attention(
+            q, k, v, w, lengths, kernel=_single_tpu()))
+        calls += _custom_calls(kernel.lower(q, k, v).compile())
+        got = np.asarray(kernel(q, k, v), np.float32)
+        ref = jax.jit(lambda q, k, v, w=window: ak._xla_prefill_attention(
+            q, k, v, w, lengths))(q, k, v)
+        diffs.append(_rel_diff(got, ref))
+        padding_zero &= all(not got[row, n:].any()
+                            for row, n in enumerate(cfg["lengths"]))
+    tol = 2e-2
+    expected = len(cfg["shapes"]) if _kernels_expected() else 0
+    return {
+        "ok": max(diffs) <= tol and padding_zero and calls == expected,
+        "compared": "prefill_attention vs _xla_prefill_attention at "
+                    "ragged lengths (max rel diff a head shape)",
+        "max_diff": max(diffs), "diffs": diffs, "tol": tol,
+        "padding_zero": bool(padding_zero),
+        "custom_calls": calls, "custom_calls_expected": expected,
+        "peak_bytes_in_use": _peak_bytes(),
+    }
+
+
 def phase_lm_serve(size: dict, seed: int) -> dict:
     import http.client
 
@@ -592,7 +647,9 @@ def phase_lm3d(size: dict, seed: int) -> dict:
 
 
 ONE_CHIP_PHASES = (("featurize", phase_featurize), ("vit", phase_vit),
-                   ("lm_train", phase_lm_train), ("lm_serve", phase_lm_serve))
+                   ("lm_train", phase_lm_train),
+                   ("admit_attn", phase_admit_attn),
+                   ("lm_serve", phase_lm_serve))
 FOUR_CHIP_PHASES = (("lm3d", phase_lm3d),)
 
 

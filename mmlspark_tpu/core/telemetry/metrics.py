@@ -84,6 +84,10 @@ DECLARED_METRICS: Dict[str, str] = {
     # -- counters: the continuous batcher's loop thread (serving/batcher.py)
     "serving.batcher.prefill.tokens": "counter",         # real prompt tokens
     "serving.batcher.prefill.padded_tokens": "counter",  # rows x bucket computed
+    # score tiles the admission flash forward visits for the prompts' own
+    # lengths / what their buckets' whole schedules hold
+    "serving.batcher.prefill.attn_tiles": "counter",
+    "serving.batcher.prefill.attn_tiles_bucket": "counter",
     "serving.batcher.live_tokens": "counter",   # K/V rows read, summed per tick
     # models with two kinds of KV state and routed experts (models/moe_lm.py)
     "serving.batcher.pages.full": "counter",      # pages in use, summed a tick

@@ -104,10 +104,12 @@ class _LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, y, cache=None, pos=None, page_table=None,
-                 train: bool = False):
+                 train: bool = False, lengths=None):
         """cache None: causal attention over y [B, S, E]; returns (out
         [B, S, E], (rows [B, S, row = `latent_row_width`],)); `train`: by
-        the attention that carries a backward (`_expanded`).  Otherwise y
+        the attention that carries a backward (`_expanded`); `lengths`
+        [B]: a row's own positions (an admission's bucket is padded past
+        them; the rest attend nothing and come back zero).  Otherwise y
         is [B, 1, E] at per-slot `pos` [B] and cache this sublayer's pool
         ([NP, page, row],) under `page_table` [B, MP]; returns
         (out, (pool,))."""
@@ -150,14 +152,17 @@ class _LatentAttention(nn.Module):
                                               cache, pos, page_table)
             else:
                 with jax.named_scope("mla.expand"):
-                    a = self._expanded(q_nope, q_rope, c, kr, wkvb, train)
+                    a = self._expanded(q_nope, q_rope, c, kr, wkvb, train,
+                                       lengths)
                 cache = (rows,)
         a = a.astype(dt).reshape(b, s, h * dv)
         return jnp.dot(a, proj("wo", h * dv, e)), cache
 
-    def _expanded(self, q_nope, q_rope, c, kr, wkvb, train: bool = False):
+    def _expanded(self, q_nope, q_rope, c, kr, wkvb, train: bool = False,
+                  lengths=None):
         """K and V of every head from the latent rows, then the causal
-        flash forward at q/k heads of nope + rope and v heads of v.
+        flash forward at q/k heads of nope + rope and v heads of v, over
+        each row's first `lengths` positions (None: all).
         `train`: through `fused_attention`, the kernels that carry a
         backward (`default_attn`: on one TPU, the XLA composition
         elsewhere), which take q, k and v of ONE width: a v narrower than
@@ -173,7 +178,8 @@ class _LatentAttention(nn.Module):
         if not train:
             from ..ops.attention_kernels import prefill_attention
 
-            return prefill_attention(q, k, v, None, kernel=_single_tpu())
+            return prefill_attention(q, k, v, None, lengths,
+                                     kernel=_single_tpu())
         if self.v_dim > q.shape[-1]:
             raise NotImplementedError(
                 "training attends at one head width, q/k's: a v wider "
@@ -227,8 +233,10 @@ class _ShortcutBlock(nn.Module):
     dtype: Any
 
     @nn.compact
-    def __call__(self, x, cache=None, pos=None, page_table=None, live=None):
-        """cache None: x [B, S, E] -> (x, the two attentions' rows).
+    def __call__(self, x, cache=None, pos=None, page_table=None, live=None,
+                 lengths=None):
+        """cache None: x [B, S, E] -> (x, the two attentions' rows);
+        `lengths` [B]: the rows' own positions, for the attentions.
         Otherwise x [B, 1, E] at `pos` over the two sublayers' pools
         `cache` -> (x, the two sublayers' pools)."""
         dt = self.dtype
@@ -240,7 +248,8 @@ class _ShortcutBlock(nn.Module):
         def attend(j, x):
             a, kept = _LatentAttention(dtype=dt, eps=self.eps,
                                        name=f"attn{j}", **self.attn)(
-                norm(f"attn_norm{j}")(x), caches[j], pos, page_table)
+                norm(f"attn_norm{j}")(x), caches[j], pos, page_table,
+                lengths=lengths)
             return x + a.astype(dt), kept
 
         x, kept0 = attend(0, x)
@@ -319,6 +328,13 @@ class LongCatLM(nn.Module):
         return (0,) * (2 * self.num_layers)
 
     @property
+    def attn_shapes(self):
+        """Per cache kind, (query heads a KV head, q/k head width, v head
+        width) of the admission's attention: the latent rows expanded to
+        a K and a V head for every query head."""
+        return ((1, self.qk_nope_dim + self.qk_rope_dim, self.v_head_dim),)
+
+    @property
     def cache_rows(self):
         """One pool a sublayer: the latent and the rotated rope key, in
         rows of whole lane tiles."""
@@ -370,7 +386,7 @@ class LongCatLM(nn.Module):
         live = jnp.arange(tokens.shape[1])[None] <= last[:, None]
         rows = []
         for i in range(self.num_layers):
-            x, kept = self._block(i)(x, live=live)
+            x, kept = self._block(i)(x, live=live, lengths=last + 1)
             rows.extend(kept)
         x_last = x[jnp.arange(x.shape[0]), last]
         return self._head(x_last), tuple(rows)

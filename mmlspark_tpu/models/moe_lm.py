@@ -351,8 +351,11 @@ class _Block(nn.Module):
         return _rotate(x, positions, inv, mscale)
 
     @nn.compact
-    def __call__(self, x, cache=None, pos=None, page_table=None, live=None):
-        """cache None: causal (windowed) attention over x [B, S, E];
+    def __call__(self, x, cache=None, pos=None, page_table=None, live=None,
+                 lengths=None):
+        """cache None: causal (windowed) attention over x [B, S, E], of
+        each row its first `lengths` [B] positions (None: all; the rest
+        is an admission bucket's padding and attends nothing);
         returns (x, (k, v) rows [B, S, Hkv*D]).  Otherwise x is [B, 1, E]
         at per-slot `pos` [B] and cache this layer's page pools
         ([NP, page, Hkv*D] each) under `page_table` [B, MP] (a ring of
@@ -380,7 +383,7 @@ class _Block(nn.Module):
             if cache is None:
                 from ..ops.attention_kernels import prefill_attention
 
-                a = prefill_attention(q, k, v, self.window,
+                a = prefill_attention(q, k, v, self.window, lengths,
                                       kernel=_single_tpu())
                 cache = (k.reshape(b, s, hkv * d), v.reshape(b, s, hkv * d))
             else:
@@ -520,6 +523,17 @@ class MoELM(nn.Module):
         """Per layer, the index into `cache_kinds`."""
         return tuple(0 if t == "full" else 1 for t in self.layer_types)
 
+    @property
+    def attn_shapes(self):
+        """Per cache kind, (query heads a KV head, q/k head width, v head
+        width) of its (first) layer's admission attention: what
+        `prefill_attention` is called at, for the batcher's count of its
+        tiles."""
+        kinds = self.layer_kinds
+        return tuple((self.layer_heads[kinds.index(kind)] // self.kv_heads,
+                      self.head_dim, self.head_dim)
+                     for kind in range(len(self.cache_kinds)))
+
     # ---- the network -----------------------------------------------------
     def _block(self, i: int):
         windowed = self.layer_types[i] == "window"
@@ -569,7 +583,7 @@ class MoELM(nn.Module):
         live = jnp.arange(tokens.shape[1])[None] <= last[:, None]
         rows = []
         for i in range(self.num_layers):
-            x, kv = self._block(i)(x, live=live)
+            x, kv = self._block(i)(x, live=live, lengths=last + 1)
             rows.append(kv)
         x_last = x[jnp.arange(x.shape[0]), last]
         return self._head(x_last), tuple(rows)

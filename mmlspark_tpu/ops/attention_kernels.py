@@ -59,6 +59,19 @@ in VMEM, on the same tiles, schedule and `_bwd_tile`.  Shapes
 There is no other fallback: a shape `kernel_ok` admits and the compiler
 refuses raises (under an outer jit, when the outer program compiles).
 
+Serving: `prefill_attention` is the admission's flash forward, the same
+plan (tiles looped in a grid step, transposed, `_scaled`, `_hide`, q's
+dtype out) for what an admission adds: grouped heads (a KV head's query
+heads packed into one step, side by side along a tile's lanes), an
+optional window, a v head narrower than q/k, and the prompt's own
+LENGTH inside its bucket (`lengths`, scalar-prefetched): the tiles
+visited are exactly those in which a row of the prompt sees a key
+(`_prefill_k_range`; `prefill_tile_counts` counts them), the bucket's
+padding costs a zero fill.  Its tiles
+are chosen for what an instance costs to COMPILE as well as for ms a
+call (`_pick_prefill_blocks`): a serving cell warms some twenty admission
+programs of an instance a layer before its first request.
+
 Off TPU the kernel runs interpret=True (tests/CI); on TPU it compiles to
 Mosaic.  tests/test_attention_kernels.py holds the parity suite,
 tests/test_aot_tpu_compile.py the compiles for a described v5e, and
@@ -73,23 +86,15 @@ import jax.numpy as jnp
 
 from .pallas_kernels import PALLAS_IMAGE_VMEM_BUDGET, _interpret, _pad_up
 
-__all__ = ["fused_attention", "attention_fits_vmem", "kernel_ok"]
+__all__ = ["fused_attention", "attention_fits_vmem", "kernel_ok",
+           "prefill_attention", "prefill_attention_ok",
+           "prefill_tile_counts"]
 
-_BLOCK_Q = 128
-_BLOCK_K = 512
 _LANE = 128
 _NEG_INF = -1e30  # finite stand-in: -inf arithmetic is fragile on Mosaic
 # what the one-kernel backward may keep resident: under the 16 MiB a v5e
 # kernel gets by default, with room for what the estimate leaves out
 _FUSED_BWD_VMEM_BUDGET = 12 * 1024 * 1024
-
-
-def _pick_block_k(s: int) -> int:
-    """Largest K block that tiles s (the prefill kernel's rule)."""
-    for blk in (_BLOCK_K, 256, 128):
-        if s >= blk and s % blk == 0:
-            return blk
-    return s  # s < 128: single block (s itself must divide by 8)
 
 
 # ---- the tile schedule -----------------------------------------------------
@@ -152,17 +157,18 @@ def causal_tiles(s: int, block_q: int, block_k: int) -> list:
     return tiles
 
 
-def _kv_major(s: int, d: int, itemsize: int, block_k: int) -> int:
+def _kv_major(s: int, d: int, itemsize: int, block_k: int, dv=None,
+              budget: int = PALLAS_IMAGE_VMEM_BUDGET // 2) -> int:
     """Keys a forward grid step holds in VMEM: the largest multiple of
-    block_k that tiles s and keeps K and V, double-buffered, inside half
-    the budget.  Up to 4,096 keys at d <= 128 in bf16, so a training
-    sequence is ONE grid step a query block and its key tiles a loop
-    inside that step; past that the major blocks stream."""
-    d_l = _pad_up(d, _LANE)
+    block_k that tiles s and keeps K and V (heads `dv` wide, d if None),
+    double-buffered, inside `budget` (the training forward's: half the
+    image budget).  Up to 4,096 keys at d <= 128 in bf16 there, so a
+    training sequence is ONE grid step a query block and its key tiles
+    a loop inside that step; past that the major blocks stream."""
+    d_l = _pad_up(d, _LANE) + _pad_up(d if dv is None else dv, _LANE)
     n = s // block_k
     for m in range(n, 0, -1):
-        if n % m == 0 and (4 * m * block_k * d_l * itemsize
-                           <= PALLAS_IMAGE_VMEM_BUDGET // 2):
+        if n % m == 0 and 2 * m * block_k * d_l * itemsize <= budget:
             return m * block_k
     return block_k
 
@@ -228,17 +234,31 @@ def _scaled(qb, scale: float):
 def _masked(sc, q0, k0, q_axis: int, causal: bool, kv_valid):
     """The causal and/or KV-padding mask on one score tile whose axis
     `q_axis` runs over queries from q0 and whose other axis over keys
-    from k0 — THE shared definition for the forward and the backward
-    kernels, so mask and _NEG_INF semantics cannot desynchronize.  Only
-    tiles the diagonal (or `kv_valid`) crosses come here.  `kv_valid`
-    (static) masks key columns >= the true sequence length when S was
-    padded up to the block grid: zero-padded K rows would otherwise
-    score 0 and steal softmax mass from every valid query."""
+    from k0 (`_hide` at the tile's own positions).  Only tiles the
+    diagonal (or `kv_valid`) crosses come here."""
     kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1 - q_axis)
+    qpos = None
+    if causal:
+        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, q_axis)
+    return _hide(sc, qpos, kpos, causal, kv_valid)
+
+
+def _hide(sc, qpos, kpos, causal: bool, kv_valid, window=None):
+    """Scores `sc` with every (query, key) pair hidden that the causal
+    rule, `kv_valid` or `window` hides — THE shared definition for the
+    forward, the backward and the admission kernels, so mask and
+    _NEG_INF semantics cannot desynchronize.  `qpos` / `kpos`: the
+    positions along the tile's two axes, of the tile's shape or thin (a
+    row and a column that broadcast).  `kv_valid` (static) masks key
+    columns >= the true sequence length when S was padded up to the
+    block grid: zero-padded K rows would otherwise score 0 and steal
+    softmax mass from every valid query.  `window` (static, causal
+    only) hides the keys at or before query - window."""
     mask = None
     if causal:
-        mask = (q0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape,
-                                              q_axis)) >= kpos
+        mask = qpos >= kpos
+        if window is not None:
+            mask = mask & (kpos > qpos - window)
     if kv_valid is not None:
         kv_mask = kpos < kv_valid
         mask = kv_mask if mask is None else (mask & kv_mask)
@@ -637,9 +657,9 @@ def _padded_len(s: int):
     masked blocks cost more than XLA dense's score traffic."""
     if s < 8:
         return None
-    if s % min(_BLOCK_Q, s) == 0 and s % 8 == 0:
+    if s % min(_LANE, s) == 0 and s % 8 == 0:
         return s                       # native fit, no padding
-    s_p = _pad_up(s, _BLOCK_Q)
+    s_p = _pad_up(s, _LANE)
     return s_p if 2 * s_p <= 3 * s else None
 
 
@@ -769,109 +789,277 @@ fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 
 # ---- prefill: causal, grouped heads, optional window (forward only) -------
+def _div(a, b: int):
+    """a // b for a >= 0 and an int b > 0, of ints or traced int32.
+    Traced it is `lax.div` and not jnp's floor division, whose sign
+    correction is a dozen operations the kernel's lowering pays for a
+    division (4 ms each, some forty an instance, an instance a layer
+    of every admission program a serving cell warms); so too `lax.min`,
+    `max`, `rem`, `select` and `clamp` in the admission kernel's index
+    arithmetic, where jnp's are jitted calls of their own."""
+    return a // b if isinstance(a, int) else jax.lax.div(a, b)
+
+
+def _prefill_k_range(r0, r_end, block_k: int, window):
+    """Key tiles of query rows [r0, r_end) (those rows of a query block
+    that are a prompt's own), causal and inside `window`: -> (a, b, c, n).
+    Tiles [a, b) are crossed by the window's trailing edge (mask), [b, c)
+    show every key to every row (no mask), [c, n) are crossed by the
+    diagonal (mask); nothing before a (behind the window for every row)
+    or from n on (above the diagonal of the last own row) is visited.
+    `_k_range` with the window and the length beside the diagonal; ints
+    or traced."""
+    ints = isinstance(r0, int) and isinstance(r_end, int)
+    hi, lo = (max, min) if ints else (jax.lax.max, jax.lax.min)
+    n = _div(r_end + block_k - 1, block_k)
+    c = lo(_div(r0 + 1, block_k), n)
+    if window is None:
+        return 0, 0, c, n
+    a = _div(hi(r0 - window + 1, 0), block_k)
+    b = lo(hi(_div(hi(r_end - window, 0) + block_k - 1, block_k), a), n)
+    return a, b, hi(c, b), n
+
+
+# VMEM of the admission kernel: what K and V of one KV head may take,
+# double-buffered (8,192 keys at q/k 192 and v 128 in bf16: the longest
+# bucket of `longcat-serve-long` stays resident), the limit the call asks
+# the compiler for (a v5e core has 128 MiB; 16 is the default) and what
+# the estimate may come to under it
+_PREFILL_KV_BUDGET = 16 * 1024 * 1024
+_PREFILL_VMEM_LIMIT = 32 * 1024 * 1024
+_PREFILL_VMEM_BUDGET = 24 * 1024 * 1024
+# a score tile's bounds, which are the kernel's set-up budget: Mosaic
+# unrolls a tile's vector work, and an instance's compile seconds follow
+# the tile's query columns first and its key rows second
+# (`_pick_prefill_blocks`)
+_PREFILL_LANES = 512
+_PREFILL_KEYS = 256
+
+
+def _pick_prefill_blocks(s: int, group: int) -> tuple:
+    """(block_q, block_k, pack) of the admission kernel's score tiles,
+    from the shape alone, by TWO measured columns: ms a call on the chip
+    and compile seconds an instance (described v5e; PERF.md section 6,
+    PR 35).  `pack` query heads of one KV head share a grid step, their
+    query blocks side by side along the tile's lanes, so a key tile is
+    read once for all of them: the group's largest divisor at 128 rows
+    a head within 512 lanes; then the largest block_q of 512, 256, 128
+    within the lanes, and key tiles of 256 rows, or of a query block's
+    where that is longer (one head to a step).
+    A tile visit costs about 0.5 us whatever its width (K and V latched
+    into the MXU), which is why wide tiles win on the chip: 48 heads on
+    8 KV heads at 128, a prompt of 3,000 in a 4,096 bucket, 1.06 ms at
+    (256, 256, 6), 1.50 at (256, 256, 3), 2.07 at (128, 256, 3), 2.84 at
+    (128, 128, 3), 5.88 on PR 33's kernel; 72 on 8 under a window of 512
+    0.87 at (128, 256, 9), 1.08 at (256, 256, 3), 1.54 at (128, 256, 3),
+    4.96 before; 64 heads of their own at q/k 192, v 128, 8,192 of
+    8,192, 14.8 at (512, 512, 1), 17.0 at (512, 256, 1), 34.6 before.
+    The same width is what an instance costs to compile: 0.55 s at
+    (256, 256, 6), 0.24 at (256, 256, 3), 0.15 at (128, 256, 3) and half
+    as much again at (128, 512, 3), 0.40 at (512, 512, 1), 0.23 at
+    (512, 256, 1), against 0.06-0.16 before.
+    So the width is bounded where PR 34 took the fastest (1,536 lanes,
+    0.9 s an instance, and was refused on `setup_s`): at most 0.2 s an
+    instance with grouped heads and 0.5 without."""
+    if s < _LANE:
+        return s, s, 1  # one tile
+    sizes = [blk for blk in (512, 256, _LANE) if s % blk == 0]
+    pack = max(p for p in range(1, group + 1)
+               if group % p == 0 and p * _LANE <= _PREFILL_LANES)
+    block_q = max(blk for blk in sizes if pack * blk <= _PREFILL_LANES)
+    block_k = max(blk for blk in sizes
+                  if blk <= max(block_q, _PREFILL_KEYS))
+    return block_q, block_k, pack
+
+
+def prefill_attention_vmem(s: int, d: int, dv: int, group: int,
+                           itemsize: int = 2) -> int:
+    """Bytes of VMEM a grid step of the admission kernel holds, by the
+    same arithmetic as `attention_fits_vmem`: K and V of `_kv_major`
+    keys and the packed heads' query and output blocks, all
+    double-buffered, the scaled queries, a tile's f32 scores and
+    probabilities with their cast, and the f32 output accumulator in
+    scratch and as the value a tile updates."""
+    block_q, block_k, pack = _pick_prefill_blocks(s, group)
+    d_l, dv_l = _pad_up(d, _LANE), _pad_up(dv, _LANE)
+    k_major = _kv_major(s, d, itemsize, block_k, dv, _PREFILL_KV_BUDGET)
+    cols = pack * block_q
+    return (2 * k_major * (d_l + dv_l) * itemsize
+            + cols * (3 * d_l + 2 * dv_l) * itemsize
+            + block_k * cols * (8 + itemsize)
+            + 2 * dv_l * cols * 4)
+
+
 @partial(jax.jit, static_argnames=("group", "window", "scale"))
-def _prefill_attention_pallas(q, k, v, group: int, window, scale: float):
+def _prefill_attention_pallas(q, k, v, lengths, group: int, window,
+                              scale: float):
     """q [B*H, S, D], k [B*Hkv, S, D], v [B*Hkv, S, Dv] (H = Hkv *
     group; query head b*H + h reads KV head (b*H + h) // group; Dv = D
-    but for latent attention's expanded heads, 192 against 128) ->
-    [B*H, S, Dv] f32.
+    but for latent attention's expanded heads, 192 against 128),
+    lengths [B] int32 -> [B*H, S, Dv] at q's dtype.
 
-    The flash forward again, for serving's admission prefill: causal,
-    and with `window` (static) only keys in (query - window, query].  The
-    K axis of the grid is RELATIVE: step j of query block i reads key
-    block first(i) + j, first(i) the block of the oldest key the window
-    still shows to the block's first query, so a window layer visits
-    (window + block_q) / block_k + 1 key blocks a query block whatever
-    the prompt's length, and a full layer all of them (those above the
-    diagonal skipped, copy and compute)."""
+    The flash forward again, for serving's admission prefill, on the
+    training forward's plan (`_attention_pallas`): causal, with `window`
+    (static) only keys in (query - window, query], and of each batch
+    row only its first `lengths[b]` positions: rows from there on are
+    the bucket's padding and come back zero.  grid (B*H / pack, S /
+    block_q, S / k_major): a step holds `pack` heads' query blocks and
+    `k_major` keys (`_kv_major`: all of them wherever a cell's bucket
+    goes, copied once a KV head because the block index does not move)
+    and LOOPS over their key tiles: first those the window's trailing
+    edge crosses (mask), then those wholly visible (no mask), then those
+    the diagonal crosses (`_prefill_k_range`).  The running maximum, sum
+    and output live in VMEM scratch and the loops carry nothing: a
+    carried [Dv, lanes] f32 accumulator was a fifth to two fifths of an
+    instance's compile seconds, and major blocks that stream find the
+    state where the last one left it.  A tile behind the window, above
+    the diagonal or past the prompt is neither started nor copied, and
+    a query block past the prompt writes zeros and reads nothing.
+    `lengths` rides in SMEM (scalar prefetch), so the index maps see it
+    too."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = q.shape
     dv = v.shape[-1]
-    block_q = min(_BLOCK_Q, s)
-    block_k = min(256, s) if window is not None else _pick_block_k(s)
-    n_kb = s // block_k
-    n_rel = n_kb
-    if window is not None:
-        n_rel = min(n_kb, (window + block_q - 2) // block_k + 2)
+    block_q, block_k, pack = _pick_prefill_blocks(s, group)
+    k_major = _kv_major(s, d, q.dtype.itemsize, block_k, dv,
+                        _PREFILL_KV_BUDGET)
+    n_q, n_major, n_sub = s // block_q, s // k_major, k_major // block_k
+    steps_a_row = bh // lengths.shape[0] // pack   # grid rows a batch row
+    cols = pack * block_q
 
-    def first(qi):
-        if window is None:
-            return 0
-        return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+    def q_turn(b, i, len_ref):
+        """(the prompt's length, the query block grid step i takes): the
+        blocks past the prompt FIRST, then its own in rising order, so
+        that a head's last step is its longest, and the copy of the next
+        head's K and V, which starts with that step, hides behind it."""
+        length = len_ref[_div(b, steps_a_row)]
+        n_own = jax.lax.min(_div(length + block_q - 1, block_q), n_q)
+        return length, jax.lax.select(i < n_q - n_own, i + n_own,
+                                      i - (n_q - n_own))
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, o_acc, m_acc, l_acc):
-        qi = pl.program_id(1)
-        j = pl.program_id(2)
-        kb_i = first(qi) + j
+    def kernel(len_ref, q_ref, k_ref, v_ref, o_ref, o_acc, m_acc, l_acc):
+        kj = pl.program_id(2)
+        length, qi = q_turn(pl.program_id(0), pl.program_id(1), len_ref)
+        r0 = qi * block_q
 
-        @pl.when(j == 0)
-        def _init():
-            o_acc[...] = jnp.zeros_like(o_acc)
-            m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
-            l_acc[...] = jnp.zeros_like(l_acc)
+        @pl.when(r0 < length)
+        def _own():
+            bounds = _prefill_k_range(r0, jax.lax.min(r0 + block_q, length),
+                                      block_k, window)
+            if n_major > 1:           # this major block's share of them
+                bounds = tuple(jax.lax.clamp(0, x - kj * n_sub, n_sub)
+                               for x in bounds)
+            t_a, t_b, t_c, t_n = bounds
+            qs = _scaled(q_ref[0].reshape(cols, d), scale)
+            # positions, thin: the packed heads' rows lie side by side
+            # along the lanes (column n is query r0 + n % block_q), keys
+            # run down the sublanes
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+            qpos = r0 + (jax.lax.rem(col, block_q) if pack > 1 else col)
+            k_iota = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
 
-        @pl.when(kb_i * block_k <= qi * block_q + block_q - 1)
-        def _update():
-            qb, kb, vb = q_ref[0], k_ref[0], v_ref[0]
-            sc = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, sc.shape, 0)
-            cols = kb_i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, sc.shape, 1)
-            seen = rows >= cols
-            if window is not None:
-                seen = seen & (cols > rows - window)
-            sc = jnp.where(seen, sc, _NEG_INF)
-            m_prev = jnp.max(m_acc[...], axis=-1, keepdims=True)
-            l_prev = jnp.max(l_acc[...], axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-            corr = jnp.exp(m_prev - m_new)
-            # a row whose every key of this block is masked keeps m at the
-            # stand-in: its p must be 0, not exp(0)
-            p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
-            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-            o_acc[...] = o_acc[...] * corr + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_acc[...] = jnp.broadcast_to(m_new, m_acc.shape)
-            l_acc[...] = jnp.broadcast_to(l_new, l_acc.shape)
+            @pl.when(kj == 0)
+            def _init():
+                o_acc[...] = jnp.zeros_like(o_acc)
+                m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
+                l_acc[...] = jnp.zeros_like(l_acc)
 
-        @pl.when(j == n_rel - 1)
-        def _finish():
-            l_fin = jnp.max(l_acc[...], axis=-1, keepdims=True)
-            o_ref[0] = o_acc[...] / jnp.maximum(l_fin, 1e-20)
+            def tile(t, masked: bool):
+                c0 = pl.multiple_of(t * block_k, block_k)
+                kb = k_ref[0, pl.ds(c0, block_k), :]
+                vb = v_ref[0, pl.ds(c0, block_k), :]
+                s_t = _dot(kb, qs, _NT)                # [bk, N] f32
+                if masked:
+                    s_t = _hide(s_t, qpos, kj * k_major + c0 + k_iota, True,
+                                None, window)
+                m_prev = m_acc[...]                    # [1, N]
+                m_new = jax.lax.max(m_prev,
+                                    jnp.max(s_t, axis=0, keepdims=True))
+                corr = jax.lax.exp(m_prev - m_new)
+                # a query a tile hides wholly (behind the window's edge)
+                # has p = exp(0) there: the first tile that shows it a
+                # key, and its own position always does, multiplies that
+                # away (corr 0)
+                p_t = jax.lax.exp(s_t - m_new)
+                m_acc[...] = m_new
+                l_acc[...] = l_acc[...] * corr + jnp.sum(p_t, axis=0,
+                                                         keepdims=True)
+                o_acc[...] = o_acc[...] * corr + _dot(
+                    vb, p_t.astype(vb.dtype), _TN)     # [Dv, N]
 
-    def kv_block(b, i, j):
-        # blocks above the diagonal park on the diagonal's: no new copy
-        diag = (i * block_q + block_q - 1) // block_k
-        return (b // group, jnp.minimum(first(i) + j, diag), 0)
+            # the masked tiles in ONE loop (each copy of the body is a
+            # fifth of an instance's compile): the window's edge's, then,
+            # past the unmasked ones, the diagonal's
+            _loop(t_b, t_c, lambda t, _: tile(t, False))
+            _loop(t_a, t_n - (t_c - t_b), lambda u, _: tile(
+                jax.lax.select(u < t_b, u, u + (t_c - t_b)), True))
 
-    return pl.pallas_call(
+            @pl.when(kj == n_major - 1)
+            def _finish():
+                # rows of the block past the prompt attended the
+                # padding: zero
+                l_fin = l_acc[...]
+                inv = jax.lax.select(qpos < length, 1.0 / l_fin,
+                                     jnp.zeros_like(l_fin))
+                out = (o_acc[...] * inv).T.astype(o_ref.dtype)  # [N, Dv]
+                for h in range(pack):
+                    o_ref[0, h] = out[h * block_q:(h + 1) * block_q]
+
+        @pl.when((r0 >= length) & (kj == n_major - 1))
+        def _padding():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    def q_block(b, i, j, len_ref):
+        # blocks past the prompt park on its first one: no copy
+        length, qi = q_turn(b, i, len_ref)
+        return (b, 0, jax.lax.select(qi * block_q < length, qi,
+                                     jnp.zeros_like(qi)), 0)
+
+    def kv_block(b, i, j, len_ref):
+        if n_major > 1:
+            # major blocks behind the window, above the diagonal or past
+            # the prompt park on the nearest one that shows something
+            length, qi = q_turn(b, i, len_ref)
+            r0 = qi * block_q
+            r_end = jax.lax.min(r0 + block_q, length)
+            first = 0 if window is None else _div(
+                jax.lax.max(r0 - window + 1, 0), k_major)
+            j = jax.lax.clamp(first, j,
+                              _div(jax.lax.max(r_end - 1, 0), k_major))
+        return (_div(b * pack, group), j, 0)
+
+    o = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((bh, s, dv), jnp.float32),
-        grid=(bh, s // block_q, n_rel),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_block),
-            pl.BlockSpec((1, block_k, dv), kv_block),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((bh // pack, pack, s, dv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh // pack, n_q, n_major),
+            in_specs=[
+                pl.BlockSpec((1, pack, block_q, d), q_block),
+                pl.BlockSpec((1, k_major, d), kv_block),
+                pl.BlockSpec((1, k_major, dv), kv_block),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, pack, block_q, dv),
+                lambda b, i, j, len_ref: (b, 0, q_turn(b, i, len_ref)[1],
+                                          0)),
+            scratch_shapes=[
+                pltpu.VMEM((dv, cols), jnp.float32),
+                pltpu.VMEM((1, cols), jnp.float32),
+                pltpu.VMEM((1, cols), jnp.float32),
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PREFILL_VMEM_LIMIT),
         interpret=_interpret(),
-    )(q, k, v)
+    )(lengths, q.reshape(bh // pack, pack, s, d), k, v)
+    return o.reshape(bh, s, dv)
 
 
-def _xla_prefill_attention(q, k, v, window):
+def _xla_prefill_attention(q, k, v, window, lengths=None):
     """Dense composition of the same: [B, S, H|Hkv, D|Dv] -> [B, S, H,
-    Dv] f32."""
+    Dv] f32, rows from `lengths` [B] on zero."""
     b, s, h, d = q.shape
     group = h // k.shape[2]
     k = jnp.repeat(k, group, axis=2)
@@ -884,31 +1072,76 @@ def _xla_prefill_attention(q, k, v, window):
     if window is not None:
         seen = seen & (cols > rows - window)
     p = jax.nn.softmax(jnp.where(seen[None, None], sc, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    if lengths is None:
+        return o
+    own = jnp.arange(s)[None] < lengths[:, None]
+    return jnp.where(own[:, :, None, None], o, 0.0)
+
+
+def _prefill_shape_ok(s: int, group: int, d: int, dv: int,
+                      itemsize: int = 2) -> bool:
+    """A q/k head of whole or one and a half lane tiles (128, 192, 256:
+    the contraction compiles at the 64-minor tile), a lane-wide v head,
+    a sequence the blocks tile, and a grid step within the VMEM asked."""
+    return (d >= _LANE and d % 64 == 0 and dv % _LANE == 0 and s % 8 == 0
+            and (s <= _LANE or s % _LANE == 0)
+            and prefill_attention_vmem(s, d, dv, group, itemsize)
+            <= _PREFILL_VMEM_BUDGET)
 
 
 def prefill_attention_ok(q, v=None) -> bool:
-    """q [B, S, H, D], v [B, S, Hkv, Dv] (None: Dv = D): a q/k head of
-    whole or one and a half lane tiles (128, 192, 256: the contraction
-    compiles at the 64-minor tile), a lane-wide v head, and a sequence
-    the blocks tile."""
-    _b, s, _h, d = q.shape
-    dv = d if v is None else v.shape[-1]
-    return (d >= _LANE and d % 64 == 0 and dv % _LANE == 0 and s % 8 == 0
-            and (s <= _BLOCK_Q or s % 256 == 0))
+    """q [B, S, H, D], v [B, S, Hkv, Dv] (None: Dv = D): whether the
+    admission kernel takes the shape (`_prefill_shape_ok`)."""
+    _b, s, h, d = q.shape
+    dv, hkv = (d, h) if v is None else (v.shape[-1], v.shape[2])
+    return _prefill_shape_ok(s, h // hkv, d, dv, q.dtype.itemsize)
 
 
-def prefill_attention(q, k, v, window=None, kernel: bool = True):
+def prefill_tile_counts(s: int, length: int, window, group: int, d: int,
+                        dv: int, itemsize: int = 2) -> tuple:
+    """(own, bucket): the score tiles the admission kernel visits, a
+    grid row (the query heads packed into one step), for a row of
+    `length` own positions in a bucket of s, and for a row that fills
+    the bucket: the key tiles of `_prefill_k_range` a query block, as
+    the kernel loops over them.
+    (0, 0) at a shape the kernel declines (`_prefill_shape_ok`), where
+    the XLA composition runs and visits no tile.  What the batcher adds
+    up a request."""
+    if not _prefill_shape_ok(s, group, d, dv, itemsize):
+        return 0, 0
+    block_q, block_k, _pack = _pick_prefill_blocks(s, group)
+
+    def tiles(length):
+        count = 0
+        for r0 in range(0, min(length, s), block_q):
+            a, _b, _c, n = _prefill_k_range(r0, min(r0 + block_q, length),
+                                            block_k, window)
+            count += n - a
+        return count
+
+    return tiles(length), tiles(s)
+
+
+def prefill_attention(q, k, v, window=None, lengths=None,
+                      kernel: bool = True):
     """Causal attention of a whole prompt with grouped heads and an
     optional window: q [B, S, H, D], k [B, S, Hkv, D], v [B, S, Hkv, Dv]
-    -> [B, S, H, Dv] f32.  `kernel` False (or a shape the kernel
-    declines) takes the XLA composition."""
+    -> [B, S, H, Dv] at q's dtype.  `lengths` [B] int32: the count of a
+    row's own positions in the bucket (None: all S); the rows from there
+    on come back zero, on both arms, and cost the kernel nothing.
+    `kernel` False (or a shape the kernel declines) takes the XLA
+    composition."""
     b, s, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[-1]
     if not (kernel and prefill_attention_ok(q, v)):
-        return _xla_prefill_attention(q, k, v, window)
+        return _xla_prefill_attention(q, k, v, window,
+                                      lengths).astype(q.dtype)
+    if lengths is None:
+        lengths = jnp.full((b,), s, jnp.int32)
     o = _prefill_attention_pallas(
-        _to_bhsd(q, d), _to_bhsd(k, d), _to_bhsd(v, dv), group=h // hkv,
-        window=window, scale=1.0 / float(d) ** 0.5)
+        _to_bhsd(q, d), _to_bhsd(k, d), _to_bhsd(v, dv),
+        lengths.astype(jnp.int32), group=h // hkv, window=window,
+        scale=1.0 / float(d) ** 0.5)
     return _from_bhsd(o, b, s, h, dv)

@@ -78,8 +78,11 @@ that has fallen wholly behind the window is the one the next page
 overwrites — recycled while the request runs, never more than the ring
 held.  Such a model also brings its own admission forward (`prefill`:
 the last position's logits only, cache rows of the bucket's length, at
-most `max_len` prompt tokens a program) and may name statistics
-(`stat_counters`), which ride back with the tick's token fetch.
+most `max_len` prompt tokens a program), names per kind the head shape
+its admission attends at (`attn_shapes`: query heads a KV head, q/k and
+v head widths, for the count of the flash forward's tiles) and may name
+statistics (`stat_counters`), which ride back with the tick's token
+fetch.
 `TransformerLM` is the case of one kind, "full".
 Each arm below asks for the one thing it needs: `_own_prefill` (the
 model's admission forward; its programs hand back the model's
@@ -105,6 +108,7 @@ import numpy as np
 
 from ..core import telemetry
 from ..core.flow import AdmissionStage, FlowGraph, Stage
+from ..ops.pallas_kernels import on_single_tpu
 from ..utils.sync import make_rlock
 
 __all__ = ["ContinuousBatcher", "PrefillStage", "TokenStream"]
@@ -400,6 +404,10 @@ class ContinuousBatcher:
         # row width of every pool a layer of the kind keeps
         kinds = model.cache_kinds
         self._layer_kinds = tuple(model.layer_kinds)
+        # per kind, the head shape of the admission's flash forward, for
+        # the count of its tiles: where that kernel runs (one TPU)
+        self._attn_shapes = (tuple(model.attn_shapes) if self._own_prefill
+                             and on_single_tpu() else ())
         # the whole-context kind's counters, by its name
         self._count = {what: f"serving.batcher.{what}.{kinds[0][0]}"
                        for what in ("pages", "attended", "prefill.attended")}
@@ -1245,6 +1253,7 @@ class ContinuousBatcher:
                 pairs = n * (n + 1) // 2
                 telemetry.incr(self._count["attended"], pairs)
                 telemetry.incr(self._count["prefill.attended"], pairs)
+                self._note_attn_tiles(n, padded.shape[1])
                 if win is not None:
                     for lp, pg in win.admit(slot, n):
                         ids[1][i, lp] = pg
@@ -1259,6 +1268,24 @@ class ContinuousBatcher:
                 self.variables, d_padded, d_last, d_slots, self._adm)
             self._cache = self._load_kinds(self._cache, rows, tuple(d_ids))
         return out
+
+    def _note_attn_tiles(self, n: int, bucket: int) -> None:
+        """Score tiles the admission flash forward visits for a prompt
+        of n tokens, one layer of each kind, beside what the bucket's
+        whole causal (windowed) schedule holds: their ratio is the share
+        of the bucket's attention work the prompts needed.  Nothing
+        where the kernel does not run (off one TPU, or a bucket or head
+        shape it declines: `prefill_tile_counts`)."""
+        from ..ops.attention_kernels import prefill_tile_counts
+
+        for (_name, window), shape in zip(self.model.cache_kinds,
+                                          self._attn_shapes):
+            own, whole = prefill_tile_counts(
+                bucket, n, window, *shape,
+                itemsize=jnp.dtype(self.model.dtype).itemsize)
+            telemetry.incr("serving.batcher.prefill.attn_tiles", own)
+            telemetry.incr("serving.batcher.prefill.attn_tiles_bucket",
+                           whole)
 
     def _rows_cap(self, bucket: int) -> int:
         """Most rows of one admission program of this bucket under

@@ -165,6 +165,74 @@ def test_serving_prefill_attention_compiles(v5e, bucket):
                                 0.125, None))
 
 
+# (query heads, KV heads, q/k width, v width, window, the admission's token
+# cap) of the two serving cells' attention layers, and every bucket each
+# admits: a power of two, rows x bucket under the cap
+ADMISSION_ATTN = {
+    "laguna_full": (48, 8, 128, 128, None, 4096),
+    "laguna_window": (72, 8, 128, 128, 512, 4096),
+    "longcat": (64, 64, 192, 128, None, 8192),
+}
+ADMISSION_BUCKETS = [(kind, bucket) for kind, lo in
+                     (("laguna_full", 128), ("laguna_window", 128),
+                      ("longcat", 256))
+                     for bucket in (128, 256, 512, 1024, 2048, 4096, 8192)
+                     if lo <= bucket <= ADMISSION_ATTN[kind][5]]
+
+
+@pytest.mark.parametrize("kind,bucket", ADMISSION_BUCKETS,
+                         ids=[f"{k}-{b}" for k, b in ADMISSION_BUCKETS])
+def test_admission_prefill_attention_compiles(v5e, kind, bucket):
+    """The admission flash forward of `laguna-serve-mixed` (48 and 72
+    query heads on 8 KV heads at 128, full and window 512) and
+    `longcat-serve-long` (64 heads at q/k 192, v 128) at every bucket
+    the cells admit, the smallest and the largest among them, and the
+    most rows a program of it holds, with the prompts' lengths: what
+    `prefill_attention_ok` admits by its VMEM estimate compiles under
+    the limit the call asks for, K and V of a whole bucket resident, as
+    ONE custom call under the name the metrics `prefill_attn_ms`,
+    `prefill_attn_roofline` and `mla_prefill_roofline` find it by."""
+    h, hkv, d, dv, window, cap = ADMISSION_ATTN[kind]
+    rows = cap // bucket
+    q = _shape(v5e, (rows, bucket, h, d), jnp.bfloat16)
+    v = _shape(v5e, (rows, bucket, hkv, dv), jnp.bfloat16)
+    assert ak.prefill_attention_ok(q, v)
+    assert ak.prefill_attention_vmem(bucket, d, dv, h // hkv) \
+        <= ak._PREFILL_VMEM_BUDGET < ak._PREFILL_VMEM_LIMIT
+    block_k = ak._pick_prefill_blocks(bucket, h // hkv)[1]
+    assert ak._kv_major(bucket, d, 2, block_k, dv,
+                        ak._PREFILL_KV_BUDGET) == bucket
+    compiled = _compile(
+        ak._prefill_attention_pallas,
+        _shape(v5e, (rows * h, bucket, d), jnp.bfloat16),
+        _shape(v5e, (rows * hkv, bucket, d), jnp.bfloat16),
+        _shape(v5e, (rows * hkv, bucket, dv), jnp.bfloat16),
+        _shape(v5e, (rows,), jnp.int32), group=h // hkv, window=window,
+        scale=1.0 / float(d) ** 0.5)
+    _assert_one_kernel(compiled)
+    assert [line.split("=")[0].strip().lstrip("%").split(".")[0]
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line] == \
+        ["_prefill_attention_pallas"]
+
+
+def test_admission_attention_shapes_are_the_cells():
+    """`ADMISSION_ATTN` against the configurations the cells serve."""
+    laguna = _bench_json("configs", "laguna-s-2.1.json")
+    heads = laguna["num_attention_heads_per_layer"]
+    assert ADMISSION_ATTN["laguna_full"][:5] == (
+        heads[0], laguna["num_key_value_heads"], laguna["head_dim"],
+        laguna["head_dim"], None)
+    assert ADMISSION_ATTN["laguna_window"][:5] == (
+        heads[1], laguna["num_key_value_heads"], laguna["head_dim"],
+        laguna["head_dim"], laguna["sliding_window"])
+    longcat = _bench_json("configs", "longcat-flash-chat.json")
+    assert ADMISSION_ATTN["longcat"][:5] == (
+        longcat["num_attention_heads"], longcat["num_attention_heads"],
+        longcat["qk_nope_head_dim"] + longcat["qk_rope_head_dim"],
+        longcat["v_head_dim"], None)
+
+
 # ---- fused resize + normalize (featurize) ---------------------------------
 _MEAN, _STD = (103.53, 116.28, 123.675), (57.375, 57.12, 58.395)
 _RESIZED = [hw for hw in FEAT["sizes"] if hw != (FEAT["side"], FEAT["side"])]

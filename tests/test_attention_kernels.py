@@ -389,3 +389,339 @@ def test_grad_at_256_wide_heads_takes_the_split_backward(monkeypatch):
     assert ak.kernel_ok(q)
     monkeypatch.setattr(ak, "_fused_bwd_fits", lambda *a, **kw: False)
     _assert_fwd_and_grads_match(q, k, v, True)
+
+
+# ---- what the training kernels share with the admission kernel -------------
+def _masked_before(sc, q0, k0, q_axis, causal, kv_valid):
+    """`_masked` as the training kernels had it before the admission
+    kernel shared its definition (PR 33's tree), word for word."""
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1 - q_axis)
+    mask = None
+    if causal:
+        mask = (q0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape,
+                                              q_axis)) >= kpos
+    if kv_valid is not None:
+        kv_mask = kpos < kv_valid
+        mask = kv_mask if mask is None else (mask & kv_mask)
+    return jnp.where(mask, sc, ak._NEG_INF)
+
+
+@pytest.mark.parametrize("q_axis", [0, 1])
+@pytest.mark.parametrize("causal,kv_valid", [(True, None), (True, 200),
+                                             (False, 200)])
+def test_training_mask_traces_as_before(q_axis, causal, kv_valid):
+    """`_hide` took a window and thin positions for the admission
+    kernel; what the training kernels trace through `_masked` is the
+    same operations in the same order as before (one jaxpr, so one
+    Mosaic module: `lm-train` and `glm-train-moe` run the device code
+    they ran), on a forward tile (queries down) and a transposed one."""
+    sc = jax.ShapeDtypeStruct((256, 512), jnp.float32)
+    at = jax.ShapeDtypeStruct((), jnp.int32)
+    now, before = (str(jax.make_jaxpr(
+        lambda s, q0, k0: fn(s, q0, k0, q_axis, causal, kv_valid))(
+            sc, at, at)) for fn in (ak._masked, _masked_before))
+    assert now == before
+
+
+@pytest.mark.parametrize("s,d,itemsize,block_k", [
+    (1024, 64, 2, 512), (4096, 256, 2, 256), (4096, 128, 2, 512),
+    (8192, 64, 2, 512), (8192, 128, 4, 512), (2048, 256, 4, 256),
+    (640, 64, 2, 128)])
+def test_training_major_block_is_as_before(s, d, itemsize, block_k):
+    """`_kv_major` took a v width and a budget for the admission
+    kernel; with neither given it is the training forward's rule: K and
+    V of one width, double-buffered, in half the image budget."""
+    d_l = ak._pad_up(d, 128)
+    n = s // block_k
+    before = next((m * block_k for m in range(n, 0, -1) if n % m == 0
+                   and 4 * m * block_k * d_l * itemsize
+                   <= ak.PALLAS_IMAGE_VMEM_BUDGET // 2), block_k)
+    assert ak._kv_major(s, d, itemsize, block_k) == before
+
+
+# ---- the admission flash forward (serving's prefill) -----------------------
+def _prefill_qkv(seed, b, s, hkv, group, d, dv, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=shape), dtype) for shape in
+                 ((b, s, hkv * group, d), (b, s, hkv, d), (b, s, hkv, dv)))
+
+
+def _prefill_lengths(kind, s, group):
+    """The lengths of a batch by name; block edges are the picked
+    tile's."""
+    edge = ak._pick_prefill_blocks(s, group)[0]
+    return {"none": None, "full": [s, s], "ragged": [s, s // 2 + 3, 1, 0],
+            "one": [1, 1], "edge": [min(edge, s), min(2 * edge, s)],
+            "edge+1": [min(edge + 1, s), min(2 * edge + 1, s)]}[kind]
+
+
+def _assert_prefill_matches(q, k, v, window, lengths, tol=F32_TOL):
+    lens = None if lengths is None else jnp.asarray(lengths, jnp.int32)
+    assert ak.prefill_attention_ok(q, v)
+    got = np.asarray(ak.prefill_attention(q, k, v, window, lens),
+                     np.float32)
+    want = np.asarray(ak._xla_prefill_attention(q, k, v, window, lens))
+    np.testing.assert_allclose(got, want, **tol)
+    for row, n in enumerate(lengths or []):
+        assert not got[row, n:].any()          # the padding: exactly zero
+
+
+_PREFILL_S = [128, 256, 1024, 2048]
+_PREFILL_LENGTHS = ["none", "full", "ragged", "one", "edge", "edge+1"]
+# every s with every kind of lengths; head widths, group and window taken
+# in turn so that each pair of them meets
+_PREFILL_CASES = [
+    (s, [(128, 128), (192, 128)][(i + j) % 2], [1, 6, 9][(i + j // 2) % 3],
+     [None, 512, 4096][(2 * i + j) % 3], kind)
+    for i, s in enumerate(_PREFILL_S)
+    for j, kind in enumerate(_PREFILL_LENGTHS)]
+
+
+@pytest.mark.parametrize(
+    "s,dd,group,window,kind", _PREFILL_CASES,
+    ids=[f"s{s}-d{dd[0]}-g{g}-w{w}-{kind}"
+         for s, dd, g, w, kind in _PREFILL_CASES])
+def test_prefill_kernel_matches_its_composition(s, dd, group, window, kind):
+    """The admission kernel against `_xla_prefill_attention` over the
+    buckets' lengths, both head shapes, grouped heads packed into a
+    step, no window, a window inside the bucket and one past it, and
+    the prompt's own length: whole, ragged in one batch (a row of 0
+    among them), 1, on a block's edge and one past it."""
+    lengths = _prefill_lengths(kind, s, group)
+    b = 2 if lengths is None else len(lengths)
+    q, k, v = _prefill_qkv(s + group, b, s, 1, group, *dd)
+    _assert_prefill_matches(q, k, v, window, lengths)
+
+
+@pytest.mark.parametrize("window", [None, 300])
+@pytest.mark.parametrize("blocks", [(128, 128, 3), (256, 128, 1),
+                                    (128, 256, 3)])
+def test_prefill_kernel_streams_major_blocks(monkeypatch, blocks, window):
+    """Past what stays resident the keys stream in major blocks, the
+    statistics carried in scratch: 256 keys a step here, unequal tiles,
+    and the index maps park where a step shows nothing."""
+    monkeypatch.setattr(ak, "_pick_prefill_blocks", lambda *a: blocks)
+    monkeypatch.setattr(ak, "_kv_major", lambda *a, **kw: 256)
+    ak._prefill_attention_pallas.clear_cache()
+    try:
+        q, k, v = _prefill_qkv(5, 3, 1024, 2, 3, 128, 128)
+        _assert_prefill_matches(q, k, v, window, [1000, 257, 1])
+    finally:
+        ak._prefill_attention_pallas.clear_cache()
+
+
+def test_prefill_kernel_at_bf16():
+    """bf16 in, bf16 out, on both arms."""
+    q, k, v = _prefill_qkv(3, 2, 512, 1, 2, 192, 128, jnp.bfloat16)
+    lens = jnp.asarray([512, 200], jnp.int32)
+    got = ak.prefill_attention(q, k, v, None, lens)
+    assert got.dtype == jnp.bfloat16
+    assert ak.prefill_attention(q, k, v, None, lens,
+                                kernel=False).dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(ak._xla_prefill_attention(q, k, v, None, lens)),
+        atol=3e-2)
+
+
+def _prefill_tiles(s, length, window, block_q, block_k):
+    """[(query block, key block, masked)] of every tile the admission
+    kernel computes for a row of `length` own positions in a bucket of
+    s, by the arithmetic the kernel's loops run on (`_prefill_k_range`
+    a query block that holds an own row): the schedule as a list, for
+    the brute-force picture to be held against."""
+    tiles = []
+    for qi in range(min(s, length + block_q - 1) // block_q):
+        r0 = qi * block_q
+        a, b, c, n = ak._prefill_k_range(r0, min(r0 + block_q, length),
+                                         block_k, window)
+        tiles += [(qi, ki, not b <= ki < c) for ki in range(a, n)]
+    return tiles
+
+
+@pytest.mark.parametrize("s,block_q,block_k,window", [
+    (1024, 512, 512, None), (1024, 256, 256, None), (1024, 256, 256, 512),
+    (1024, 128, 128, 512), (1024, 128, 256, 300), (1024, 256, 128, 512),
+    (1024, 512, 512, 512), (2048, 512, 512, 4096), (256, 256, 256, 512),
+    (64, 64, 64, None), (1024, 128, 512, 1), (1024, 512, 128, 129),
+])
+def test_prefill_tiles_are_exactly_the_tiles_with_something_to_show(
+        s, block_q, block_k, window):
+    """The admission schedule is a pure function of the bucket, the
+    prompt's length, the window and the tile: every tile in which an own
+    row (one under the length) sees a key and no other, masked exactly
+    where such a row also has a key hidden, at every length that matters
+    (none, one, around every block's edge, the bucket's)."""
+    rows, cols = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = rows >= cols
+    if window is not None:
+        seen &= cols > rows - window
+    lengths = {0, 1, s // 2 + 7, s - 1, s}
+    for edge in range(block_q, s, block_q):
+        lengths |= {edge - 1, edge, edge + 1}
+    for length in sorted(lengths):
+        want = []
+        for qi in range(s // block_q):
+            own = slice(qi * block_q, min((qi + 1) * block_q, length))
+            for ki in range(s // block_k):
+                t = seen[own, ki * block_k:(ki + 1) * block_k]
+                if t.any():
+                    want.append((qi, ki, not t.all()))
+        assert _prefill_tiles(s, length, window, block_q,
+                              block_k) == want, length
+    # with everything shown it is the training schedule
+    if window is None:
+        assert _prefill_tiles(s, s, None, block_q, block_k) == \
+            ak.causal_tiles(s, block_q, block_k)
+
+
+# (query heads a KV head, window, q/k and v head widths) of the two
+# serving cells' admission layers and the buckets each admits
+# (tests/test_aot_tpu_compile.py holds the head shapes to the cells'
+# configurations)
+_CELL_KINDS = {"laguna_full": (6, None, (128, 128), 128, 4096),
+               "laguna_window": (9, 512, (128, 128), 128, 4096),
+               "longcat": (1, None, (192, 128), 256, 8192)}
+_CELL_BUCKETS = [(kind, bucket) for kind, (_g, _w, _dd, lo, hi)
+                 in _CELL_KINDS.items()
+                 for bucket in (128, 256, 512, 1024, 2048, 4096, 8192)
+                 if lo <= bucket <= hi]
+_CELL_IDS = [f"{kind}-{bucket}" for kind, bucket in _CELL_BUCKETS]
+
+
+def _brute_force_tiles(s, length, window, block_q, block_k):
+    """[(query block, key block, masked)]: every tile in which a row
+    under `length` sees a key, masked where such a row also has one
+    hidden, from the s x s picture of who sees whom."""
+    rows, cols = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = rows >= cols
+    if window is not None:
+        seen &= cols > rows - window
+    own = rows < length
+    shape = (s // block_q, block_q, s // block_k, block_k)
+    shown = (seen & own).reshape(shape).any(axis=(1, 3))
+    hidden = (~seen & own).reshape(shape).any(axis=(1, 3))
+    return [(int(qi), int(ki), bool(hidden[qi, ki]))
+            for qi, ki in zip(*np.nonzero(shown))]
+
+
+def _lengths_that_matter(s, block_q):
+    """1, around the first and a middle tile's edge, the bucket."""
+    mid = (s // block_q // 2) * block_q
+    return sorted({1, s} | {n for edge in (block_q, mid)
+                            for n in (edge - 1, edge, edge + 1)
+                            if 1 <= n <= s})
+
+
+@pytest.mark.parametrize("window", [None, 512])
+@pytest.mark.parametrize("s", [128, 256, 512, 1024, 2048, 4096, 8192])
+def test_prefill_schedule_at_the_picked_tiles(s, window):
+    """The schedule at the tiles the rule picks (grouped heads packed,
+    and heads of their own) over every bucket a cell admits, with and
+    without Laguna's window, against the brute-force picture."""
+    for group in (1, 6):
+        block_q, block_k, _pack = ak._pick_prefill_blocks(s, group)
+        for length in _lengths_that_matter(s, block_q):
+            assert _prefill_tiles(s, length, window, block_q, block_k) \
+                == _brute_force_tiles(s, length, window, block_q,
+                                      block_k), (group, length)
+
+
+@pytest.mark.parametrize("kind,bucket", _CELL_BUCKETS, ids=_CELL_IDS)
+def test_prefill_tile_counts_are_the_schedules_lengths(kind, bucket):
+    """What the batcher counts a request (`prefill_tile_counts`: the
+    prompt's own tiles and the bucket's) is the length of the schedule
+    the kernel runs (`_prefill_tiles`, which the tests above hold to
+    the picture of who sees whom), at every length that matters and a
+    few dozen drawn ones."""
+    group, window, dd, _lo, _hi = _CELL_KINDS[kind]
+    block_q, block_k, _pack = ak._pick_prefill_blocks(bucket, group)
+    whole = len(_prefill_tiles(bucket, bucket, window, block_q, block_k))
+    drawn = np.random.default_rng(bucket + group).integers(1, bucket + 1, 40)
+    for length in [0, *_lengths_that_matter(bucket, block_q), *drawn]:
+        length = int(length)
+        assert ak.prefill_tile_counts(bucket, length, window, group, *dd) \
+            == (len(_prefill_tiles(bucket, length, window, block_q,
+                                   block_k)), whole), length
+
+
+@pytest.mark.parametrize("s,group,dd,itemsize", [
+    (1000, 6, (128, 128), 2),      # a bucket cut at a max_len of no tiles
+    (200, 1, (192, 128), 2), (4100, 9, (128, 128), 2),
+    (1024, 6, (64, 64), 2),        # a head the contraction does not take
+    (1024, 1, (192, 64), 2),
+    (8192, 1, (512, 512), 4),      # a grid step past the VMEM asked
+])
+def test_prefill_tile_counts_where_the_kernel_declines(s, group, dd,
+                                                        itemsize):
+    """No tile is counted at a shape the kernel does not take: the
+    count and the dispatch ask one test (`_prefill_shape_ok`), and a
+    bucket equal to a `max_len` that no block tiles is such a shape,
+    not an error."""
+    q = jax.ShapeDtypeStruct((1, s, group, dd[0]),
+                             jnp.bfloat16 if itemsize == 2 else jnp.float32)
+    v = jax.ShapeDtypeStruct((1, s, 1, dd[1]), q.dtype)
+    assert not ak.prefill_attention_ok(q, v)
+    for window in (None, 512):
+        assert ak.prefill_tile_counts(s, s // 2, window, group, *dd,
+                                      itemsize=itemsize) == (0, 0)
+
+
+# what a score tile may hold by cell: an instance of the kernel compiles
+# for a described v5e in at most 0.2 s at Laguna's shapes and 0.5 s at
+# LongCat's up to these (PERF.md section 6, PR 35: 0.15 s at 128 x 256
+# keys x 3 heads and half as much again at 512 keys, 0.31-0.43 at
+# 512 x 512 with one head; the parent's 0.06-0.16)
+_CELL_TILE = {"laguna_full": (384, 96 * 1024),
+              "laguna_window": (384, 96 * 1024),
+              "longcat": (512, 256 * 1024)}
+
+
+@pytest.mark.parametrize("kind,bucket", _CELL_BUCKETS, ids=_CELL_IDS)
+def test_prefill_tiles_stay_inside_the_setup_budget(kind, bucket):
+    """The compile budget, held by what a CPU can hold it by: compile
+    seconds follow a score tile's lanes and area, so at every bucket of
+    both cells' admissions the picked tile stays within the rule's
+    lanes, its key rows within 256 or a query block's, and both within
+    the widest tile whose compile was measured inside the cell's
+    budget."""
+    group, _window, _dd, _lo, _hi = _CELL_KINDS[kind]
+    block_q, block_k, pack = ak._pick_prefill_blocks(bucket, group)
+    lanes, area = pack * block_q, block_k * pack * block_q
+    assert group % pack == 0 and bucket % block_q == bucket % block_k == 0
+    assert lanes <= ak._PREFILL_LANES
+    assert block_k <= max(block_q, ak._PREFILL_KEYS)
+    cell_lanes, cell_area = _CELL_TILE[kind]
+    assert lanes <= cell_lanes and area <= cell_area
+    # the rule widens with the bucket and never narrows
+    if bucket > 128:
+        bq, bk, _p = ak._pick_prefill_blocks(bucket // 2, group)
+        assert bq * bk <= block_q * block_k
+
+
+def test_pick_prefill_blocks_follows_the_shape():
+    """The tile and the packing are functions of the bucket and the
+    group: within 512 lanes, key tiles of 256 rows or a query block's,
+    LongCat's 64 heads of their own at 512 x 512, Laguna's six (full)
+    and nine (window) query heads a KV head three to a step at 128 x
+    256, short buckets one tile, a large group its largest divisor that
+    fits; both cells' longest buckets keep K and V resident under the
+    VMEM the call asks for."""
+    assert ak._pick_prefill_blocks(8192, 1) == (512, 512, 1)
+    assert ak._pick_prefill_blocks(4096, 1) == (512, 512, 1)
+    assert ak._pick_prefill_blocks(512, 1) == (512, 512, 1)
+    assert ak._pick_prefill_blocks(384, 1) == (128, 128, 1)
+    assert ak._pick_prefill_blocks(256, 1) == (256, 256, 1)
+    assert ak._pick_prefill_blocks(4096, 2) == (256, 256, 2)
+    assert ak._pick_prefill_blocks(4096, 6) == (128, 256, 3)
+    assert ak._pick_prefill_blocks(4096, 9) == (128, 256, 3)
+    assert ak._pick_prefill_blocks(1024, 9) == (128, 256, 3)
+    assert ak._pick_prefill_blocks(128, 9) == (128, 128, 3)
+    assert ak._pick_prefill_blocks(64, 6) == (64, 64, 1)
+    assert ak._pick_prefill_blocks(1024, 64) == (128, 256, 4)
+    assert ak._kv_major(8192, 192, 2, 512, 128,
+                        ak._PREFILL_KV_BUDGET) == 8192
+    assert ak.prefill_attention_vmem(8192, 192, 128, 1) \
+        <= ak._PREFILL_VMEM_BUDGET
+    assert ak.prefill_attention_vmem(4096, 128, 128, 9) \
+        <= ak._PREFILL_VMEM_BUDGET

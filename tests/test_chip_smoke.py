@@ -47,7 +47,7 @@ def test_phase_passes_tiny_on_cpu(name):
 def test_chips_4_selects_only_the_multichip_phase():
     assert [n for n, _ in chip_smoke.select_phases(4)] == ["lm3d"]
     assert [n for n, _ in chip_smoke.select_phases(1)] == [
-        "featurize", "vit", "lm_train", "lm_serve"]
+        "featurize", "vit", "lm_train", "admit_attn", "lm_serve"]
 
 
 def test_first_failing_phase_stops_the_run(capsys):

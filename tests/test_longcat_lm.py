@@ -267,6 +267,64 @@ def test_pages_grow_are_released_and_counted(served):
     assert "serving.batcher.pages.full" not in c
 
 
+def test_admission_tiles_are_counted(served, monkeypatch):
+    """What the admission kernel's schedule visits for the prompts' own
+    positions, beside the buckets' whole schedules, counted where the
+    kernel runs: nothing off the TPU (the batcher holds no shapes to
+    count at), nothing at this model's narrow heads, and at the cell's
+    (64 heads of their own at q/k 192, v 128) a prompt of 5,000 in the
+    8,192 bucket 55 of 136 and one of 3,000 in the 4,096 bucket 21 of
+    36."""
+    batcher, _prompts, *_ = served
+    model = batcher.model
+    assert model.attn_shapes == ((1, CFG["qk_nope_head_dim"]
+                                  + CFG["qk_rope_head_dim"],
+                                  CFG["v_head_dim"]),)
+    assert batcher._attn_shapes == ()
+    seen = {}
+    monkeypatch.setattr(telemetry, "incr", lambda name, n=1: seen.__setitem__(
+        name, seen.get(name, 0) + n))
+    batcher._note_attn_tiles(5000, 8192)
+    assert seen == {}
+    monkeypatch.setattr(batcher, "_attn_shapes", model.attn_shapes)
+    batcher._note_attn_tiles(5000, 8192)
+    assert set(seen.values()) == {0}
+    seen.clear()
+    monkeypatch.setattr(batcher, "_attn_shapes", ((1, 192, 128),))
+    batcher._note_attn_tiles(5000, 8192)
+    assert seen == {"serving.batcher.prefill.attn_tiles": 55,
+                    "serving.batcher.prefill.attn_tiles_bucket": 136}
+    batcher._note_attn_tiles(3000, 4096)
+    assert seen == {"serving.batcher.prefill.attn_tiles": 55 + 21,
+                    "serving.batcher.prefill.attn_tiles_bucket": 136 + 36}
+
+
+@pytest.mark.parametrize("lengths", [(5, 11), (16, 1), (9, 0)])
+def test_first_tokens_do_not_see_the_buckets_padding(params, lengths):
+    """An admission's logits at each prompt's last token, and the
+    latent rows of its own positions, are the same whatever bucket the
+    prompt is padded into and whatever the padding holds: attention
+    runs over a row's first `lengths` positions (a pad row has none)."""
+    model = _model()
+    rng = np.random.default_rng(5)
+    last = jnp.asarray(lengths, jnp.int32) - 1
+    narrow = rng.integers(0, 128, (2, 16))
+    wide = rng.integers(0, 128, (2, 32))          # other padding, and more
+    for row, n in enumerate(lengths):
+        wide[row, :n] = narrow[row, :n]
+    got = [model.apply({"params": params}, jnp.asarray(toks), last,
+                       method=model.prefill, mutable=["stats"])[0]
+           for toks in (narrow, wide)]
+    for row, n in enumerate(lengths):
+        if n == 0:
+            continue                               # a pad row: no token
+        np.testing.assert_allclose(np.asarray(got[0][0][row]),
+                                   np.asarray(got[1][0][row]), atol=1e-5)
+        for (a,), (b,) in zip(got[0][1], got[1][1]):
+            np.testing.assert_allclose(np.asarray(a[row, :n]),
+                                       np.asarray(b[row, :n]), atol=1e-5)
+
+
 def test_routing_counters_ride_the_token_fetch(served):
     _b, prompts, replies, _w = served
     c = telemetry.counters()

@@ -323,6 +323,54 @@ def test_window_pages_are_recycled_and_bounded(served):
         counted["serving.batcher.pages.full"]
 
 
+def test_admission_tiles_are_counted_where_the_kernel_runs(served):
+    """The model names a head shape a cache kind (two query heads a KV
+    head on full, three on window layers, 16 wide); off the TPU the
+    admission runs the XLA composition and the batcher holds no shape
+    to count tiles at."""
+    batcher, _prompts, *_ = served
+    assert batcher.model.attn_shapes == ((2, 16, 16), (3, 16, 16))
+    assert batcher._attn_shapes == ()
+
+
+def test_a_bucket_cut_at_max_len_is_admitted_and_counts_no_tiles(
+        monkeypatch):
+    """`max_len` 1,000, which no block of the admission kernel tiles:
+    a prompt of 600 is admitted at a bucket of 1,000, a shape the
+    kernel declines (the XLA composition runs, as before the kernel
+    took lengths) and the count reads as no tiles, not as an error on
+    the loop thread; a prompt of 100 at the 128 bucket counts its one
+    tile a kind both ways.  The count is on (`on_single_tpu` forced
+    for the batcher alone; the programs are the CPU's)."""
+    from mmlspark_tpu.serving import batcher as batcher_mod
+
+    monkeypatch.setattr(batcher_mod, "on_single_tpu", lambda: True)
+    model = MoELM.from_config(dict(CFG, head_dim=128), 1000, jnp.float32)
+    variables = model.init(jax.random.PRNGKey(3),
+                           jnp.zeros((1, 8), jnp.int32))
+    batcher = ContinuousBatcher(model, variables, max_slots=2, paged=True,
+                                page_size=8)
+    assert batcher._attn_shapes == ((2, 128, 128), (3, 128, 128))
+    assert batcher._bucket(600) == 1000
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 128, n).tolist() for n in (600, 100)]
+    names = ("serving.batcher.prefill.attn_tiles",
+             "serving.batcher.prefill.attn_tiles_bucket")
+    before = telemetry.counters("serving.batcher.prefill.attn_tiles")
+    batcher.start()
+    try:
+        replies = [batcher.submit(p, max_new_tokens=2).tokens()
+                   for p in prompts]
+    finally:
+        batcher.stop()
+    after = telemetry.counters("serving.batcher.prefill.attn_tiles")
+    assert [after.get(n, 0) - before.get(n, 0) for n in names] == [2, 2]
+    for prompt, reply in zip(prompts, replies):
+        logits, _taps = model.apply(variables, jnp.asarray([prompt]))
+        row = np.asarray(logits[0, -1])
+        assert row.max() - row[reply[0]] < 2e-3
+
+
 def test_free_lists_return_to_full(served):
     batcher = served[0]
     win = batcher._win
@@ -362,6 +410,34 @@ def test_live_rows_are_the_prompts_own(params):
     assert whole[0] == part[0] == 2 * 16 * 2 * 4      # rows x top-k x layers
     assert whole[2] == whole[0] and part[2] == 5 * 2 * 4
     assert part[3] <= part[2]
+
+
+@pytest.mark.parametrize("lengths", [(5, 11), (16, 1), (9, 0)])
+def test_first_tokens_do_not_see_the_buckets_padding(params, lengths):
+    """An admission's logits at each prompt's last token, and the K/V
+    rows of its own positions, are the same whatever bucket the prompt
+    is padded into and whatever the padding holds: attention runs over
+    a row's first `lengths` positions (a pad row has none)."""
+    model = _model()
+    rng = np.random.default_rng(5)
+    last = jnp.asarray(lengths, jnp.int32) - 1
+    narrow = rng.integers(0, 128, (2, 16))
+    wide = rng.integers(0, 128, (2, 32))          # other padding, and more
+    for row, n in enumerate(lengths):
+        wide[row, :n] = narrow[row, :n]
+    got = [model.apply({"params": params}, jnp.asarray(toks), last,
+                       method=model.prefill, mutable=["stats"])[0]
+           for toks in (narrow, wide)]
+    for row, n in enumerate(lengths):
+        if n == 0:
+            continue                               # a pad row: no token
+        np.testing.assert_allclose(np.asarray(got[0][0][row]),
+                                   np.asarray(got[1][0][row]), atol=1e-5)
+        for kv_a, kv_b in zip(got[0][1], got[1][1]):
+            for a, b in zip(kv_a, kv_b):
+                np.testing.assert_allclose(np.asarray(a[row, :n]),
+                                           np.asarray(b[row, :n]),
+                                           atol=1e-5)
 
 
 def test_teacher_force_replays_what_was_served(ref, params, served):
