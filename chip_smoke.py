@@ -22,8 +22,12 @@ published widths, each phase compared with a plain reference:
 `--chips 4` runs ONLY the multi-chip phase: make_lm_train_step_3d on
 MeshPlan(data=2, model=2, pipe=1) against the one-device step.
 
-Every phase prints one JSON line as it finishes; the first failure stops
-the run with a non-zero exit.  The LAST stdout line of a passing run is
+Every phase prints one JSON line as it finishes, with what it spent
+compiling or fetching executables and the persistent cache's answers
+(the compile sentry's totals); the first failure stops the run with a
+non-zero exit.  A `setup_programs` line follows: the sentry's start-up
+report of the programs that cost set-up most.  The LAST stdout line of
+a passing run is
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 
@@ -92,33 +96,6 @@ SIZES = {
 _COLLECTIVES = re.compile(
     r" (all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
     r"(?:-start)?\(")
-
-
-class _CompileMeter:
-    """Seconds JAX spent producing executables (a persistent-cache hit
-    counts its retrieval), and how the cache answered."""
-
-    def __init__(self):
-        from jax import monitoring
-
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_kw):
-        if event.endswith("backend_compile_duration"):
-            self.seconds += float(duration)
-
-    def _on_event(self, event, **_kw):
-        if event.endswith("/compilation_cache/cache_hits"):
-            self.hits += 1
-        elif event.endswith("/compilation_cache/cache_misses"):
-            self.misses += 1
-
-    def snapshot(self):
-        return self.seconds, self.hits, self.misses
 
 
 def _custom_calls(compiled) -> int:
@@ -659,12 +636,14 @@ def select_phases(chips: int):
     return FOUR_CHIP_PHASES if chips == 4 else ONE_CHIP_PHASES
 
 
-def run_phases(phases, size: dict, seed: int, meter=None) -> bool:
+def run_phases(phases, size: dict, seed: int, sentry=None) -> bool:
     """Run phases in order, one JSON line each; stop at the first that
-    fails so no later line can say ok."""
-    snapshot = meter.snapshot if meter else (lambda: (0.0, 0, 0))
+    fails so no later line can say ok.  With the compile sentry, a line
+    also says what its phase spent compiling or fetching executables
+    and how the persistent cache answered."""
+    totals = sentry.totals if sentry else collections.Counter
     for name, fn in phases:
-        c0 = snapshot()
+        c0 = totals()
         t0 = time.perf_counter()
         try:
             rec = fn(size, seed)
@@ -673,11 +652,12 @@ def run_phases(phases, size: dict, seed: int, meter=None) -> bool:
 
             traceback.print_exc()
             rec = {"ok": False, "error": f"{type(e).__name__}: {e}"[-2000:]}
-        c1 = snapshot()
+        c1 = totals()
         print(json.dumps({
             "phase": name, **rec,
-            "compile_s": round(c1[0] - c0[0], 3),
-            "cache_hits": c1[1] - c0[1], "cache_misses": c1[2] - c0[2],
+            "compile_s": round(c1["compile_s"] - c0["compile_s"], 3),
+            **{f"cache_{k}": c1[k] - c0[k]
+               for k in ("hits", "misses", "unwritten")},
             "wall_s": round(time.perf_counter() - t0, 3)}), flush=True)
         if not rec["ok"]:
             return False
@@ -713,8 +693,14 @@ def main(argv=None) -> int:
     print(json.dumps({"phase": "setup", "device": device,
                       "compile_cache_dir": cache_dir,
                       "jax": jax.__version__, "seed": args.seed}), flush=True)
-    if not run_phases(select_phases(args.chips), SIZES["full"], args.seed,
-                      _CompileMeter()):
+    from mmlspark_tpu.core import telemetry
+
+    sentry = telemetry.track_compiles()
+    ok = run_phases(select_phases(args.chips), SIZES["full"], args.seed,
+                    sentry)
+    # set-up is what ran before the first warm-up was declared over
+    print(json.dumps({"setup_programs": sentry.report()}), flush=True)
+    if not ok:
         return 1
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

@@ -17,8 +17,26 @@ watches the runtime underneath it.  Three concerns:
   cache they, not the backend compile, are what set-up pays.  After the
   caller DECLARES warmup over (`SENTRY.end_warmup()`), every further
   compile is flagged as a steady-state recompile: `xla.compile.hot_path`
-  counter + WARNING log.  This jax version's monitoring events carry no
-  function/shape metadata, so naming the triggering shape is the job of
+  counter + WARNING log.
+
+  Set-up is attributed where it is paid.  The sentry's STAGE is `setup`
+  from its installation until the process's first `end_warmup()` and
+  `run` after it for good (`reset()` re-arms the flagging, not set-up);
+  the three histograms carry it as a `stage` label.  JAX names the
+  function in every event (`fun_name`: `f` when traced, `jit(f)` when
+  lowered and compiled), and records the persistent cache's answer on
+  the compiling thread just before the backend compile it belongs to,
+  so each compile is a hit, a miss, or an UNWRITTEN miss (the cache is
+  on and did not keep the program: the next run compiles it again) in
+  `xla.compile.cache.{hits,misses,unwritten}.<stage>`, and a bounded
+  table a stage (`programs(stage)`) holds what each function cost to
+  trace, lower and compile or fetch.  When set-up ends the table is
+  written once as a `setup_programs` record.  `setup.start_s` is the
+  process's age when the sentry was installed: what came before it
+  watched (interpreter, imports, backend start).
+
+  What the events do not carry is the argument shapes, so naming the
+  shape that forced a steady-state recompile is the job of
   `watch_compiles(fn, name)`: a transparent wrapper around a jitted
   callable that detects a compile during a call (`_cache_size()` delta,
   falling back to the sentry's global compile count) and, in steady
@@ -55,6 +73,8 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import os
+import re
 import sys
 import threading
 import time
@@ -68,7 +88,7 @@ from .records import log_verb, logger
 __all__ = ["CompileSentry", "SENTRY", "track_compiles", "watch_compiles",
            "describe_abstract_shapes", "sample_device_memory",
            "MemorySampler", "start_memory_sampler",
-           "device_annotation"]
+           "device_annotation", "process_age_s"]
 
 # the one monitoring event that means "XLA produced an executable";
 # jaxpr tracing / MLIR lowering durations ride the same listener as
@@ -76,6 +96,52 @@ __all__ = ["CompileSentry", "SENTRY", "track_compiles", "watch_compiles",
 _COMPILE_EVENT_SUFFIX = "backend_compile_duration"
 _TRACE_EVENT_SUFFIX = "jaxpr_trace_duration"
 _LOWER_EVENT_SUFFIX = "jaxpr_to_mlir_module_duration"
+# the persistent cache's answer: JAX records `cache_hits` when it fetched
+# the executable and `cache_misses` when it WROTE a new entry; a miss it
+# does not keep (compiled under `jax_persistent_cache_min_compile_time_secs`,
+# under the entry-size floor, ...) records nothing
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_WRITE_EVENT = "/jax/compilation_cache/cache_misses"
+
+SETUP, RUN = "setup", "run"
+PROGRAMS_KEPT = 256            # names a stage's table keeps ...
+OTHER_PROGRAMS = "_other_"     # ... and where the rest are folded
+SETUP_REPORT_TOP = 20
+_PROGRAM_FIELDS = ("traced", "trace_s", "lower_s", "compile_s",
+                   "hits", "misses", "unwritten")
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")
+
+
+def process_age_s() -> Optional[float]:
+    """Seconds since this process was started, from the kernel's record;
+    None where there is no /proc."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def _program_name(fun_name: Any) -> str:
+    """The function an event is about: JAX names it `f` when it traces it
+    and `jit(f)` when it lowers and compiles it; both are `f` here."""
+    name = "<unnamed>" if fun_name is None else str(fun_name)
+    wrapped = _WRAPPED.match(name)
+    return wrapped.group(1) if wrapped else name
+
+
+def _persistent_cache_on() -> bool:
+    """JAX keeps executables across processes: a cache directory is set
+    and the cache is enabled."""
+    config = getattr(sys.modules.get("jax"), "config", None)
+    try:
+        return bool(config.jax_compilation_cache_dir
+                    and config.jax_enable_compilation_cache)
+    except AttributeError:
+        return False
 
 
 def describe_abstract_shapes(args: Iterable[Any],
@@ -109,7 +175,8 @@ class CompileSentry:
     `end_warmup()` every compile is a steady-state recompile — the exact
     hazard `tpu_model.pad_to_batch` exists to prevent — and is flagged
     loudly.  `reset()` returns to warmup (tests, or a planned
-    reconfiguration that legitimately recompiles)."""
+    reconfiguration that legitimately recompiles).  The first
+    `end_warmup()` also ends the stage `setup`, for good."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -117,9 +184,16 @@ class CompileSentry:
         self._listener_active = False
         self._steady = False
         self._compiles = 0
+        self._stage = SETUP
+        self._start_s: Optional[float] = None
+        #: guarded-by self._lock
+        self._programs: Dict[str, Dict[str, Dict[str, float]]] = {
+            SETUP: {}, RUN: {}}
         # per thread, the (start, duration) of trace events not yet
         # enclosed by a later one: what an outer trace has to subtract
         self._traces = threading.local()
+        # per thread, the cache's answer to the compile in progress
+        self._answers = threading.local()
 
     # ---- state ---------------------------------------------------------
     @property
@@ -138,15 +212,73 @@ class CompileSentry:
         with self._lock:
             return not self._steady
 
+    @property
+    def stage(self) -> str:
+        """`setup` until the first `end_warmup()`, `run` after it."""
+        with self._lock:
+            return self._stage
+
     def end_warmup(self) -> None:
         """Declare warmup over: from here, any compile is a hot-path
-        recompile and gets flagged."""
+        recompile and gets flagged.  The first call ends set-up and
+        writes its `setup_programs` record."""
         with self._lock:
             self._steady = True
+            ended, self._stage = self._stage == SETUP, RUN
+        if ended:
+            with log_verb(self, "setup_programs", **self.report(SETUP)):
+                pass
 
     def reset(self) -> None:
+        """Re-arm warmup; set-up, once ended, stays ended (a planned
+        recompile after it, such as a check's, is not set-up)."""
         with self._lock:
             self._steady = False
+
+    # ---- the per-program table -----------------------------------------
+    def programs(self, stage: str = SETUP) -> Dict[str, Dict[str, float]]:
+        """function name -> calls traced, trace / lower / compile-or-fetch
+        seconds, cache hits, misses and unwritten misses, in `stage`: at
+        most `PROGRAMS_KEPT` names, the rest under `OTHER_PROGRAMS`."""
+        with self._lock:
+            return {n: dict(row) for n, row in self._programs[stage].items()}
+
+    def totals(self, stage: Optional[str] = None) -> Dict[str, float]:
+        """The table's columns summed, over one stage or both."""
+        out = dict.fromkeys(_PROGRAM_FIELDS, 0)
+        for s in (stage,) if stage else (SETUP, RUN):
+            for row in self.programs(s).values():
+                for k in _PROGRAM_FIELDS:
+                    out[k] += row[k]
+        return out
+
+    def report(self, stage: str = SETUP,
+               top: int = SETUP_REPORT_TOP) -> Dict[str, Any]:
+        """A stage's start-up report: its totals and the `top` programs
+        by trace + lower + compile seconds."""
+        rows = self.programs(stage)
+        cost = sorted(rows, key=lambda n: -(rows[n]["trace_s"]
+                                            + rows[n]["lower_s"]
+                                            + rows[n]["compile_s"]))
+
+        def rounded(row):
+            return {k: round(v, 4) if k.endswith("_s") else v
+                    for k, v in row.items()}
+
+        return {"start_s": self._start_s, "names": len(rows),
+                "totals": rounded(self.totals(stage)),
+                "programs": [{"name": n, **rounded(rows[n])}
+                             for n in cost[:top]]}
+
+    def _row(self, stage: str, name: str) -> Dict[str, float]:
+        """The table's row for `name` (the caller holds the lock)."""
+        rows = self._programs[stage]
+        if name not in rows and len(rows) >= PROGRAMS_KEPT:
+            name = OTHER_PROGRAMS
+        row = rows.get(name)
+        if row is None:
+            row = rows[name] = dict.fromkeys(_PROGRAM_FIELDS, 0)
+        return row
 
     @contextlib.contextmanager
     def warmup(self):
@@ -169,45 +301,71 @@ class CompileSentry:
             if self._installed:
                 return self
             self._installed = True
+            self._start_s = process_age_s()
+        if self._start_s is not None:
+            REGISTRY.gauge("setup.start_s").set(self._start_s)
         try:
             from jax import monitoring
             monitoring.register_event_duration_secs_listener(
                 self._on_event_duration)
+            monitoring.register_event_listener(self._on_event)
         except Exception:
             return self
         with self._lock:
             self._listener_active = True
         return self
 
+    def _on_event(self, event: str, **_kw: Any) -> None:
+        # the cache's answer, on the compiling thread, before the
+        # backend-compile event it belongs to
+        if event == _CACHE_HIT_EVENT:
+            self._answers.last = "hit"
+        elif event == _CACHE_WRITE_EVENT:
+            self._answers.last = "written"
+
     def _on_event_duration(self, event: str, duration: float,
-                           **_kw: Any) -> None:
+                           **kw: Any) -> None:
         # fires synchronously on the thread running the compile, so
         # current_context() attributes the span to the request/step that
         # triggered it
+        name = _program_name(kw.get("fun_name"))
+        duration = float(duration)
         if not event.endswith(_COMPILE_EVENT_SUFFIX):
             try:
                 if event.endswith(_TRACE_EVENT_SUFFIX):
-                    REGISTRY.histogram("xla.compile.trace.latency").observe(
-                        self._trace_self_time(float(duration)))
+                    self._phase("xla.compile.trace.latency", "trace_s", name,
+                                self._trace_self_time(duration))
                 elif event.endswith(_LOWER_EVENT_SUFFIX):
-                    REGISTRY.histogram("xla.compile.lower.latency").observe(
-                        float(duration))
+                    self._phase("xla.compile.lower.latency", "lower_s", name,
+                                duration)
             except Exception:
                 pass
             return
+        answer = getattr(self._answers, "last", None)
+        self._answers.last = None
+        outcome = "hits" if answer == "hit" else "misses"
+        unwritten = answer is None and _persistent_cache_on()
         with self._lock:
             self._compiles += 1
-            steady = self._steady
+            steady, stage = self._steady, self._stage
+            row = self._row(stage, name)
+            row["compile_s"] += duration
+            row[outcome] += 1
+            row["unwritten"] += unwritten
         phase = "steady" if steady else "warmup"
         try:
             REGISTRY.incr("xla.compile.count")
-            REGISTRY.histogram("xla.compile.latency").observe(float(duration))
+            REGISTRY.histogram("xla.compile.latency",
+                               stage=stage).observe(duration)
+            REGISTRY.incr(f"xla.compile.cache.{outcome}.{stage}")
+            if unwritten:
+                REGISTRY.incr(f"xla.compile.cache.unwritten.{stage}")
             _spans.record_span("xla.compile", _spans.current_context(),
-                               float(duration), phase=phase)
+                               duration, phase=phase)
             # a compile observed after training started is wall the run
             # can never get back — the goodput ledger drops this until
             # its first recorded step, so warmup stays unattributed
-            LEDGER.note_lost("recompile", float(duration))
+            LEDGER.note_lost("recompile", duration)
             if steady:
                 REGISTRY.incr("xla.compile.hot_path")
                 logger.warning(
@@ -217,6 +375,17 @@ class CompileSentry:
         except Exception:
             # a telemetry listener must never break a compile
             pass
+
+    def _phase(self, hist: str, column: str, name: str,
+               seconds: float) -> None:
+        """A trace or lower event: the stage's histogram and the
+        function's row."""
+        with self._lock:
+            stage = self._stage
+            row = self._row(stage, name)
+            row[column] += seconds
+            row["traced"] += column == "trace_s"
+        REGISTRY.histogram(hist, stage=stage).observe(seconds)
 
     def _trace_self_time(self, duration: float) -> float:
         """JAX fires a trace event for every jitted function, also for

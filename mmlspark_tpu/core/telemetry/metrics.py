@@ -81,6 +81,11 @@ DECLARED_METRICS: Dict[str, str] = {
     "flow.expired.prefill": "counter",
     "xla.compile.count": "counter",       # every observed XLA compile
     "xla.compile.hot_path": "counter",    # + .<fn> variants: steady-state
+    # the persistent cache's answer to each compile, + .<stage> variants
+    # (setup / run: core/telemetry/device.py)
+    "xla.compile.cache.hits": "counter",       # fetched, not compiled
+    "xla.compile.cache.misses": "counter",     # compiled
+    "xla.compile.cache.unwritten": "counter",  # compiled, and not kept
     # -- counters: the continuous batcher's loop thread (serving/batcher.py)
     "serving.batcher.prefill.tokens": "counter",         # real prompt tokens
     "serving.batcher.prefill.padded_tokens": "counter",  # rows x bucket computed
@@ -156,6 +161,7 @@ DECLARED_METRICS: Dict[str, str] = {
     "io.http.request.latency": "histogram",
     "models.training.step_latency": "histogram",
     "checkpoint.verify.latency": "histogram",
+    # labeled {stage=setup|run}: the compile sentry's stage
     "xla.compile.latency": "histogram",
     "xla.compile.trace.latency": "histogram",   # jaxpr tracing, self time
     "xla.compile.lower.latency": "histogram",   # jaxpr -> MLIR module
@@ -189,6 +195,7 @@ DECLARED_METRICS: Dict[str, str] = {
     "device.hbm.bytes_in_use": "gauge",
     "device.hbm.peak_bytes": "gauge",
     "device.live_buffer_count": "gauge",
+    "setup.start_s": "gauge",     # process age when the compile sentry armed
     "serving.fleet.replicas": "gauge",
     "serving.fleet.healthy": "gauge",
     "fleet.pull.replicas": "gauge",       # replicas reached by last pull

@@ -58,8 +58,10 @@ def _prompt(rng, n):
 
 
 def _hist(name):
-    snap = telemetry.histogram(name).snapshot()
-    return snap["count"], snap["sum"]
+    """count and sum over every label set of `name`"""
+    snaps = [h.snapshot() for (n, _), h in
+             telemetry.REGISTRY.histograms().items() if n == name]
+    return (sum(s["count"] for s in snaps), sum(s["sum"] for s in snaps))
 
 
 def _bucket(n):

@@ -78,7 +78,9 @@ def test_compile_count_latency_and_span(sentry):
     assert telemetry.counters("xla.compile.count")[
         "xla.compile.count"] > count0
     snap = telemetry.export_snapshot(include_spans=False)
-    assert snap["histograms"]["xla.compile.latency"]["count"] > 0
+    # labeled by the sentry's stage: xla.compile.latency{stage="..."}
+    assert sum(h["count"] for k, h in snap["histograms"].items()
+               if k.startswith("xla.compile.latency{")) > 0
     names = {r["name"] for r in telemetry.get_trace(sp.trace_id)}
     assert "xla.compile" in names
 
