@@ -48,7 +48,9 @@ Training: fused_attention carries a custom VJP whose BACKWARD is the
 flash-attention backward, recomputing each score tile in VMEM from the
 forward's saved logsumexp — the dense-XLA backward materialized f32
 [B, H, S, S] score tensors per layer.  Where a head's Q, dO and f32 dQ
-fit VMEM (`_fused_bwd_fits`: to S=2,048 at bf16) it is ONE kernel,
+fit VMEM (`_fused_bwd_fits`: to S=8,192 at D <= 128 and 4,096 at
+D = 256 in bf16, the kernel asking the compiler for 32 MiB where its
+estimate passes the 16 MiB a v5e kernel gets by default) it is ONE kernel,
 `_attention_bwd_dkdv_dq`: key blocks on the grid, a loop over the query
 blocks that see them, each tile's p and ds computed once for dV, dK
 and dQ (5 matmuls and one vector pass a tile).  Longer sequences take
@@ -92,9 +94,17 @@ __all__ = ["fused_attention", "attention_fits_vmem", "kernel_ok",
 
 _LANE = 128
 _NEG_INF = -1e30  # finite stand-in: -inf arithmetic is fragile on Mosaic
-# what the one-kernel backward may keep resident: under the 16 MiB a v5e
-# kernel gets by default, with room for what the estimate leaves out
-_FUSED_BWD_VMEM_BUDGET = 12 * 1024 * 1024
+# VMEM of the one-kernel backward, on the admission kernel's model
+# (`_PREFILL_VMEM_*`): what its estimate (`_fused_bwd_vmem`) may come to
+# under the 16 MiB a v5e kernel gets by default, with room for what the
+# estimate leaves out; past that, the limit the call asks the compiler for
+# (a v5e core has 128 MiB) and what the estimate may come to under it.  A
+# head's Q, dO and f32 dQ stay resident, so the estimate grows with S x D:
+# 20.75 MiB at S = 4,096, D = 256 in bf16, where the split pair would
+# compute every score tile twice
+_FUSED_BWD_VMEM_DEFAULT = 12 * 1024 * 1024
+_FUSED_BWD_VMEM_LIMIT = 32 * 1024 * 1024
+_FUSED_BWD_VMEM_BUDGET = 24 * 1024 * 1024
 
 
 # ---- the tile schedule -----------------------------------------------------
@@ -173,13 +183,11 @@ def _kv_major(s: int, d: int, itemsize: int, block_k: int, dv=None,
     return block_k
 
 
-def _fused_bwd_fits(s: int, d: int, itemsize: int = 2) -> bool:
-    """Does the one-kernel backward fit?  It keeps a head's Q and dO (as
-    given, and Q scaled), the f32 dQ accumulator and the dQ output
-    resident beside one key block's tiles: S=1024, D=64 in bf16 comes
-    to 7 MB, 3 of them the f32 tiles, and S=2,048 in bf16 (1,024 in
-    f32) is the last that fits (the split pair, O(block) in VMEM, takes
-    the sequences past it)."""
+def _fused_bwd_vmem(s: int, d: int, itemsize: int = 2) -> int:
+    """VMEM estimate of the one-kernel backward, in bytes.  It keeps a
+    head's Q and dO (as given, and Q scaled), the f32 dQ accumulator and
+    the dQ output resident beside one key block's tiles: S=1024, D=64 in
+    bf16 comes to 7 MB, 3 of them the f32 tiles."""
     block_q, block_k = _pick_blocks(s, d, True)
     d_l = _pad_up(d, _LANE)
     resident = (s * d_l * (7 * itemsize + 4)     # q, dO x2; q scaled; dQ x2
@@ -187,7 +195,19 @@ def _fused_bwd_fits(s: int, d: int, itemsize: int = 2) -> bool:
     step = (8 * block_k * d_l * itemsize         # K, V in; dK, dV out, x2
             + 2 * block_k * d_l * 4              # dK, dV accumulators
             + 3 * block_q * block_k * 4)         # p / dp / ds (f32)
-    return resident + step <= _FUSED_BWD_VMEM_BUDGET
+    return resident + step
+
+
+def _fused_bwd_fits(s: int, d: int, itemsize: int = 2) -> bool:
+    """Does the one-kernel backward fit `_FUSED_BWD_VMEM_BUDGET`?  In
+    bf16 the last length on the 128 grid that fits is S=9,856 at
+    D <= 128 and 4,992 at D = 192 or 256 (8,192 and 4,096 of the powers
+    of two; in f32 5,504 and 2,688); the split pair, O(block) in VMEM,
+    takes the sequences past it.  A shape whose estimate passes
+    `_FUSED_BWD_VMEM_DEFAULT` asks the compiler for
+    `_FUSED_BWD_VMEM_LIMIT` (in bf16 no power of two to S=2,048 does, at
+    any D to 256)."""
+    return _fused_bwd_vmem(s, d, itemsize) <= _FUSED_BWD_VMEM_BUDGET
 
 
 def attention_fits_vmem(s: int, d: int, itemsize: int = 2) -> bool:
@@ -433,7 +453,10 @@ def _attention_bwd_dkdv_dq(q, k, v, do, lse, delta, causal: bool,
     accumulator ([S / block_q, D, block_q]: the small K block is the
     operand transposed, not the tile), turned and written once a head.
     Five matmuls and one vector pass a tile, as FlashAttention's
-    backward has them.  The name starts with
+    backward has them.  A shape whose VMEM estimate passes
+    `_FUSED_BWD_VMEM_DEFAULT` asks the compiler for
+    `_FUSED_BWD_VMEM_LIMIT`; the others pass no compiler parameters, so
+    their kernel is the one the default limit compiles.  The name starts with
     `_attention_bwd_dkdv`: the benchmark's trace metrics find the kernel
     by that."""
     from jax.experimental import pallas as pl
@@ -442,6 +465,9 @@ def _attention_bwd_dkdv_dq(q, k, v, do, lse, delta, causal: bool,
     bh, s, d = q.shape
     block_q, block_k = _pick_blocks(s, d, causal)
     n_q, n_kb = s // block_q, s // block_k
+    params = None
+    if _fused_bwd_vmem(s, d, q.dtype.itemsize) > _FUSED_BWD_VMEM_DEFAULT:
+        params = pltpu.CompilerParams(vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT)
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                dq_ref, dk_ref, dv_ref, qs_ref, dq_acc, dk_acc, dv_acc):
@@ -502,6 +528,7 @@ def _attention_bwd_dkdv_dq(q, k, v, do, lse, delta, causal: bool,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=params,
         interpret=_interpret(),
     )(q, k, v, do, _stat_rows(lse, block_q), _stat_rows(delta, block_q))
 

@@ -94,11 +94,16 @@ ATTN_SHAPES = {
     "s256_d192_native": (8, 256, 192, jnp.bfloat16, None, True),
     # f32 callers (the parity tests' dtype) at the LM cell's length
     "s1024_d64_f32": (16, 1024, 64, jnp.float32, None, True),
-    # past the fused backward's limit: K/V stream in two major blocks
-    # forward, and the backward is the dK/dV + dQ pair
-    "s8192_d64_split": (4, 8192, 64, jnp.bfloat16, None, True),
+    # K/V stream in two major blocks forward; the fused backward at the
+    # last power of two under its raised limit, asking for that VMEM
+    "s8192_d64": (4, 8192, 64, jnp.bfloat16, None, True),
+    # glm-train-moe's call: 256-wide heads, the fused backward asking for
+    # its VMEM (20.75 MiB by the estimate)
+    "glm_s4096_d256": (80, 4096, 256, jnp.bfloat16, None, True),
+    # past the fused backward's limit: the dK/dV + dQ pair
+    "s16384_d64_split": (4, 16384, 64, jnp.bfloat16, None, True),
 }
-SPLIT_ONLY = ["s8192_d64_split"]
+SPLIT_ONLY = ["s16384_d64_split"]
 FUSED_SHAPES = [t for t in ATTN_SHAPES if t not in SPLIT_ONLY]
 
 
@@ -797,7 +802,7 @@ def test_glm_epoch_program_fits_with_its_kernels(glm_epoch_program):
     """The cell's batch is the largest of 1, 2, 4 that fits 14.5 GB by
     this number (`batch_found` in its workload file: 13.08 GB); every
     attention sublayer runs the flash forward twice (a checkpointed
-    block) and the SPLIT backward, every routed layer the grouped
+    block) and the one-kernel backward, every routed layer the grouped
     matmul's forward twice (two calls each) and its dX (two) and dW
     (three) programs."""
     import collections
@@ -813,8 +818,7 @@ def test_glm_epoch_program_fits_with_its_kernels(glm_epoch_program):
             r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
             glm_epoch_program.as_text()))
     assert names == {"_attention_pallas": 2 * GLM_LAYERS,
-                     "_attention_bwd_dkdv": GLM_LAYERS,
-                     "_attention_bwd_dq": GLM_LAYERS,
+                     "_attention_bwd_dkdv_dq": GLM_LAYERS,
                      "_moe_gmm_train_fwd": 4 * GLM_SPARSE,
                      "_moe_gmm_bwd_dx": 2 * GLM_SPARSE,
                      "_moe_gmm_bwd_dw": 3 * GLM_SPARSE}
@@ -927,13 +931,14 @@ KERNEL_NAMES = [
     ("longcat_admission_program", "moe_expert_prefill_ms", "_moe_gmm_prefill",
      4),
     # the trained routed model: the flash kernels at 256-wide heads keep
-    # their names (forward twice a sublayer, the split pair), and `^_moe_gmm`
-    # finds the grouped matmul's three training programs
+    # their names (forward twice a sublayer, the one-kernel backward), and
+    # `^_moe_gmm` finds the grouped matmul's three training programs
     ("glm_epoch_program", "flash_attn_ms", "_attention_pallas",
      2 * GLM_LAYERS),
-    ("glm_epoch_program", "flash_attn_ms", "_attention_bwd_dkdv", GLM_LAYERS),
-    ("glm_epoch_program", "flash_attn_roofline_family", "_attention_bwd_dq",
+    ("glm_epoch_program", "flash_attn_ms", "_attention_bwd_dkdv_dq",
      GLM_LAYERS),
+    ("glm_epoch_program", "flash_attn_roofline_family",
+     "_attention_bwd_dkdv_dq", GLM_LAYERS),
     ("glm_epoch_program", "moe_train_ms", "_moe_gmm_train_fwd",
      4 * GLM_SPARSE),
     ("glm_epoch_program", "moe_train_ms", "_moe_gmm_bwd_dx", 2 * GLM_SPARSE),

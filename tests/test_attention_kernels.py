@@ -1,6 +1,8 @@
 """Fused-attention Pallas kernels: interpret-mode parity vs the XLA
 composition (forward, and the flash backward fused and split), the causal
 tile schedule as a pure function, and fallback routing."""
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -316,14 +318,55 @@ def test_streamed_major_blocks_parity(monkeypatch):
 
 def test_fused_backward_limit():
     """The one-kernel backward is a function of (s, d, itemsize): the LM
-    cell's shape and ViT's take it, a 128k context cannot."""
+    cell's shape, ViT's and GLM's take it, 16k keys and a 128k context
+    cannot."""
     assert ak._fused_bwd_fits(1024, 64, 2)
     assert ak._fused_bwd_fits(256, 64, 2)
     assert ak._fused_bwd_fits(2048, 64, 2)
-    assert not ak._fused_bwd_fits(8192, 64, 2)
+    assert ak._fused_bwd_fits(8192, 64, 2)
+    assert ak._fused_bwd_fits(4096, 256, 2)
+    assert not ak._fused_bwd_fits(16384, 64, 2)
+    assert not ak._fused_bwd_fits(8192, 256, 2)
     assert not ak._fused_bwd_fits(131072, 128, 2)
     assert ak._kv_major(1024, 64, 2, 256) == 1024
     assert ak._kv_major(131072, 128, 2, 256) == 4096
+
+
+@pytest.mark.parametrize("d,itemsize,last", [
+    (64, 2, 9856), (128, 2, 9856), (256, 2, 4992),
+    (64, 4, 5504), (256, 4, 2688)])
+def test_fused_backward_boundary(d, itemsize, last):
+    """The largest length on the 128 grid that fuses under
+    `_FUSED_BWD_VMEM_BUDGET`, and the next multiple of its tile, which
+    does not; at every length past the default's room the estimate is
+    one the raised limit holds."""
+    block = ak._pick_blocks(last, d, True)[0]
+    assert ak._fused_bwd_fits(last, d, itemsize)
+    assert not ak._fused_bwd_fits(last + block, d, itemsize)
+    assert ak._fused_bwd_vmem(last, d, itemsize) > \
+        ak._FUSED_BWD_VMEM_DEFAULT
+    assert ak._FUSED_BWD_VMEM_BUDGET < ak._FUSED_BWD_VMEM_LIMIT
+
+
+@pytest.mark.parametrize("bh,s,d,limit", [
+    (96, 1024, 64, None), (80, 4096, 256, ak._FUSED_BWD_VMEM_LIMIT)],
+    ids=["lm_train", "glm"])
+def test_fused_backward_asks_for_vmem_only_past_the_default(bh, s, d,
+                                                            limit):
+    """lm-train's shape passes no compiler parameters, so its kernel is
+    the one the default limit compiles; GLM's asks for
+    `_FUSED_BWD_VMEM_LIMIT`."""
+    q = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16)
+    stat = jax.ShapeDtypeStruct((bh, s), jnp.float32)
+    traced = jax.make_jaxpr(partial(ak._attention_bwd_dkdv_dq.__wrapped__,
+                                    causal=True, scale=d ** -0.5))(
+        q, q, q, q, stat, stat)
+    params, = [e.params["compiler_params"] for e in traced.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    if limit is None:
+        assert not params
+    else:
+        assert params["mosaic_tpu"].vmem_limit_bytes == limit
 
 
 # ---- no hidden fallback: a refused kernel raises ---------------------------
@@ -370,24 +413,28 @@ def test_kernel_head_dim_rule(d, want):
     assert ak._kernel_d(d) == want
 
 
-def test_grad_at_256_wide_heads_takes_the_split_backward(monkeypatch):
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "split"])
+def test_grad_at_256_wide_heads(monkeypatch, fused):
     """The latent-attention training shape: q, k and v heads of 256 (the
     kernels run it native, 256 x 256 tiles).  At the cell's S = 4,096 the
     repo's own predicates give the kernel path, 2,048 keys a forward step
-    and the SPLIT backward; here the same kernels at a length the
-    interpreter can afford, the split pair forced as the long sequence
-    takes it."""
+    and the ONE-KERNEL backward (at the raised VMEM limit); here the same
+    kernels at a length the interpreter can afford, by the predicate, and
+    the split pair forced as a sequence past the limit takes it."""
     assert ak.attention_fits_vmem(4096, 256)
     assert ak._pick_blocks(4096, 256, True) == (256, 256)
     assert ak._kv_major(4096, 256, 2, 256) == 2048
-    assert not ak._fused_bwd_fits(4096, 256)
+    assert ak._fused_bwd_fits(4096, 256)
     assert ak._kernel_d(256) == 256
     # lm-train's shape keeps its tiles and its fused backward
     assert ak._pick_blocks(1024, 64, True) == (512, 512)
     assert ak._fused_bwd_fits(1024, 64)
     q, k, v = _normal(13, (1, 512, 2, 256))
     assert ak.kernel_ok(q)
-    monkeypatch.setattr(ak, "_fused_bwd_fits", lambda *a, **kw: False)
+    if fused:
+        assert ak._fused_bwd_fits(512, 256, q.dtype.itemsize)
+    else:
+        monkeypatch.setattr(ak, "_fused_bwd_fits", lambda *a, **kw: False)
     _assert_fwd_and_grads_match(q, k, v, True)
 
 
